@@ -1,7 +1,7 @@
 """Ports of the pre-framework static checks, one typed rule each.
 
 Every check that lived as a bespoke scanner in
-``tests/test_static_checks.py`` (clock discipline, exception taxonomy,
+``tests/test_static_checks.py`` (clock discipline, exception hierarchy,
 zero-copy framing, pickle confinement, staging/device-upload
 discipline, print ban, qid minting, obs counter discipline) is now a
 :class:`~netsdb_tpu.analysis.lint.Rule` with the same scope and the
@@ -75,7 +75,7 @@ class BroadExceptRule(Rule):
     """Broad except handlers that neither bind nor re-raise."""
 
     id = "broad-except"
-    rationale = ("an opaque except erases the typed error taxonomy — "
+    rationale = ("an opaque except erases the typed error hierarchy — "
                  "bind ('as e') and forward, or re-raise")
 
     def select(self, mod: Module) -> bool:

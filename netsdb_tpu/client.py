@@ -84,7 +84,7 @@ class Client:
         config.ensure_dirs()
         from netsdb_tpu.config import enable_compilation_cache
 
-        enable_compilation_cache(config)  # PreCompiledWorkload analogue
+        enable_compilation_cache()  # PreCompiledWorkload analogue
         self.catalog = Catalog(catalog_path or ":memory:")
         self.store = SetStore(config)
         # mesh of the most recent placement applied via create_set — the

@@ -365,18 +365,6 @@ class Configuration:
     enable_compression: bool = True  # host spill compression (ref -DENABLE_COMPRESSION)
     log_level: str = "WARNING"
 
-    # --- persistent XLA compilation cache (reference: the master's
-    # PreCompiledWorkload plan cache, src/queryPlanning/headers/
-    # PreCompiledWorkload.h — here the cache holds compiled XLA
-    # executables keyed by HLO hash, shared across processes, so a
-    # fresh process reaches steady state without a cold compile) ---
-    # "auto" = <root_dir>/compile_cache; None/"" disables. The env var
-    # NETSDB_TPU_COMPILE_CACHE seeds this default (an explicitly passed
-    # value wins over it, like every other dataclass field).
-    compilation_cache_dir: Optional[str] = dataclasses.field(
-        default_factory=lambda: os.environ.get(
-            "NETSDB_TPU_COMPILE_CACHE", "auto"))
-
     def __post_init__(self) -> None:
         if self.bucket_density not in (2, 4):
             raise ValueError(f"bucket_density must be 2 or 4, got "
@@ -426,36 +414,42 @@ class Configuration:
         os.makedirs(self.data_dir, exist_ok=True)
 
 
-_cache_path: Optional[str] = None
+#: the ONE compile-cache location when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed, git-ignored directory inside the checkout. It must not
+#: depend on ``root_dir``, a pid or a temp name — a daemon started under
+#: a fresh root would otherwise never hit it, and a cold FF + decode +
+#: q01/q06 compile is most of a cold start.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache")
+
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def enable_compilation_cache(config: "Configuration" = None) -> Optional[str]:
-    """Point jax at the persistent compilation cache. Re-entrant: a
-    later call with a DIFFERENT resolved directory (e.g. a Client built
-    with an explicit root after the CLI enabled the default) re-points
-    jax's global cache there; ``compilation_cache_dir=None`` disables.
-    Returns the active directory or None."""
-    global _cache_path
-    cfg = config or DEFAULT_CONFIG
-    path = cfg.compilation_cache_dir
-    if path == "auto":
-        path = os.path.join(cfg.root_dir, "compile_cache")
-    if path == _cache_path:
-        return path
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache (reference: the
+    master's PreCompiledWorkload plan cache,
+    ``src/queryPlanning/headers/PreCompiledWorkload.h`` — compiled XLA
+    executables keyed by HLO hash, shared across processes, so a fresh
+    process reaches steady state without a cold compile).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it
+    stands and no directory is set in code; otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`. Idempotent. Returns the active
+    directory."""
     import jax
 
-    if not path:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _cache_path = None
-        return None
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not os.environ.get(_CACHE_ENV):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+        compilation_cache.set_cache_dir(COMPILE_CACHE_DIR)
     # cache everything: the queries this framework compiles are
     # worth persisting even when individually quick to build
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _cache_path = path
-    return path
+    # jax names the flag after the variable, lower-cased
+    return getattr(jax.config, _CACHE_ENV.lower())
 
 
 DEFAULT_CONFIG = Configuration()

@@ -76,23 +76,14 @@ def bench_placement_ab(width: int = 1100, batch: int = 4096,
     bo = rng.standard_normal((labels,)).astype(np.float32) * 0.01
     x = rng.standard_normal((batch, width)).astype(np.float32)
 
-    # one STABLE compile-cache dir for all rounds: the per-round roots
-    # are deleted below, and the jax cache pointer is process-global —
-    # pointing it at a to-be-deleted dir would leave it dangling (and
-    # the warm cache also makes later rounds measure steady state).
-    # uid-suffixed so shared machines don't collide on ownership; the
-    # pointer intentionally survives the bench (enable_compilation_cache
-    # is re-entrant — the next Client repoints it).
-    import os
-
-    uid = os.getuid() if hasattr(os, "getuid") else "u"
-    cache_dir = os.path.join(tempfile.gettempdir(),
-                             f"netsdb_ab_cache_{uid}")
+    # (every round's Client shares the one process-wide compile cache —
+    # config.enable_compilation_cache — so the per-round roots deleted
+    # below take no compiled code with them and later rounds measure
+    # steady state)
     def one_round(advisor_on: bool = True, force_block=None):
         root = tempfile.mkdtemp(prefix="ab_bench_")
         try:
-            client = Client(Configuration(
-                root_dir=root, compilation_cache_dir=cache_dir))
+            client = Client(Configuration(root_dir=root))
             if advisor_on:
                 client.set_placement_advisor(advisor, key=job)
             model = FFModel(db="ab")
@@ -221,8 +212,6 @@ def bench_batch_distribution_ab(width: int = 768, batch: int = 4096,
     Weights are replicated explicitly; only ``inputs`` consults the
     advisor. Each measured round runs ``reps`` inferences (amortizing
     per-job dispatch overhead) under a warm compile cache."""
-    import os
-
     import jax
 
     from netsdb_tpu.parallel.placement import Placement
@@ -242,17 +231,13 @@ def bench_batch_distribution_ab(width: int = 768, batch: int = 4096,
     wo = rng.standard_normal((labels, width)).astype(np.float32) * 0.02
     bo = rng.standard_normal((labels,)).astype(np.float32) * 0.01
     x = rng.standard_normal((batch, width)).astype(np.float32)
-    uid = os.getuid() if hasattr(os, "getuid") else "u"
-    cache_dir = os.path.join(tempfile.gettempdir(),
-                             f"netsdb_ab_cache_{uid}")
     wpl = {n: Placement((("data", 0),), (None, None))
            for n in ("w1", "b1", "wo", "bo")}
 
     def one_round(placement_override=None):
         root = tempfile.mkdtemp(prefix="ab_batch_")
         try:
-            client = Client(Configuration(
-                root_dir=root, compilation_cache_dir=cache_dir))
+            client = Client(Configuration(root_dir=root))
             if placement_override is None:
                 client.set_placement_advisor(advisor, key=job)
             model = FFModel(db="ab", block=(256, 256))
@@ -316,8 +301,6 @@ def bench_fusion_ab(rows: int = 120_000, spine: int = 6,
     the measured wall recorded against the arm — "first run explores,
     later runs serve the measured winner" (the reference's
     self-learning loop, applied to plan compilation)."""
-    import os
-
     import jax
 
     from netsdb_tpu.learning.advisor import fusion_candidates
@@ -330,9 +313,6 @@ def bench_fusion_ab(rows: int = 120_000, spine: int = 6,
     advisor = PlacementAdvisor(cands, hdb)
     job = "ab-fusion"
     rng = np.random.default_rng(seed)
-    uid = os.getuid() if hasattr(os, "getuid") else "u"
-    cache_dir = os.path.join(tempfile.gettempdir(),
-                             f"netsdb_ab_cache_{uid}")
     li = {
         "l_shipdate": rng.integers(19940101, 19950101, rows,
                                    dtype=np.int32),
@@ -363,7 +343,6 @@ def bench_fusion_ab(rows: int = 120_000, spine: int = 6,
         root = tempfile.mkdtemp(prefix="ab_fusion_")
         try:
             cfg = Configuration(root_dir=root,
-                                compilation_cache_dir=cache_dir,
                                 fusion_cost_source="static")
             cfg.plan_fusion = bool(arm.specs["plan_fusion"])
             client = Client(cfg)
@@ -418,8 +397,6 @@ def bench_mapper_ab(rows: int = 120_000, spine: int = 6,
     resident Apply chain alone (where the DP's segmentation can
     differ), ``shape="mixed"`` the same mixed paged/resident DAG
     :func:`bench_fusion_ab` measures."""
-    import os
-
     import jax
 
     from netsdb_tpu.learning.advisor import mapper_candidates
@@ -432,9 +409,6 @@ def bench_mapper_ab(rows: int = 120_000, spine: int = 6,
     advisor = PlacementAdvisor(cands, hdb)
     job = f"ab-mapper:{shape}"
     rng = np.random.default_rng(seed)
-    uid = os.getuid() if hasattr(os, "getuid") else "u"
-    cache_dir = os.path.join(tempfile.gettempdir(),
-                             f"netsdb_ab_cache_{uid}")
     li = {
         "l_shipdate": rng.integers(19940101, 19950101, rows,
                                    dtype=np.int32),
@@ -467,7 +441,6 @@ def bench_mapper_ab(rows: int = 120_000, spine: int = 6,
         root = tempfile.mkdtemp(prefix="ab_mapper_")
         try:
             cfg = Configuration(root_dir=root,
-                                compilation_cache_dir=cache_dir,
                                 fusion_cost_source="static")
             cfg.fusion_mapper = str(arm.specs["fusion_mapper"])
             client = Client(cfg)
@@ -549,14 +522,6 @@ def bench_distribution_ab(scale: int = 16, rounds: int = 4,
     tables = tables_from_rows(tpch.generate(scale=scale, seed=seed))
     chosen = []
     applied_labels = []
-    # one STABLE compile cache across rounds (same discipline as
-    # bench_placement_ab): without it the explore rounds measure cold
-    # compiles, not placements — the r2 autotune noise trap
-    import os
-
-    uid = os.getuid() if hasattr(os, "getuid") else "u"
-    cache_dir = os.path.join(tempfile.gettempdir(),
-                             f"netsdb_ab_cache_{uid}")
 
     def one_round(placement_override=None):
         """One job under either an explicit placement (warmup) or the
@@ -567,8 +532,7 @@ def bench_distribution_ab(scale: int = 16, rounds: int = 4,
 
         root = tempfile.mkdtemp(prefix="ab_dist_")
         try:
-            client = Client(Configuration(
-                root_dir=root, compilation_cache_dir=cache_dir))
+            client = Client(Configuration(root_dir=root))
             if placement_override is None:
                 client.set_placement_advisor(advisor, key=job)
             client.create_database("d")

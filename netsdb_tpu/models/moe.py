@@ -1,7 +1,7 @@
 """Mixture-of-experts layer with expert parallelism.
 
 No reference analogue (netsDB has no experts, SURVEY §2.6 row
-"TP/SP/EP … absent"); added so the framework's parallelism taxonomy is
+"TP/SP/EP … absent"); added so the framework's parallelism classification is
 complete. Top-1 token routing with a capacity limit, the classic
 dispatch/combine einsum formulation: dispatch (tokens→expert slots) and
 combine (expert outputs→tokens) are one-hot tensors, so expert compute

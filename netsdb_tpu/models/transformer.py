@@ -15,6 +15,7 @@ x: (batch, seq, embed).
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -87,10 +88,12 @@ class TransformerLayerModel:
         return jnp.einsum("bsf,fe->bse", h, p.w_down, precision=_HI)
 
     def forward(self, p: TransformerLayerParams, x: jax.Array,
-                causal: bool = True) -> jax.Array:
-        """Single-chip forward."""
+                causal: bool = True, impl: Optional[str] = None
+                ) -> jax.Array:
+        """Single-chip forward. ``impl`` picks the attention core
+        (``ops.attention.attention_dispatch``; None auto-selects)."""
         a = mha_forward(self._ln(x), p.w_qkv, p.w_out, self.num_heads,
-                        causal=causal)
+                        causal=causal, impl=impl)
         x = x + a
         return x + self._mlp(self._ln(x), p)
 

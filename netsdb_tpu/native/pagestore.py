@@ -16,21 +16,17 @@ import numpy as np
 _POLICIES = {"lru": 0, "mru": 1, "random": 2}
 
 _lib = None
-_lib_err: Optional[str] = None
 
 
 def _load():
-    global _lib, _lib_err
-    if _lib is not None or _lib_err is not None:
+    """The built library; a failed build raises ``NativeBuildError``
+    with the compiler's message (no silent Python stand-in)."""
+    global _lib
+    if _lib is not None:
         return _lib
-    try:
-        from netsdb_tpu.native.build import build_library
+    from netsdb_tpu.native.build import build_library
 
-        path = build_library()
-        lib = ctypes.CDLL(path)
-    except Exception as e:  # toolchain missing → pure-Python fallback
-        _lib_err = str(e)
-        return None
+    lib = ctypes.CDLL(build_library())
     lib.ps_create.restype = ctypes.c_void_p
     lib.ps_create.argtypes = [ctypes.c_uint64, ctypes.c_uint64,
                               ctypes.c_char_p, ctypes.c_int]
@@ -64,7 +60,15 @@ def _load():
 
 
 def native_available() -> bool:
-    return _load() is not None
+    """Explicit probe (tests skip on it): False only when the
+    toolchain cannot build the library."""
+    from netsdb_tpu.native.build import NativeBuildError
+
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 class NativePageStore:
@@ -74,8 +78,6 @@ class NativePageStore:
                  evict_watermark: Optional[int] = None,
                  background_flush: bool = False):
         lib = _load()
-        if lib is None:
-            raise RuntimeError(f"native page store unavailable: {_lib_err}")
         os.makedirs(spill_dir, exist_ok=True)
         watermark = evict_watermark or int(pool_bytes * 0.8)
         self._lib = lib

@@ -2,35 +2,30 @@
 
 Columnar ingestion of TPC-H dbgen files — the C++ role of the
 reference's ``tpchDataLoader.cc``, returning numpy columns instead of
-per-row objects (the array form the TPU path wants). Falls back to
-None when the toolchain is unavailable; callers keep the pure-Python
-row parser as the portable path.
+per-row objects (the array form the TPU path wants). A failed build
+raises ``NativeBuildError`` with the compiler's message; callers that
+can do without the library ask :func:`available` first.
 """
 
 from __future__ import annotations
 
 import ctypes
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 import numpy as np
 
 _lib = None
-_lib_err: Optional[str] = None
 
 _TYPE_CODES = {int: 0, float: 1, str: 2}
 
 
 def _load():
-    global _lib, _lib_err
-    if _lib is not None or _lib_err is not None:
+    global _lib
+    if _lib is not None:
         return _lib
-    try:
-        from netsdb_tpu.native.build import build_library
+    from netsdb_tpu.native.build import build_library
 
-        lib = ctypes.CDLL(build_library("tblparse"))
-    except Exception as e:
-        _lib_err = str(e)
-        return None
+    lib = ctypes.CDLL(build_library("tblparse"))
     lib.tp_parse.restype = ctypes.c_void_p
     lib.tp_parse.argtypes = [ctypes.c_char_p, ctypes.c_int,
                              ctypes.POINTER(ctypes.c_int)]
@@ -52,18 +47,22 @@ def _load():
 
 
 def available() -> bool:
-    return _load() is not None
+    """False only when the toolchain cannot build the library."""
+    from netsdb_tpu.native.build import NativeBuildError
+
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 def parse_columnar(path: str, schema: List[Tuple[str, type]]
-                   ) -> Optional[Dict[str, np.ndarray]]:
+                   ) -> Dict[str, np.ndarray]:
     """Parse a .tbl file into {column: array} (int64 / float64 /
-    object-dtype strings). Returns None when the native library is
-    unavailable; raises ValueError on malformed input (same contract as
-    the Python parser)."""
+    object-dtype strings). Raises ValueError on malformed input (same
+    contract as the Python parser)."""
     lib = _load()
-    if lib is None:
-        return None
     types = (ctypes.c_int * len(schema))(
         *[_TYPE_CODES[t] for _, t in schema])
     h = lib.tp_parse(path.encode(), len(schema), types)

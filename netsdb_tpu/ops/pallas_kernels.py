@@ -12,7 +12,8 @@ round-tripping (S x S) logits through HBM.
 (batch*heads, q_blocks, k_blocks) with the k-block dimension innermost
 (sequential on TPU), accumulators (m, l, acc) in VMEM scratch carried
 across k iterations, causal blocks skipped entirely when fully masked.
-Falls back to interpret mode off-TPU so tests run on CPU.
+Interpret mode on the CPU backend only (tests); see
+``ops.common.pallas_interpret``.
 """
 
 from __future__ import annotations
@@ -184,9 +185,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"seq {s} shares no usable block size with requested blocks "
             f"(gcd gives {block_q}, {block_k}; need >= 8 sublanes)")
     if interpret is None:
-        from netsdb_tpu.ops.common import on_tpu
+        from netsdb_tpu.ops.common import pallas_interpret
 
-        interpret = not on_tpu()
+        interpret = pallas_interpret()
     scale = scale if scale is not None else d ** -0.5
     bh = b * h
     qf = _prescale_q(q.reshape(bh, s, d), scale)
@@ -241,6 +242,15 @@ def _vmem(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.VMEM(shape, dtype)
+
+
+def _smem_spec():
+    """Whole-array scalar operand: lives in SMEM and is read with
+    scalar loads, instead of a vector load + extract from a padded
+    VMEM tile."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ------------------------------------------------------- ring-step kernel
@@ -305,9 +315,9 @@ def flash_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
     bh, s_q, d = q.shape
     s_k = k.shape[1]
     if interpret is None:
-        from netsdb_tpu.ops.common import on_tpu
+        from netsdb_tpu.ops.common import pallas_interpret
 
-        interpret = not on_tpu()
+        interpret = pallas_interpret()
     scale = scale if scale is not None else d ** -0.5
     q = _prescale_q(q, scale)
     block_q = math.gcd(1024, s_q)
@@ -333,7 +343,7 @@ def flash_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
     acc2, l2, m2 = pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
-        in_specs=[pl.BlockSpec((1, 2), lambda b_, qi, ki: (0, 0)),
+        in_specs=[_smem_spec(),
                   qspec, kspec, kspec, qspec, lspec, lspec],
         out_specs=(qspec, lspec, lspec),
         out_shape=(shp(acc), shp(l), shp(m)),

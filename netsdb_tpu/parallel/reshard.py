@@ -146,7 +146,7 @@ def _step_program(kind: str, mesh, axis: str, dim: int, dim_to: int,
     building the shard_map per call would retrace per block (the
     difference between a collective move and a compile storm)."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def spec_at(d):
@@ -158,7 +158,7 @@ def _step_program(kind: str, mesh, axis: str, dim: int, dim_to: int,
         fn = shard_map(
             lambda v: jax.lax.all_gather(v, axis, axis=dim, tiled=True),
             mesh=mesh, in_specs=(spec_at(dim),),
-            out_specs=P(*([None] * ndim)), check_rep=False)
+            out_specs=P(*([None] * ndim)), check_vma=False)
     elif kind == "local_slice":
         def slice_local(v):
             idx = jax.lax.axis_index(axis)
@@ -167,13 +167,13 @@ def _step_program(kind: str, mesh, axis: str, dim: int, dim_to: int,
 
         fn = shard_map(slice_local, mesh=mesh,
                        in_specs=(P(*([None] * ndim)),),
-                       out_specs=spec_at(dim), check_rep=False)
+                       out_specs=spec_at(dim), check_vma=False)
     else:  # all_to_all
         fn = shard_map(
             lambda v: jax.lax.all_to_all(v, axis, split_axis=dim_to,
                                          concat_axis=dim, tiled=True),
             mesh=mesh, in_specs=(spec_at(dim),),
-            out_specs=spec_at(dim_to), check_rep=False)
+            out_specs=spec_at(dim_to), check_vma=False)
     return jax.jit(fn)
 
 

@@ -71,9 +71,8 @@ def _ring_attention_flash_local(q, k, v, axis_name: str, causal: bool,
                                 scale: float):
     """Per-device ring body folding each arriving k/v chunk with the
     pallas flash-carry kernel (``ops.pallas_kernels.flash_attention_step``)
-    instead of the naive XLA fold — per BASELINE.md the naive block fold
-    runs ~30 TFLOP/s where flash runs ~110, so this is where round 1
-    left ~3.5x on the table inside every ring step."""
+    instead of the naive XLA fold (which round-trips every step's
+    (s_local, s_local) logits through HBM)."""
     from netsdb_tpu.ops.pallas_kernels import flash_attention_step
 
     n_dev = jax.lax.psum(1, axis_name)

@@ -84,7 +84,7 @@ def _round_program(mesh, axis: str, n: int, kp: int):
     XLA program."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(a_blk, b_blk):
@@ -114,7 +114,7 @@ def _round_program(mesh, axis: str, n: int, kp: int):
 
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(axis, None), P(axis, None)),
-                   out_specs=P(axis, None), check_rep=False)
+                   out_specs=P(axis, None), check_vma=False)
     return jax.jit(fn)
 
 
@@ -358,7 +358,7 @@ def _grid_program(mesh, pr: int, pc: int, kp: int):
     1/(pr·pc) of A, of B and of C."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     steps = pr * pc
@@ -400,7 +400,7 @@ def _grid_program(mesh, pr: int, pc: int, kp: int):
 
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(*GRID_AXES), P(*GRID_AXES)),
-                   out_specs=P(*GRID_AXES), check_rep=False)
+                   out_specs=P(*GRID_AXES), check_vma=False)
     return jax.jit(fn)
 
 

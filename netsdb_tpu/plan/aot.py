@@ -16,8 +16,8 @@ PreCompiledWorkload.h``, consulted in ``QuerySchedulerServer.cc:
    process can load and run without the Python that built it — the
    shippable compiled plan (serve daemons, release bundles).
 
-Both are exercised by tests/test_aot.py; cold-vs-warm numbers live in
-BASELINE.md.
+Both are exercised by tests/test_aot.py; ``chip_smoke.py`` reports the
+cache's entry count and the cold/warm spawn-to-first-reply seconds.
 """
 
 from __future__ import annotations

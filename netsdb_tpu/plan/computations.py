@@ -7,7 +7,7 @@ trees of per-tuple C++ logic and compile themselves to TCAP strings.
 Here each node carries a traced-Python function over set values
 (``BlockedTensor``s or host objects); "compiling" is composing those
 functions into jit stages (``netsdb_tpu.plan.planner``), with XLA as the
-physical optimizer. The node taxonomy is kept 1:1 so every reference
+physical optimizer. The node vocabulary is kept 1:1 so every reference
 query has a structural analogue, and ``to_plan_string`` emits a
 TCAP-like textual dump (debuggability + test surface, standing in for
 ``src/logicalPlan``'s IR).

@@ -29,8 +29,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-_I32_SENTINEL = jnp.int32(-2147483648)
+# a HOST scalar: a jnp value here would create a device array at import
+# time, i.e. importing this package (as every client process does for
+# ColumnTable) would initialise a jax backend and claim the chip
+_I32_SENTINEL = np.int32(-2147483648)
 
 
 def _masked(values: jnp.ndarray, mask: Optional[jnp.ndarray],

@@ -125,17 +125,21 @@ _BROADCAST_HBM_FRACTION = 0.10
 
 
 def device_memory_bytes() -> int:
-    """Per-device memory for distribution planning: the live backend's
-    own number when it reports one, else the per-device-kind table."""
-    try:
-        import jax
+    """Per-device memory for distribution planning: the accelerator's
+    own ``bytes_limit``. The CPU backend reports none, so tests plan
+    against the cpu tuning table's host share; an accelerator that
+    reports none is an error rather than an assumed HBM size."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return int(tuning.get("device_hbm_bytes"))
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return int(tuning.get("device_hbm_bytes", "cpu"))
+    stats = dev.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise RuntimeError(
+            f"{dev.device_kind} reports no memory_stats()['bytes_limit']; "
+            f"cannot size broadcast-vs-repartition plans")
+    return int(stats["bytes_limit"])
 
 
 def plan_distribution(build_bytes: int, n_devices: int,

@@ -250,10 +250,14 @@ class ColumnTable:
                 "valid": None if self.valid is None else np.asarray(self.valid)}
 
     def __setstate__(self, state):
-        self.cols = {n: jnp.asarray(c) for n, c in state["cols"].items()}
+        # columns stay HOST numpy: unpickling happens in client
+        # processes too (a fetched result table), and a client must
+        # never initialise a jax backend — on a TPU host the daemon
+        # owns the chip. Device placement is the consumer's step
+        # (jit arguments / the staging pipeline upload on use).
+        self.cols = dict(state["cols"])
         self.dicts = state["dicts"]
-        v = state["valid"]
-        self.valid = None if v is None else jnp.asarray(v)
+        self.valid = state["valid"]
 
     # --- pytree protocol ----------------------------------------------
     # Registered below: a ColumnTable is a jit-traceable value (columns

@@ -803,7 +803,7 @@ class RemoteClient:
         first success wins. When the hedge wins, the primary's socket
         is force-closed so its worker thread (and the connection lock)
         are released promptly instead of waiting out a slow reply.
-        Reads are idempotent by taxonomy, so duplicated execution is
+        Reads are idempotent by classification, so duplicated execution is
         harmless; failures surface exactly like an unhedged attempt
         (the retry loop above classifies them).
 

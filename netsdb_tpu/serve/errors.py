@@ -1,4 +1,4 @@
-"""Typed failure taxonomy for the serve control plane.
+"""Typed failure hierarchy for the serve control plane.
 
 The reference surfaces every RPC failure as an ``errMsg`` string the
 caller string-matches (``src/communication/headers/PDBCommunicator.h``);

@@ -11,8 +11,8 @@ streamed block-by-block into device HBM (``jax.device_put`` per chunk),
 so working sets larger than host RAM or HBM flow through without ever
 materializing densely.
 
-Falls back to a pure-Python page dict when the native toolchain is
-unavailable.
+A failed native build is an error carrying the compiler's message;
+the pure-Python page dict below exists for ``force_python=True`` only.
 """
 
 from __future__ import annotations
@@ -28,7 +28,8 @@ from netsdb_tpu.utils.locks import TrackedLock
 
 
 class _PyPageBackend:
-    """Fallback backend with the same surface as NativePageStore.
+    """In-process backend with the same surface as NativePageStore
+    (explicit ``force_python=True`` only — no spill, no arena cap).
 
     Thread-safe like the native store (its C++ side is mutex-guarded):
     concurrent writers — two object-set appends no longer serialized by
@@ -328,17 +329,13 @@ class PagedTensorStore:
             self.backend = _PyPageBackend()
             self.native = False
         else:
-            try:
-                from netsdb_tpu.native.pagestore import NativePageStore
+            from netsdb_tpu.native.pagestore import NativePageStore
 
-                self.backend = NativePageStore(
-                    pool_bytes or config.shared_mem_bytes,
-                    os.path.join(config.data_dir, "pages"),
-                )
-                self.native = True
-            except Exception:
-                self.backend = _PyPageBackend()
-                self.native = False
+            self.backend = NativePageStore(
+                pool_bytes or config.shared_mem_bytes,
+                os.path.join(config.data_dir, "pages"),
+            )
+            self.native = True
 
     def _set_id(self, name: str) -> int:
         # MONOTONIC allocation: len()+1 would recycle the id of a live

@@ -205,6 +205,16 @@ class SetStore:
                     pool_bytes=self.config.page_pool_bytes)
             return self._page_store
 
+    def page_store_stats(self) -> Optional[Dict[str, Any]]:
+        """The paged arena's counters (hits/misses/evictions/spills/
+        loads/bytes) plus whether the native backend holds it; None
+        while no ``storage="paged"`` set has created the arena."""
+        with self._lock:
+            ps = self._page_store
+        if ps is None:
+            return None
+        return dict(ps.stats(), native=ps.native)
+
     def device_cache(self):
         """The cross-query device block cache (``storage/devcache.py``)
         backing warm repeat queries — one per store, budgeted by

@@ -121,7 +121,7 @@ def bench_attention(seq_lens: Sequence[int] = (1024, 2048, 4096, 8192),
             raise AssertionError(
                 f"flash kernel at seq={CEILING_SEQ} runs at {ratio:.3f}× "
                 f"of jax's reference kernel (< {CEILING_RATIO}); the "
-                f"attention-ceiling claim in BASELINE.md/"
+                f"attention-ceiling claim in "
                 f"ops/pallas_kernels.py must be re-validated")
     return out
 

@@ -883,7 +883,7 @@ def bench_fusion(spine: int = 12, dim_rows: int = 65_536,
       epilogue is one program over the merged state.
 
     Numbers from a CPU container measure dispatch/materialization
-    overhead, not TPU compute overlap — same caveat as BENCH_r06."""
+    overhead, not TPU compute overlap."""
     import contextlib as _ctx
     import shutil
     import tempfile

@@ -169,18 +169,18 @@ def parse_tbl(path: str, table: str) -> List[Dict[str, Any]]:
 
 def parse_tbl_columnar(path: str, table: str):
     """Columnar parse → {column: numpy array}. Uses the native C++
-    parser (``native/tblparse.cpp``) when available — the reference's
-    C++ loader role, an order of magnitude faster than row dicts — and
-    falls back to transposing the Python row parser."""
+    parser (``native/tblparse.cpp``) — the reference's C++ loader role,
+    an order of magnitude faster than row dicts — and, only where no
+    C++ toolchain exists, transposes the Python row parser (same
+    output, host-side either way)."""
     schema = _TBL_SCHEMAS.get(table)
     if schema is None:
         raise ValueError(f"unknown TPC-H table {table!r}; "
                          f"one of {sorted(_TBL_SCHEMAS)}")
     from netsdb_tpu.native import tblparse
 
-    cols = tblparse.parse_columnar(path, schema)
-    if cols is not None:
-        return cols
+    if tblparse.available():
+        return tblparse.parse_columnar(path, schema)
     import numpy as np
 
     rows = parse_tbl(path, table)

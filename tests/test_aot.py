@@ -80,24 +80,51 @@ def test_ff_export_round_trip(tmp_path):
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_compilation_cache_populates(tmp_path):
-    """A jit compiled under the cache config writes an entry a second
-    process can reuse (the PreCompiledWorkload behavior)."""
-    cache = str(tmp_path / "cc")
-    script = f"""
+_TWO_CLIENTS = """
+import sys
 import jax
-jax.config.update("jax_platforms", "cpu")
-from netsdb_tpu.config import Configuration, enable_compilation_cache
-cfg = Configuration(root_dir={str(tmp_path)!r},
-                    compilation_cache_dir={cache!r})
-enable_compilation_cache(cfg)
 import jax.numpy as jnp
+from netsdb_tpu.client import Client
+from netsdb_tpu.config import Configuration, enable_compilation_cache
+for root in sys.argv[1:]:
+    Client(Configuration(root_dir=root))
 out = jax.jit(lambda x: (x @ x.T).sum())(jnp.ones((64, 64)))
-print(float(out))
+assert float(out) == 64.0 ** 3
+print(enable_compilation_cache())
 """
+
+
+def _run_two_clients(tmp_path, env):
+    roots = [str(tmp_path / "r1"), str(tmp_path / "r2")]
+    proc = subprocess.run(
+        [sys.executable, "-c", _TWO_CLIENTS] + roots, check=True, env=env,
+        capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    for root in roots:
+        for _dir, subdirs, _files in os.walk(root):
+            assert "compile_cache" not in subdirs, _dir
+    return proc.stdout.strip().splitlines()[-1]
+
+
+def test_compilation_cache_follows_env(tmp_path):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, jax's own reading of it
+    stands: two Clients with different roots leave the directory where
+    the variable says, entries land there (a second process can reuse
+    them — the PreCompiledWorkload behavior), and no per-root cache
+    directory appears."""
+    cache = str(tmp_path / "cc")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache)
+    assert _run_two_clients(tmp_path, env) == cache
+    assert os.listdir(cache), "compilation cache is empty after a jit"
+
+
+def test_compilation_cache_fixed_path_when_unset(tmp_path):
+    """Unset, the cache is the one fixed git-ignored directory inside
+    the checkout, whatever the Clients' roots."""
+    from netsdb_tpu.config import COMPILE_CACHE_DIR
+
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    subprocess.run([sys.executable, "-c", script], check=True, env=env,
-                   cwd=os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))))
-    entries = os.listdir(cache)
-    assert entries, "compilation cache is empty after a jit"
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    assert _run_two_clients(tmp_path, env) == COMPILE_CACHE_DIR
+    assert os.listdir(COMPILE_CACHE_DIR)

@@ -147,8 +147,9 @@ def test_tuning_override_and_device_table():
     assert tuning.get("segment_dense_limit", kind) == 7
     tuning.clear_overrides()
     assert tuning.get("segment_dense_limit", kind) == base
-    # unknown device kinds fall back to defaults
-    assert tuning.get("join_lut_factor", "weird-accelerator") == 32.0
+    # an unknown device kind is an error, never another chip's numbers
+    with pytest.raises(LookupError, match="weird-accelerator"):
+        tuning.get("join_lut_factor", "weird-accelerator")
 
 
 # ------------------------------------------------- distribution choice

@@ -44,6 +44,22 @@ def test_hello_ping_and_stats(server):
     c.close()
 
 
+def test_ping_names_the_device(server):
+    """The first thing a client can ask a daemon is where it computes:
+    platform, kind and count exactly as jax reports them in the
+    daemon's process (here the suite's 8-device virtual CPU mesh)."""
+    import jax
+
+    _, addr = server
+    c = RemoteClient(addr)
+    dev = c.ping()["device"]
+    assert dev == {"platform": jax.devices()[0].platform,
+                   "device_kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())}
+    assert dev["platform"] == "cpu"
+    c.close()
+
+
 def test_client_address_dispatch(server):
     """Client(address=...) returns the thin RPC client — same facade."""
     _, addr = server

@@ -47,7 +47,7 @@ def _content(ctl, db, s):
     return sorted(r["i"] for r in ctl.library.get_set_iterator(db, s))
 
 
-# --- typed taxonomy ----------------------------------------------------
+# --- typed hierarchy ----------------------------------------------------
 
 def test_fatal_errors_are_not_retried(server):
     ctl, addr, _ = server
