@@ -5,8 +5,10 @@ ONE process owns all four chips (a daemon in-process, its client on a
 loopback socket), so nothing here competes for a device:
 
 1. a live daemon: ``create_set(placement=Placement.data_parallel(ndim=1))``
-   + ``send_table`` + ``q01_sink`` — the stored column's shards sit on 4
-   distinct devices and the result equals ``cq01`` on the same rows;
+   + ``send_table`` + ``q01_sink`` over the smoke's synthetic lineitem plus
+   one row (2,097,153: the row axis is padded) — the stored column's
+   shards sit on 4 distinct devices and the result equals ``cq01`` (one
+   device) and a NumPy evaluation of the same rows;
 2. the same daemon: FF inference under ``dryrun_multichip``'s placements
    (data x model = 2 x 2) at the flagship width equals the unplaced
    single-device output;
@@ -20,7 +22,8 @@ loopback socket), so nothing here competes for a device:
 Run by a builder on a four-chip host: ``python chip_multichip.py``. It is
 not part of the driver's check (``chip_smoke.py`` is). ``--dryrun-cpu``
 rehearses at tiny sizes on four virtual CPU devices
-(``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``).
+(``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``;
+``tests/test_chip_smoke.py`` runs that).
 Last stdout line: one JSON object; exit code 0 only if 1-4 passed.
 """
 
@@ -36,55 +39,95 @@ from typing import Any, Dict
 
 import numpy as np
 
-from chip_smoke import check
+import chip_smoke
+from chip_smoke import FOLD_RTOL, check, lineitem, q01_reference
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, "chip_smoke_out", "multichip")
 
-FULL = dict(features=1024, hidden=4096, labels=1024, block=(512, 512),
-            batch=16384, ring=(1, 8, 8192, 128), pool_ff=(256, 512, 64, 1024))
-DRYRUN = dict(features=64, hidden=128, labels=32, block=(16, 16),
-              batch=256, ring=(1, 2, 512, 128), pool_ff=(32, 64, 16, 64))
+# the smoke's sizes (FF width, lineitem rows) plus what only this script runs
+FULL = dict(chip_smoke.FULL, ring=(1, 8, 8192, 128),
+            pool_ff=(256, 512, 64, 1024))
+DRYRUN = dict(chip_smoke.DRYRUN, ring=(1, 2, 512, 128),
+              pool_ff=(32, 64, 16, 64))
 
 
-def placed_q01(client, ctl) -> Dict[str, Any]:
+def placed_q01(client, ctl, sz: Dict[str, Any]) -> Dict[str, Any]:
+    """The smoke's synthetic lineitem (2M rows at full size) plus one row
+    in a set placed over the four chips: q01_sink == cq01 on one device
+    == NumPy."""
     import jax
 
     from netsdb_tpu.parallel.placement import Placement
     from netsdb_tpu.relational import dag as rdag
     from netsdb_tpu.relational.queries import cq01
     from netsdb_tpu.relational.table import ColumnTable
-    from netsdb_tpu.workloads import tpch
 
-    rows = tpch.generate(scale=1, seed=4)["lineitem"]
+    rows = sz["li_rows"] + 1  # not a multiple of 4: the table is padded
+    li = lineitem(rows)
+    table = ColumnTable(li, {"l_returnflag": ["A", "N", "R"],
+                             "l_linestatus": ["F", "O"]})
     client.create_database("tpch")
     client.create_set("tpch", "lineitem", type_name="table",
                       placement=Placement.data_parallel(ndim=1))
-    client.send_table("tpch", "lineitem", rows)
+    client.send_table("tpch", "lineitem", table)
     col = ctl.library.get_table("tpch", "lineitem")["l_quantity"]
-    devices = sorted(s.device.id for s in col.addressable_shards)
+    check(col.shape[0] == rows + 3,
+          f"stored column has {col.shape[0]} rows, not {rows} + 3 padding")
+    shards = [(s.device.id, s.data.shape[0])
+              for s in col.addressable_shards]
+    devices = sorted(d for d, _ in shards)
     check(len(set(devices)) == len(jax.devices()) == 4,
           f"lineitem shards sit on devices {devices}")
+    check(all(n == (rows + 3) // 4 for _, n in shards),
+          f"uneven shards (device, rows): {shards}")
     got = rdag.run_query(client, rdag.q01_sink("tpch"))[0]
-    want = cq01({"lineitem": ColumnTable.from_rows(rows)})
+    check(np.asarray(got.mask()).all(), "q01: all 6 groups present")
     keys = [(got.dicts["l_returnflag"][int(rf)],
              got.dicts["l_linestatus"][int(ls)])
             for rf, ls in zip(np.asarray(got["l_returnflag"]),
                               np.asarray(got["l_linestatus"]))]
-    live = np.asarray(got.mask())
-    check(sorted(k for k, ok in zip(keys, live) if ok)
-          == [k for k, _ in want], "q01 group keys")
-    for key, vals in want:
+    single = cq01({"lineitem": table})  # one device, unplaced
+    check(sorted(keys) == [k for k, _ in single], "q01 group keys vs cq01")
+    numpy_ref = q01_reference(li)  # float64, group = returnflag*2+linestatus
+    names = ("sum_qty", "sum_base_price", "sum_disc_price", "sum_charge",
+             "sum_disc")
+    worst = 0.0
+    for key, vals in single:
         i = keys.index(key)
-        check(int(np.asarray(got["count"])[i]) == vals["count"],
-              f"q01 {key} count")
-        for name in ("sum_qty", "sum_base_price", "sum_disc_price",
-                     "sum_charge", "sum_disc"):
-            g = float(np.asarray(got[name])[i])
-            check(abs(g - vals[name]) <= 1e-5 * abs(vals[name]),
-                  f"q01 {key} {name}: {g} vs cq01 {vals[name]}")
-    return {"rows": len(rows), "shard_devices": devices,
-            "groups": len(want)}
+        g = ["A", "N", "R"].index(key[0]) * 2 + ["F", "O"].index(key[1])
+        count = int(np.asarray(got["count"])[i])
+        check(count == vals["count"] == numpy_ref["count"][g],
+              f"q01 {key} count {count} vs cq01 {vals['count']} vs NumPy "
+              f"{numpy_ref['count'][g]}")
+        for name in names:
+            v = float(np.asarray(got[name])[i])
+            for what, want in (("cq01", vals[name]),
+                               ("NumPy", float(numpy_ref[name][g]))):
+                rel = abs(v - want) / abs(want)
+                worst = max(worst, rel)
+                check(rel <= FOLD_RTOL,
+                      f"q01 {key} {name}: {v} vs {what} {want}")
+    return {"rows": rows, "table_bytes": sum(c.nbytes for c in li.values()),
+            "shard_rows": [n for _, n in shards],
+            "shard_devices": devices, "groups": len(single),
+            "max_rel_err": worst}
+
+
+def oversized_placement(dryrun: bool) -> Dict[str, Any]:
+    """A placement that names more devices than the process holds raises
+    on the chips; only the CPU backend collapses it to one device."""
+    from netsdb_tpu.parallel.placement import Placement
+
+    big = Placement((("data", 64),), ("data",))
+    if dryrun:
+        check(big.resolved_axes() == (("data", 1),), "CPU collapse")
+        return {"raised": False}
+    try:
+        axes = big.resolved_axes()
+    except ValueError as e:
+        return {"raised": True, "message": str(e)}
+    raise AssertionError(f"64-device placement resolved to {axes} on 4 chips")
 
 
 def ff_2x2(client, sz: Dict[str, Any]) -> Dict[str, Any]:
@@ -257,7 +300,9 @@ def main() -> int:
         client = RemoteClient(ctl.advertise_addr)
         check(client.ping()["device"]["count"] == len(devices),
               "the daemon sees every chip")
-        run("placed_q01", lambda: placed_q01(client, ctl))
+        run("oversized_placement",
+            lambda: oversized_placement(args.dryrun_cpu))
+        run("placed_q01", lambda: placed_q01(client, ctl, sz))
         run("ff_2x2", lambda: dict(ff_2x2(client, sz),
                                    **placed_weight_devices(ctl)))
         client.close()

@@ -423,8 +423,6 @@ COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_compile_cache")
 
-_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-
 
 def enable_compilation_cache() -> str:
     """Turn on jax's persistent compilation cache (reference: the
@@ -439,17 +437,14 @@ def enable_compilation_cache() -> str:
     directory."""
     import jax
 
-    if not os.environ.get(_CACHE_ENV):
-        from jax.experimental.compilation_cache import compilation_cache
-
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
-        compilation_cache.set_cache_dir(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     # cache everything: the queries this framework compiles are
     # worth persisting even when individually quick to build
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax names the flag after the variable, lower-cased
-    return getattr(jax.config, _CACHE_ENV.lower())
+    return jax.config.jax_compilation_cache_dir
 
 
 DEFAULT_CONFIG = Configuration()

@@ -244,15 +244,6 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _smem_spec():
-    """Whole-array scalar operand: lives in SMEM and is read with
-    scalar loads, instead of a vector load + extract from a padded
-    VMEM tile."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
 # ------------------------------------------------------- ring-step kernel
 
 def _flash_carry_kernel(off_ref, q_ref, k_ref, v_ref,
@@ -343,7 +334,7 @@ def flash_attention_step(q: jax.Array, k: jax.Array, v: jax.Array,
     acc2, l2, m2 = pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
-        in_specs=[_smem_spec(),
+        in_specs=[pl.BlockSpec((1, 2), lambda b_, qi, ki: (0, 0)),
                   qspec, kspec, kspec, qspec, lspec, lspec],
         out_specs=(qspec, lspec, lspec),
         out_shape=(shp(acc), shp(l), shp(m)),

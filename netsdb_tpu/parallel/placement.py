@@ -20,12 +20,13 @@ materializes the same ``Mesh`` for equal axis descriptions (cached), so
 NamedShardings built from one Placement compare equal across calls —
 a requirement for jit cache hits.
 
-Degraded-hardware rule: if the process has fewer devices than the
-declared mesh (the single-chip bench vs the 8-device test mesh), the
-placement collapses to the trivial single-device mesh — the same
-fallback the reference dispatcher makes when a set cannot be
-partitioned by the preferred policy (``PartitionPolicy.h:40``,
-DefaultPolicy). Data stays correct; parallelism degrades.
+Fewer devices than the declared mesh: on the CPU backend (tests, whose
+virtual mesh has 8 devices) the placement collapses to the trivial
+single-device mesh — the fallback the reference dispatcher makes when a
+set cannot be partitioned by the preferred policy
+(``PartitionPolicy.h:40``, DefaultPolicy). On an accelerator it raises:
+a set declared over four chips must not quietly compute on one. Size 0
+("all devices on this axis") is how a placement adapts to the machine.
 """
 
 from __future__ import annotations
@@ -93,9 +94,10 @@ class Placement:
     # --- materialization ----------------------------------------------
     def resolved_axes(self,
                       n_devices: Optional[int] = None) -> Tuple[Tuple[str, int], ...]:
-        """Axis sizes with 0 resolved to "the remaining devices" and the
-        whole shape collapsed to 1s when the process can't supply enough
-        devices (degraded-hardware rule in the module docstring)."""
+        """Axis sizes with 0 resolved to "the remaining devices". When
+        the process can't supply enough devices the whole shape collapses
+        to 1s on the CPU backend and raises on any other (module
+        docstring)."""
         n = n_devices if n_devices is not None else len(jax.devices())
         fixed = int(np.prod([s for _, s in self.axes if s > 0] or [1]))
         free = sum(1 for _, s in self.axes if s == 0)
@@ -115,6 +117,12 @@ class Placement:
                 size = max(1, remaining)
             out.append((name, size))
         if int(np.prod([s for _, s in out])) > n:
+            if jax.default_backend() != "cpu":
+                raise ValueError(
+                    f"placement {self.label()} needs "
+                    f"{int(np.prod([s for _, s in out]))} devices; this "
+                    f"process has {n} on backend "
+                    f"{jax.default_backend()!r} (size 0 = all devices)")
             return tuple((name, 1) for name, _ in self.axes)
         return tuple(out)
 

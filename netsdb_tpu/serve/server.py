@@ -890,6 +890,8 @@ class ServeController:
         self._jobs: Dict[int, Dict[str, Any]] = {}
         self._jobs_lock = TrackedLock("ServeController._jobs_lock")
         self._started = time.monotonic()  # uptime only — never wall
+        # what jax computes on; None until start() has taken the device
+        self.device: Optional[Dict[str, Any]] = None
         self._stop = threading.Event()
         self._listener: Optional[socket.socket] = None
         # live accepted sockets — shutdown() half-closes them so a

@@ -85,12 +85,12 @@ import sys
 import jax
 import jax.numpy as jnp
 from netsdb_tpu.client import Client
-from netsdb_tpu.config import Configuration, enable_compilation_cache
+from netsdb_tpu.config import Configuration
 for root in sys.argv[1:]:
     Client(Configuration(root_dir=root))
 out = jax.jit(lambda x: (x @ x.T).sum())(jnp.ones((64, 64)))
 assert float(out) == 64.0 ** 3
-print(enable_compilation_cache())
+print(jax.config.jax_compilation_cache_dir)
 """
 
 

@@ -46,6 +46,18 @@ def test_placement_degrades_to_available_devices():
     assert _num_shards(x) == 1
 
 
+def test_placement_short_of_devices_raises_off_cpu(monkeypatch):
+    # on an accelerator a set declared over more chips than the process
+    # holds must not quietly compute on one
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    p = Placement((("data", 64),), ("data",))
+    with pytest.raises(ValueError, match="needs 64 devices"):
+        p.resolved_axes()
+    full = len(jax.devices())
+    assert Placement.data_parallel(ndim=1).resolved_axes() == (
+        ("data", full),)
+
+
 def test_placement_zero_means_all_devices():
     p = Placement.data_parallel(ndim=2)
     assert dict(p.resolved_axes())["data"] == len(jax.devices())
