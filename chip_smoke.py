@@ -15,11 +15,16 @@ is asserted before exit); the daemon child inherits the environment
 untouched and is the only process on the chip; the kernel child starts
 only after the daemon's exit code has been checked.
 
-Exit code 0 and a last stdout line ``{"ok": true, "device": {...}, ...}``
-only when every phase passed on a TPU. Any phase's failure is the
-script's failure (logs are kept under ``chip_smoke_out/`` and their tails
-printed). ``--dryrun-cpu`` is the explicit CPU rehearsal (tiny sizes,
-pallas interpret mode; its summary says ``"dryrun": true``) — without
+Exit code 0 and two stdout lines only when every phase passed on a TPU:
+first the report (one JSON object: per-phase ``ok`` and seconds,
+``spawn_to_first_reply_s``, compile-cache entries before and after; also
+kept as ``chip_smoke_out/summary.json``), then, LAST, the verdict with
+exactly these keys: ``{"ok": true, "device": {"platform": "tpu",
+"kind": ..., "count": ...}}``, the device as the daemon's jax reports it.
+Any phase's failure is the script's failure (logs are kept under
+``chip_smoke_out/`` and their tails printed), and then stdout stays
+empty. ``--dryrun-cpu`` is the explicit CPU rehearsal (tiny sizes,
+pallas interpret mode; its report says ``"dryrun": true``) — without
 that flag there is no CPU path: a daemon that reports any platform but
 ``tpu`` fails the run, naming what it found.
 
@@ -679,7 +684,10 @@ def main() -> int:
     }
     with open(os.path.join(OUT, "summary.json"), "w") as f:
         json.dump(summary, f, indent=1)
+    # the report first; the LAST line is the verdict alone, with exactly
+    # the keys the driver's check reads
     print(json.dumps(summary))
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
     return 0
 
 

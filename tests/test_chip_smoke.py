@@ -23,9 +23,14 @@ def test_dryrun_cpu_passes_every_phase(tmp_path):
                           env=env, cwd=REPO, capture_output=True,
                           text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-4000:]
-    summary = json.loads(proc.stdout.strip().splitlines()[-1])
+    summary, verdict = map(json.loads, proc.stdout.strip().splitlines())
+    # the last line is the verdict alone, with exactly the driver's keys
+    assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert isinstance(verdict["device"]["count"], int)
     assert summary["ok"] is True and summary["dryrun"] is True
-    assert summary["platform"] == summary["device"]["platform"] == "cpu"
+    assert summary["device"] == verdict["device"]
+    assert summary["platform"] == "cpu"
     assert list(summary["phases"]) == PHASES
     assert all(p["ok"] for p in summary["phases"].values())
     assert summary["spawn_to_first_reply_s"] > 0
