@@ -728,11 +728,6 @@ def _print_obs(stats, traces) -> None:
         print(f"{indent}{prof.get('qid')} [{prof.get('origin')}] "
               f"total={total * 1e3:.2f}ms "
               f"counters={prof.get('counters') or {}}")
-        hd = prof.get("host_device")
-        if hd:
-            print(f"{indent}  host/device: "
-                  f"host={hd['host_s'] * 1e3:.2f}ms "
-                  f"device_est={hd['device_est_s'] * 1e3:.2f}ms")
         if prof.get("meta"):
             print(f"{indent}  meta: {json.dumps(prof['meta'])}")
         for sp in prof.get("spans") or ():

@@ -36,7 +36,6 @@ headline renders.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -140,19 +139,22 @@ class ModelServing:
         c = self._client()
         db = self.model.db
         batch = np.asarray(batch, np.float32)
-        t0 = time.perf_counter()
-        c.send_matrix(db, self.input_set, batch, self.block)
-        reply = c._request(
-            MsgType.EXECUTE_COMPUTATIONS,
-            {"sinks": [self._sink()], "job_name": f"{db}-serve",
-             "materialize": True, "explain": bool(explain)},
-            codec=CODEC_PICKLE)
-        results = c._collect_results(reply["results"], True)
+        # ONE trace for the whole request: its three frames (ship the
+        # batch, execute, read the scores back) carry one query id, so
+        # the daemon's profile of each joins it; the span's self time
+        # is this side's work between and after the frames
+        with c.request_trace("models.score"):
+            c.send_matrix(db, self.input_set, batch, self.block)
+            reply = c._request(
+                MsgType.EXECUTE_COMPUTATIONS,
+                {"sinks": [self._sink()], "job_name": f"{db}-serve",
+                 "materialize": True, "explain": bool(explain)},
+                codec=CODEC_PICKLE)
+            results = c._collect_results(reply["results"], True)
         value = next(iter(results.values()))
         rows = int(batch.shape[0])
         obs.REGISTRY.counter("models.batches_scored").inc()
         obs.REGISTRY.counter("models.rows_scored").inc(rows)
-        obs.add("models.score_s", time.perf_counter() - t0)
         if explain:
             return value, reply.get("shard_operators")
         return value

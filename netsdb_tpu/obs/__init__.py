@@ -31,6 +31,8 @@ from netsdb_tpu.obs.trace import (  # noqa: F401
     Span,
     TraceRing,
     add,
+    adopt,
+    capture,
     current_trace,
     enabled,
     new_query_id,
@@ -43,7 +45,7 @@ from netsdb_tpu.obs.trace import (  # noqa: F401
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "registry", "DEFAULT_RING", "QidSampler", "QueryTrace", "Span",
-    "TraceRing", "add", "attrib", "current_trace", "enabled",
-    "new_query_id", "operators", "sample_qid", "set_enabled", "span",
-    "trace",
+    "TraceRing", "add", "adopt", "attrib", "capture", "current_trace",
+    "enabled", "new_query_id", "operators", "sample_qid", "set_enabled",
+    "span", "trace",
 ]

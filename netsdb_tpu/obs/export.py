@@ -60,6 +60,13 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("serve.requests", "workload frames dispatched (outcome time; "
                            "OBS frames excluded)"),
         ("serve.requests_ok", "workload frames answered without an ERR"),
+        ("serve.wire.bytes_in", "bytes of workload request frames read "
+                                "off the socket (header + segment "
+                                "table + body + segments; OBS frames "
+                                "excluded)"),
+        ("serve.wire.bytes_out", "bytes of the reply and stream frames "
+                                 "that answered workload requests (OBS "
+                                 "frames' replies excluded)"),
         ("serve.idem.memory_hits", "idempotent retries answered from "
                                    "the in-memory reply cache"),
         ("serve.idem.persist_hits", "idempotent retries answered from "

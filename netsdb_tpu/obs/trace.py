@@ -14,10 +14,26 @@ chunks, traces triggered, cache hits).
 Propagation is a ``contextvars.ContextVar``: the serve handler (or the
 client's request path) installs the trace, and every instrumented layer
 below reads it back with :func:`current_trace` — zero plumbing through
-call signatures. Worker threads (staging) don't inherit the context;
-they capture the trace at stream construction on the consumer's thread
-and add COUNTERS only (cross-thread span nesting would lie about
-concurrency).
+call signatures. Worker threads don't inherit the context. Staging
+workers capture the trace at stream construction on the consumer's
+thread and add COUNTERS only; a thread that does a piece of the
+request itself (the routed ingest's slot threads) is handed the trace
+with :func:`capture` / :func:`adopt`, and its spans name the span that
+was open where the trace was captured as their ``parent``.
+
+One logical request is ONE trace: a caller that sends several frames
+(``ModelServing.score``: SEND_MATRIX, EXECUTE_COMPUTATIONS, then the
+GET_TENSOR that reads the scores back) opens the trace around all of
+them and the wire client stamps its query id on every frame, so the
+daemon's profile of each frame shares it.
+
+Every span carries an ``id`` and the ``parent`` that caused it, and
+every profile a wall-clock anchor ``t0_unix_ns`` (``time.time_ns()``
+read once when the trace opens). Offsets and durations stay on
+``perf_counter``; the anchor only ALIGNS profiles of different
+processes of one host with each other and with a device trace — a span
+begins at ``t0_unix_ns + start_s * 1e9`` — and is never compared with
+a deadline.
 
 Cost discipline: tracing is ALWAYS ON (``config.obs_enabled`` is the
 kill switch). The no-trace fast path of :func:`span` is one context-var
@@ -29,18 +45,19 @@ fold stream (< 3% is the budget).
 Completed traces land in a bounded :class:`TraceRing` — the daemon
 keeps the last N query profiles for the ``GET_TRACE`` frame; client
 processes keep their own ring (:data:`DEFAULT_RING`) for local
-introspection. All clocks are ``time.perf_counter`` — monotonic, never
-wall (the serve clock discipline, enforced by the static checks).
+introspection. Everything timed is ``time.perf_counter`` — monotonic,
+never wall (the serve clock discipline, enforced by the static checks).
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import threading
 import time
 import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from netsdb_tpu.obs import metrics as _metrics
 from netsdb_tpu.utils.locks import TrackedLock
@@ -124,14 +141,20 @@ def sample_qid(sample: int = 1) -> Optional[str]:
 
 class Span:
     """One timed region inside a trace. ``start_s`` is the offset from
-    the trace's own start (monotonic deltas — profile timelines line up
-    without any cross-process clock agreement)."""
+    the trace's own start (monotonic deltas; the profile's
+    ``t0_unix_ns`` places it on the wall clock). ``id`` numbers the
+    span within its trace from 1; ``parent`` is the id of the span
+    that was open on the recording thread when this one began (for a
+    thread that :func:`adopt`-ed the trace: the span open where it was
+    captured), 0 for a span directly under the trace."""
 
-    __slots__ = ("name", "category", "start_s", "duration_s", "depth",
-                 "counters")
+    __slots__ = ("id", "parent", "name", "category", "start_s",
+                 "duration_s", "depth", "counters")
 
     def __init__(self, name: str, category: str, start_s: float,
-                 depth: int):
+                 depth: int, id: int = 0, parent: int = 0):
+        self.id = id
+        self.parent = parent
         self.name = name
         self.category = category
         self.start_s = start_s
@@ -140,7 +163,8 @@ class Span:
         self.counters: Dict[str, float] = {}
 
     def as_dict(self) -> Dict[str, Any]:
-        d: Dict[str, Any] = {"name": self.name, "category": self.category,
+        d: Dict[str, Any] = {"id": self.id, "parent": self.parent,
+                             "name": self.name, "category": self.category,
                              "start_s": self.start_s,
                              "duration_s": self.duration_s,
                              "depth": self.depth}
@@ -153,9 +177,9 @@ class QueryTrace:
     """All spans + counters of one logical query on one side of the
     wire. ``origin`` says which side ("client"/"server"/"local").
     Thread-safe for counter adds and span records (staging threads
-    report into the consumer's trace); span DEPTH tracks per-thread
-    nesting so concurrent reporters can't corrupt each other's
-    stacks."""
+    report into the consumer's trace); the open span (``parent``) and
+    its DEPTH are tracked per thread so concurrent reporters can't
+    corrupt each other's stacks."""
 
     def __init__(self, qid: str, origin: str = "local",
                  ring: Optional["TraceRing"] = None):
@@ -163,25 +187,35 @@ class QueryTrace:
         self.origin = origin
         self._ring = ring
         self._t0 = time.perf_counter()
+        # alignment only, never a deadline (module docstring)
+        self._t0_unix_ns = time.time_ns()
+        self._ids = itertools.count(1)
         self._mu = threading.Lock()
         self._spans: List[Span] = []
         self._counters: Dict[str, float] = {}
         self._meta: Dict[str, Any] = {}
         self._sections: Dict[str, Any] = {}
-        self._depth = threading.local()
+        # per thread: (id of the open span, depth of a span begun now)
+        self._open = threading.local()
         self.total_s: Optional[float] = None  # set by finish()
 
     # --- spans --------------------------------------------------------
+    def here(self) -> Tuple[int, int]:
+        """(id of the span open on this thread, the depth a span begun
+        now gets); (0, 0) under no span."""
+        return getattr(self._open, "v", (0, 0))
+
     @contextlib.contextmanager
     def span(self, name: str, category: str = "") -> Iterator[Span]:
-        depth = getattr(self._depth, "v", 0)
-        self._depth.v = depth + 1
-        sp = Span(name, category, time.perf_counter() - self._t0, depth)
+        outer = self.here()
+        sp = Span(name, category, time.perf_counter() - self._t0,
+                  outer[1], next(self._ids), outer[0])
+        self._open.v = (sp.id, outer[1] + 1)
         try:
             yield sp
         finally:
             sp.duration_s = (time.perf_counter() - self._t0) - sp.start_s
-            self._depth.v = depth
+            self._open.v = outer
             with self._mu:
                 self._spans.append(sp)
 
@@ -191,7 +225,8 @@ class QueryTrace:
         that finished before the trace could open)."""
         if start_s is None:
             start_s = (time.perf_counter() - self._t0) - duration_s
-        sp = Span(name, category, start_s, getattr(self._depth, "v", 0))
+        parent, depth = self.here()
+        sp = Span(name, category, start_s, depth, next(self._ids), parent)
         sp.duration_s = duration_s
         if counters:
             sp.counters.update(counters)
@@ -203,8 +238,10 @@ class QueryTrace:
         finished before the trace could open (the serve frame decode):
         a span then :meth:`record`-ed at offset 0 occupies real
         timeline ahead of the first live span instead of overlapping
-        it, and ``total_s`` covers it."""
+        it, and ``total_s`` covers it. The wall-clock anchor moves with
+        the start."""
         self._t0 -= float(seconds)
+        self._t0_unix_ns -= int(float(seconds) * 1e9)
 
     # --- counters -----------------------------------------------------
     def add(self, counter: str, n: float = 1) -> None:
@@ -239,17 +276,7 @@ class QueryTrace:
         return prof
 
     def profile(self) -> Dict[str, Any]:
-        """Msgpack-safe profile dict — what GET_TRACE ships.
-
-        ``host_device`` splits the query's total into an estimated
-        device share and the host remainder. The device share sums the
-        counters the instrumented layers already measure —
-        ``device.est_s`` (time inside dispatched jitted steps, the
-        ``scan_slope``-style wall timing around each fold/tensor step)
-        plus ``stage.wait_s`` (time the consumer blocked on a staged
-        host→device upload). It is an ESTIMATE (dispatch-inclusive;
-        exact device timelines come from the opt-in per-qid
-        ``jax.profiler`` session whose directory rides ``meta``)."""
+        """Msgpack-safe profile dict — what GET_TRACE ships."""
         with self._mu:
             spans = [s.as_dict() for s in
                      sorted(self._spans, key=lambda s: s.start_s)]
@@ -257,18 +284,12 @@ class QueryTrace:
             meta = dict(self._meta)
             sections = dict(self._sections)
         out: Dict[str, Any] = {"qid": self.qid, "origin": self.origin,
+                               "t0_unix_ns": self._t0_unix_ns,
                                "total_s": self.total_s, "spans": spans,
                                "counters": counters}
         out.update(sections)
         if meta:
             out["meta"] = meta
-        if self.total_s is not None:
-            dev = (counters.get("device.est_s", 0.0)
-                   + counters.get("stage.wait_s", 0.0))
-            dev = min(dev, self.total_s)
-            out["host_device"] = {
-                "device_est_s": dev,
-                "host_s": max(self.total_s - dev, 0.0)}
         return out
 
 
@@ -280,16 +301,18 @@ class TraceRing:
         self._mu = TrackedLock("TraceRing._mu")
         self._cap = max(int(capacity), 1)
         self._items: List[Dict[str, Any]] = []
-        # sections that arrived BEFORE their profile ringed (the
-        # reply-before-ring race, merge_section docstring); qid →
-        # {section: payload}, oldest evicted first
+        # the newest sections, for profiles that ring AFTER their
+        # section arrived (the reply-before-ring race, merge_section
+        # docstring); qid → {section: payload}, oldest evicted first
         self._pending_cap = max(int(pending_capacity), 1)
         self._pending: Dict[str, Dict[str, Any]] = {}
 
     def push(self, profile: Dict[str, Any]) -> None:
         with self._mu:
             qid = profile.get("qid")
-            pend = self._pending.pop(qid, None) if qid else None
+            # get, not pop: one query id may ring SEVERAL profiles (a
+            # request of several frames), and each joins the section
+            pend = self._pending.get(qid) if qid else None
             if pend:
                 profile = {**profile, **pend}
             self._items.append(profile)
@@ -315,11 +338,12 @@ class TraceRing:
         NO causal ordering protects this: the reply goes out INSIDE
         the trace context (``_dispatch_traced``), the ring push happens
         at trace finish AFTER it — so a fast client shipping on its
-        own connection can beat the push. An unmatched section is
-        therefore BUFFERED (bounded, oldest-evicted) and
-        :meth:`push` folds it into the profile when it lands; only a
-        qid that never rings (rotated out, never sampled) stays
-        unmatched.
+        own connection can beat the push. The section is therefore
+        also BUFFERED (bounded, oldest-evicted) and :meth:`push`
+        folds it into every profile of the qid that lands later — the
+        last frame's profile of a several-frame request rings after
+        the client has shipped; only a qid that never rings (rotated
+        out, never sampled) stays unmatched.
 
         COPY-ON-MERGE: ``last``/``find`` hand out the ringed dicts
         themselves (a GET_TRACE reply may be mid-serialization on
@@ -335,10 +359,9 @@ class TraceRing:
                     merged[section] = payload
                     self._items[i] = merged
                     hit = True
-            if not hit:
-                self._pending.setdefault(qid, {})[section] = payload
-                while len(self._pending) > self._pending_cap:
-                    self._pending.pop(next(iter(self._pending)))
+            self._pending.setdefault(qid, {})[section] = payload
+            while len(self._pending) > self._pending_cap:
+                self._pending.pop(next(iter(self._pending)))
             return hit
 
     def clear(self) -> None:
@@ -360,6 +383,31 @@ _current: "contextvars.ContextVar[Optional[QueryTrace]]" = \
 
 def current_trace() -> Optional[QueryTrace]:
     return _current.get()
+
+
+def capture() -> Optional[Tuple[QueryTrace, Tuple[int, int]]]:
+    """The current trace and the span open on this thread, for a
+    worker thread to :func:`adopt` (None without a trace)."""
+    tr = _current.get()
+    return None if tr is None else (tr, tr.here())
+
+
+@contextlib.contextmanager
+def adopt(captured) -> Iterator[None]:
+    """Install a :func:`capture`-d trace on THIS thread for the
+    duration: spans recorded here join it as children of the span that
+    was open where it was captured. A no-op for None."""
+    if captured is None:
+        yield
+        return
+    tr, here = captured
+    token = _current.set(tr)
+    tr._open.v = here
+    try:
+        yield
+    finally:
+        tr._open.v = (0, 0)
+        _current.reset(token)
 
 
 @contextlib.contextmanager

@@ -255,7 +255,6 @@ def _run_fold_once(fold, pc, resident, placement, step_jit):
                 # split the profile derives (obs/trace.profile)
                 sp.counters["chunks"] = n
                 sp.counters["device_est_s"] = dev_s
-            obs.add("device.est_s", dev_s)
             obs.operators.op_add("device_est_s", dev_s)
             obs.operators.op_add("chunks", n)
             obs.attrib.account("executor.chunks", n,
@@ -379,7 +378,6 @@ def _run_fold_grace(fold, pc, rest, bi, build_pc, placement, step_jit):
             # same device-estimate + attribution feed as every other
             # executor loop — grace joins must not read as 100% host
             # time, and a join-heavy tenant's executor.chunks must book
-            obs.add("device.est_s", dev_s)
             obs.operators.op_add("device_est_s", dev_s)
             obs.operators.op_add("chunks", nchunks)
             obs.operators.op_add("pairs", npairs)
@@ -637,7 +635,6 @@ def _run_tensor_stream(node, tfold, in_vals, src, step_jit):
             if sp is not None:
                 sp.counters["blocks"] = len(outs)
                 sp.counters["device_est_s"] = dev_s
-            obs.add("device.est_s", dev_s)
             obs.operators.op_add("device_est_s", dev_s)
             obs.operators.op_add("blocks", len(outs))
             obs.attrib.account("executor.chunks", len(outs),
@@ -679,7 +676,6 @@ def _run_tensor_stream(node, tfold, in_vals, src, step_jit):
         if sp is not None:
             sp.counters["blocks"] = nblk
             sp.counters["device_est_s"] = dev_s
-        obs.add("device.est_s", dev_s)
         obs.operators.op_add("device_est_s", dev_s)
         obs.operators.op_add("blocks", nblk)
         obs.attrib.account("executor.chunks", nblk,
@@ -971,7 +967,6 @@ def _execute_streamed(client, plan: LogicalPlan, scan_values: Dict[int, Any],
             if sp is not None:
                 sp.counters["nodes"] = len(nodes)
                 sp.counters["device_est_s"] = dev_s
-            obs.add("device.est_s", dev_s)
             if opr is not None:
                 opr.add("device_est_s", dev_s)
                 opr.add("region_nodes", len(nodes))
@@ -1221,7 +1216,6 @@ def _execute_computations(
             dev_s = time.perf_counter() - t0_jit
             if sp is not None:
                 sp.counters["device_est_s"] = dev_s
-            obs.add("device.est_s", dev_s)
         rec = obs.operators.current_recorder()
         if rec is not None:
             # XLA fused the whole component: the tree keeps the plan's

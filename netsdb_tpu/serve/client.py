@@ -55,6 +55,7 @@ from netsdb_tpu.serve.protocol import (
     IDEMPOTENCY_KEY,
     LANE_KEY,
     MUTATING_TYPES,
+    OBS_FRAMES,
     PLACEMENT_EPOCH_KEY,
     PROTO_VERSION,
     QUERY_ID_KEY,
@@ -72,7 +73,10 @@ from netsdb_tpu.utils.timing import deadline_after, seconds_left
 #: frame types that open a client-side query trace (and mint the query
 #: id the daemon's trace joins on) — the query-shaped requests whose
 #: time decomposition GET_TRACE answers; decode steps trace too, so a
-#: slow GENERATE decomposes into coalesce-wait / state-load / device
+#: slow GENERATE decomposes into coalesce-wait / state-load / device.
+#: Under a trace that is ALREADY current (RemoteClient.request_trace:
+#: one logical request of several frames) every workload frame carries
+#: that trace's id instead, whatever its type
 TRACED_TYPES = frozenset({MsgType.EXECUTE_COMPUTATIONS,
                           MsgType.EXECUTE_PLAN,
                           MsgType.GENERATE})
@@ -418,7 +422,8 @@ class RemoteClient:
                     self._sock.settimeout(io_timeout)
                 with obs.span("client.send", "client"):
                     send_frame(self._sock, msg_type, payload, codec,
-                               chaos=self._chaos)
+                               chaos=self._chaos,
+                               encode_span="client.encode")
                 with obs.span("client.wait", "client"):
                     # lint: disable=lock-blocking-call -- the conn lock exists to serialize one in-flight request per connection; holding it across the reply IS the protocol, and the wait is bounded by the socket timeout set at dial
                     typ, reply = self._recv_reply(self._sock)
@@ -534,12 +539,14 @@ class RemoteClient:
                  codec: int = CODEC_MSGPACK,
                  deadline_s: Optional[float] = None) -> Any:
         """One logical request: attach an idempotency token to mutating
-        frames and this client's identity to every frame, mint a
-        SAMPLED query id for query-shaped frames (the trace the
-        daemon's spans join on — 1 in ``trace_sample``), then retry
-        under :meth:`_retry_driver`. A traced request ships its client
-        span profile to the daemon afterwards (PUT_TRACE,
-        best-effort)."""
+        frames and this client's identity to every frame, stamp the
+        CURRENT trace's query id on a workload frame — or, with no
+        trace current, mint a SAMPLED one for query-shaped frames (the
+        trace the daemon's spans join on — 1 in ``trace_sample``) —
+        then retry under :meth:`_retry_driver`. A request that opened
+        its own trace ships its client span profile to the daemon
+        afterwards (PUT_TRACE, best-effort); under a caller's trace
+        the caller does (:meth:`request_trace`)."""
         if isinstance(payload, dict):
             extra = {}
             if msg_type in MUTATING_TYPES \
@@ -557,17 +564,25 @@ class RemoteClient:
                 payload = dict(payload)
                 payload.update(extra)
         qid = None
-        if msg_type in TRACED_TYPES and isinstance(payload, dict) \
-                and QUERY_ID_KEY not in payload and obs.enabled():
-            # one id per LOGICAL query (retries reuse it), minted 1-in-N
-            # (config.obs_trace_sample via the constructor) so high-QPS
-            # traffic traces at bounded cost; a payload already carrying
-            # a qid is a forwarded frame (the leader's mirror path) —
-            # its originating client owns the trace
-            qid = self._qid_sampler.sample(self._trace_sample)
-            if qid is not None:
+        if isinstance(payload, dict) and QUERY_ID_KEY not in payload \
+                and msg_type not in OBS_FRAMES:
+            # a payload already carrying a qid is a forwarded frame
+            # (the leader's mirror path) — its originating client owns
+            # the trace
+            cur = obs.current_trace()
+            if cur is not None:
+                # a frame of a larger traced request: the daemon's
+                # profile of it joins the caller's trace
                 payload = dict(payload)
-                payload[QUERY_ID_KEY] = qid
+                payload[QUERY_ID_KEY] = cur.qid
+            elif msg_type in TRACED_TYPES:
+                # one id per LOGICAL query (retries reuse it), minted
+                # 1-in-N (config.obs_trace_sample via the constructor)
+                # so high-QPS traffic traces at bounded cost
+                qid = self._qid_sampler.sample(self._trace_sample)
+                if qid is not None:
+                    payload = dict(payload)
+                    payload[QUERY_ID_KEY] = qid
         oneshot = self._stream_owner == threading.get_ident()
 
         def attempt(io_timeout):
@@ -591,6 +606,28 @@ class RemoteClient:
             # merged end-to-end profile
             self._ship_trace(qid, tr)
         return out
+
+    @contextlib.contextmanager
+    def request_trace(self, name: str):
+        """One client trace around a logical request of SEVERAL frames
+        (``ModelServing.score``: ship the batch, execute, read back), with a
+        span ``name`` over the whole of it: sampled through this
+        client's own :class:`~netsdb_tpu.obs.QidSampler` like a
+        single traced frame, every workload frame sent inside carries
+        its query id (:meth:`_request`), and the profile ships to the
+        daemon when it closes. Yields None — and traces nothing —
+        where the request is sampled out, tracing is off, or a trace
+        is already current (the frames then join that one)."""
+        qid = None if obs.current_trace() is not None \
+            else self._qid_sampler.sample(self._trace_sample)
+        if qid is None:
+            yield None
+            return
+        with obs.trace(qid, origin="client") as tr:
+            with obs.span(name, "client"):
+                yield tr
+        if tr is not None and self.ship_traces:
+            self._ship_trace(qid, tr)
 
     def _ship_trace(self, qid: str, tr) -> None:
         """Queue a completed client trace for the background shipper —
@@ -1534,6 +1571,10 @@ class RemoteClient:
         slices = _pl.range_slices(int(dense.shape[0]), len(slots))
         errors: Dict[int, BaseException] = {}
         lock = threading.Lock()
+        # a ContextVar does not cross into the slot threads: hand them
+        # the caller's trace, so each slice's frame carries its query
+        # id and its send/wait spans land under the caller's open span
+        traced = obs.capture()
 
         def send_slot(i: int, lo: int, hi: int) -> None:
             sl = slots[i]
@@ -1541,12 +1582,14 @@ class RemoteClient:
                     if sl["state"] != "live" else sl["addr"])
             try:
                 sc = self._shard_client(addr)
-                sc._request(MsgType.SEND_MATRIX, {
-                    "db": db, "set": set_name,
-                    "tensor": tensor_to_wire(
-                        np.ascontiguousarray(dense[lo:hi]), block_shape),
-                    PLACEMENT_EPOCH_KEY: int(entry["epoch"]),
-                    SHARD_SLOT_KEY: i})
+                with obs.adopt(traced):
+                    sc._request(MsgType.SEND_MATRIX, {
+                        "db": db, "set": set_name,
+                        "tensor": tensor_to_wire(
+                            np.ascontiguousarray(dense[lo:hi]),
+                            block_shape),
+                        PLACEMENT_EPOCH_KEY: int(entry["epoch"]),
+                        SHARD_SLOT_KEY: i})
             except Exception as e:  # noqa: BLE001 — surfaced below
                 self._drop_shard_client(addr)
                 with lock:

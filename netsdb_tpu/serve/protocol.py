@@ -52,6 +52,7 @@ layer is a trusted-cluster control plane; an optional shared token
 
 from __future__ import annotations
 
+import contextlib
 import socket
 import struct
 import time
@@ -60,6 +61,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import msgpack
 import numpy as np
+
+from netsdb_tpu import obs
 
 MAGIC = 0x4E54  # "NT"
 _HEADER = struct.Struct("!HBIQ")
@@ -334,6 +337,15 @@ MUTATING_TYPES = frozenset({
     MsgType.SESSION_OPEN, MsgType.GENERATE, MsgType.SESSION_CLOSE,
 })
 
+#: introspection/meta frame types — everything else is a WORKLOAD
+#: frame. The daemon leaves them out of the serve.requests/
+#: serve.requests_ok counters, the serve.request_s histogram the SLO
+#: engine evaluates and the serve.wire.bytes_* counters (monitoring
+#: must not move what it reads); the client stamps no query id on them
+OBS_FRAMES = frozenset({MsgType.PING, MsgType.COLLECT_STATS,
+                        MsgType.GET_TRACE, MsgType.PUT_TRACE,
+                        MsgType.HEALTH, MsgType.GET_METRICS})
+
 
 class ProtocolError(ConnectionError):
     pass
@@ -555,33 +567,47 @@ def _sendmsg_all(sock: socket.socket, parts: Sequence[Any]) -> None:
                 sent = 0
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 def send_frame(sock: socket.socket, msg_type: int, payload: Any,
-               codec: int = CODEC_MSGPACK, chaos=None) -> None:
-    """``chaos``: optional :class:`~netsdb_tpu.serve.chaos.ChaosInjector`
+               codec: int = CODEC_MSGPACK, chaos=None,
+               encode_span: Optional[str] = None) -> int:
+    """Send one frame; returns the bytes put on the socket (header +
+    segment table + body + segments).
+
+    ``chaos``: optional :class:`~netsdb_tpu.serve.chaos.ChaosInjector`
     that may drop/delay/corrupt/truncate this frame (tests only; the
-    production path pays one ``is None`` check).
+    production path pays one ``is None`` check). ``encode_span``: the
+    name of a span (on the current trace) around everything BEFORE the
+    socket write — body pack, segment table, checksums — so a caller's
+    own span around this call splits into encode and ``sendmsg``.
 
     The msgpack codec auto-upgrades to codec 2 (out-of-band segments)
     when the payload holds arrays ≥ :data:`OOB_MIN_BYTES`; everything
     goes out as one vectored ``sendmsg`` either way."""
     segments: List[memoryview] = []
-    if codec in (CODEC_MSGPACK, CODEC_MSGPACK_OOB):
-        # a caller echoing a RECEIVED frame's wire codec may pass
-        # codec 2 — the payload is a decoded dict again, so re-encode
-        # through the OOB path (the mirror-forward case: a big-tensor
-        # frame arrives as codec 2 and must forward losslessly)
-        body, segments = encode_body_oob(payload)
-        wire_codec = CODEC_MSGPACK_OOB if segments else CODEC_MSGPACK
-    else:
-        body = encode_body(payload, codec)
-        wire_codec = codec
-    header = _HEADER.pack(MAGIC, wire_codec, int(msg_type), len(body))
-    segtable = _pack_segtable(segments) if segments else b""
+    with obs.span(encode_span, "wire") if encode_span else _NO_SPAN:
+        if codec in (CODEC_MSGPACK, CODEC_MSGPACK_OOB):
+            # a caller echoing a RECEIVED frame's wire codec may pass
+            # codec 2 — the payload is a decoded dict again, so
+            # re-encode through the OOB path (the mirror-forward case:
+            # a big-tensor frame arrives as codec 2 and must forward
+            # losslessly)
+            body, segments = encode_body_oob(payload)
+            wire_codec = CODEC_MSGPACK_OOB if segments else CODEC_MSGPACK
+        else:
+            body = encode_body(payload, codec)
+            wire_codec = codec
+        header = _HEADER.pack(MAGIC, wire_codec, int(msg_type), len(body))
+        segtable = _pack_segtable(segments) if segments else b""
     if chaos is not None:
         header, segtable, body, segments = chaos.on_send(
             sock, int(msg_type), header, body,
             segtable=segtable, segments=segments)
-    _sendmsg_all(sock, [header, segtable, body, *segments])
+    parts = [header, segtable, body, *segments]
+    _sendmsg_all(sock, parts)
+    return sum(memoryview(p).nbytes for p in parts)
 
 
 def _recv_exact(sock: socket.socket, n: int,
@@ -635,14 +661,19 @@ def _recv_exact(sock: socket.socket, n: int,
 
 def recv_frame_raw(sock: socket.socket, chaos=None,
                    mid_frame_timeout: Optional[float] = None,
-                   ) -> Tuple[MsgType, int, bytes, List[Tuple[Any, int]]]:
+                   ) -> Tuple[MsgType, int, bytes,
+                              List[Tuple[Any, int]], int, float]:
     """Receive one frame without decoding — servers decode separately so
     a refused codec becomes an ERR reply, not a dropped connection.
-    Returns ``(type, codec, body, segments)``; ``segments`` is the
-    codec-2 out-of-band list of (writable buffer, expected checksum)
-    pairs, empty for other codecs — each segment lands in its own
-    buffer via ``recv_into`` (no reassembly copy) and checksum
-    verification is deferred to :func:`decode_body`.
+    Returns ``(type, codec, body, segments, nbytes, recv_s)``;
+    ``segments`` is the codec-2 out-of-band list of (writable buffer,
+    expected checksum) pairs, empty for other codecs — each segment
+    lands in its own buffer via ``recv_into`` (no reassembly copy) and
+    checksum verification is deferred to :func:`decode_body`.
+    ``nbytes`` is what the frame took on the socket (header + segment
+    table + body + segments); ``recv_s`` runs from the moment the
+    header has landed to the last segment's last byte, so the idle
+    wait for a frame to START is not in it.
 
     ``mid_frame_timeout`` is the deadline-discipline knob: waiting for
     a frame to START may block (idle persistent connection), but once
@@ -653,6 +684,7 @@ def recv_frame_raw(sock: socket.socket, chaos=None,
     if chaos is not None:
         chaos.on_recv(sock)
     header = _recv_exact(sock, _HEADER.size, mid_timeout=mid_frame_timeout)
+    t_header = time.perf_counter()
     magic, codec, msg_type, body_len = _HEADER.unpack(header)
     if magic != MAGIC:
         raise ProtocolError(f"bad frame magic {magic:#x}")
@@ -677,6 +709,7 @@ def recv_frame_raw(sock: socket.socket, chaos=None,
         return rem
 
     seg_meta: List[Tuple[int, int]] = []
+    table_bytes = 0
     if codec == CODEC_MSGPACK_OOB:
         cnt = _recv_exact(sock, _SEG_COUNT.size,
                           mid_timeout=budget(), started=True)
@@ -691,24 +724,28 @@ def recv_frame_raw(sock: socket.socket, chaos=None,
         total = body_len + sum(n for n, _ in seg_meta)
         if total > MAX_FRAME_BYTES:
             raise ProtocolError(f"frame of {total} bytes exceeds cap")
+        table_bytes = _SEG_COUNT.size + len(table)
     body = _recv_exact(sock, body_len, mid_timeout=budget(),
                        started=True)
     segments = [(_recv_exact(sock, n, mid_timeout=budget(),
                              started=True), crc)
                 for n, crc in seg_meta]
+    recv_s = time.perf_counter() - t_header
+    nbytes = (_HEADER.size + table_bytes + body_len
+              + sum(n for n, _ in seg_meta))
     try:
         typ = MsgType(msg_type)
     except ValueError:
         # unknown type ids stay raw ints: the server answers them with a
         # "no handler" ERR instead of dropping the connection
         typ = msg_type
-    return typ, codec, bytes(body), segments
+    return typ, codec, bytes(body), segments, nbytes, recv_s
 
 
 def recv_frame(sock: socket.socket, allow_pickle: bool = False,
                chaos=None, mid_frame_timeout: Optional[float] = None,
                ) -> Tuple[MsgType, Any]:
-    msg_type, codec, body, segments = recv_frame_raw(
+    msg_type, codec, body, segments, _, _ = recv_frame_raw(
         sock, chaos=chaos, mid_frame_timeout=mid_frame_timeout)
     return msg_type, decode_body(body, codec, allow_pickle,
                                  segments=segments)

@@ -65,6 +65,7 @@ from netsdb_tpu.serve.protocol import (
     IDEMPOTENCY_KEY,
     LANE_KEY,
     MAX_FRAME_BYTES,
+    OBS_FRAMES,
     PLACEMENT_EPOCH_KEY,
     PROTO_VERSION,
     QUERY_ID_KEY,
@@ -82,13 +83,6 @@ from netsdb_tpu.storage.mutlog import MutationLog
 from netsdb_tpu.storage.store import SetIdentifier
 from netsdb_tpu.utils.locks import TrackedLock
 from netsdb_tpu.utils.timing import deadline_after, seconds_left, wall_now
-
-#: introspection/meta frame types — excluded from the serve.requests/
-#: serve.requests_ok counters and the serve.request_s histogram the
-#: SLO engine evaluates (monitoring must not move the SLOs it reads)
-OBS_FRAMES = frozenset({MsgType.PING, MsgType.COLLECT_STATS,
-                        MsgType.GET_TRACE, MsgType.PUT_TRACE,
-                        MsgType.HEALTH, MsgType.GET_METRICS})
 
 #: the in-flight frame's idempotency token, installed for the
 #: handler's dynamic extent. The handoff path needs it: a batch
@@ -1296,18 +1290,23 @@ class ServeController:
                 return
             while not self._stop.is_set():
                 try:
-                    typ, codec_in, raw, segs = recv_frame_raw(
-                        conn, chaos=self._chaos,
-                        mid_frame_timeout=self.frame_timeout_s)
+                    typ, codec_in, raw, segs, nbytes, recv_s = \
+                        recv_frame_raw(
+                            conn, chaos=self._chaos,
+                            mid_frame_timeout=self.frame_timeout_s)
                 except (ProtocolError, ConnectionError, OSError):
                     return
+                workload = typ not in OBS_FRAMES
+                if workload:
+                    obs.REGISTRY.counter("serve.wire.bytes_in").inc(nbytes)
                 t_dec = time.perf_counter()
                 try:
                     payload = decode_body(raw, codec_in, self.allow_pickle,
                                           segments=segs)
                 except ProtocolError as e:
                     # refused codec — deterministic, fatal to retry
-                    if not self._send_err(conn, e, retryable=False):
+                    if not self._send_err(conn, e, retryable=False,
+                                          count=workload):
                         return
                     continue
                 except Exception as e:
@@ -1315,7 +1314,8 @@ class ServeController:
                     # The request never executed, so a resend is safe —
                     # typed retryable (the chaos corrupt path).
                     fault = CorruptFrame(f"{type(e).__name__}: {e}")
-                    if not self._send_err(conn, fault, retryable=True):
+                    if not self._send_err(conn, fault, retryable=True,
+                                          count=workload):
                         return
                     continue
                 decode_s = time.perf_counter() - t_dec
@@ -1330,23 +1330,32 @@ class ServeController:
                         return
                     continue
                 if not self._dispatch_frame(conn, typ, codec_in, payload,
-                                            decode_s=decode_s):
+                                            decode_s=decode_s,
+                                            recv_s=recv_s):
                     return
 
-    def _send_reply(self, conn, typ, payload, codec=CODEC_MSGPACK) -> None:
+    def _send_reply(self, conn, typ, payload, codec=CODEC_MSGPACK,
+                    count: bool = True) -> None:
         """Reply send with the same deadline discipline as mid-frame
         recv: the peer must DRAIN within frame_timeout_s or the send
         fails (socket.timeout → the caller drops the connection) — a
         client that stops reading can never wedge a handler thread in
-        sendall. The idle-recv timeout (None) is restored after."""
+        sendall. The idle-recv timeout (None) is restored after.
+        ``count`` is False where the frame answers an introspection
+        request (``OBS_FRAMES``): its bytes stay out of
+        ``serve.wire.bytes_out``."""
         conn.settimeout(self.frame_timeout_s)
         try:
-            send_frame(conn, typ, payload, codec, chaos=self._chaos)
+            nbytes = send_frame(conn, typ, payload, codec,
+                                chaos=self._chaos)
         finally:
             conn.settimeout(None)
+        if count:
+            obs.REGISTRY.counter("serve.wire.bytes_out").inc(nbytes)
 
     def _send_err(self, conn, exc, retryable: Optional[bool] = None,
-                  with_traceback: bool = False) -> bool:
+                  with_traceback: bool = False,
+                  count: bool = True) -> bool:
         """ERR frame for ``exc``; False when the connection is dead.
         ``retryable`` rides the payload so clients classify without
         string-matching (errors.classify_remote)."""
@@ -1364,13 +1373,14 @@ class ServeController:
         if with_traceback:
             body["traceback"] = traceback.format_exc(limit=20)
         try:
-            self._send_reply(conn, MsgType.ERR, body)
+            self._send_reply(conn, MsgType.ERR, body, count=count)
             return True
         except OSError:
             return False
 
     def _dispatch_frame(self, conn, typ, codec_in, payload,
-                        decode_s: float = 0.0) -> bool:
+                        decode_s: float = 0.0,
+                        recv_s: float = 0.0) -> bool:
         """Execute one decoded request frame and send its reply. A
         frame carrying a client-minted query id opens a query-scoped
         trace first (``obs.trace``): the handler, the executor below
@@ -1414,15 +1424,16 @@ class ServeController:
         with obs.trace(str(qid), origin="server",
                        ring=self.trace_ring) as tr:
             if tr is not None:
-                # the body decode finished before the trace could
-                # open: back-date the trace so the decode span
-                # occupies real timeline [0, decode_s] AHEAD of the
-                # dispatch span (and total_s covers it) instead of
+                # the socket receive and the body decode finished
+                # before the trace could open: back-date the trace so
+                # their spans occupy real timeline [0, recv_s] and
+                # [recv_s, recv_s + decode_s] AHEAD of the dispatch
+                # span (and total_s covers them) instead of
                 # overlapping it
-                tr.backdate(decode_s)
+                tr.backdate(recv_s + decode_s)
+                tr.record("server.recv", recv_s, "serve", start_s=0.0)
                 tr.record("server.decode", decode_s, "serve",
-                          start_s=0.0)
-                tr.add("frame.decode_s", decode_s)
+                          start_s=recv_s)
                 if client is not None:
                     tr.annotate("client", str(client))
             with self._maybe_device_profile(tr):
@@ -1510,6 +1521,7 @@ class ServeController:
         ``t0`` is None for introspection frames (``OBS_FRAMES``) —
         they observe nothing and count nowhere."""
         observed = [False]
+        workload = t0 is not None
 
         def mark():
             if not observed[0] and t0 is not None:
@@ -1541,7 +1553,8 @@ class ServeController:
                 cached = self._idem.claim(token, wait_s=self.frame_timeout_s)
                 if cached is not None:
                     reply_type, reply, codec = cached
-                    self._send_reply(conn, reply_type, reply, codec)
+                    self._send_reply(conn, reply_type, reply, codec,
+                                     count=workload)
                     mark()
                     done(True)
                     return True
@@ -1566,13 +1579,14 @@ class ServeController:
                         f_type, f_payload, f_codec = frame
                     else:
                         (f_type, f_payload), f_codec = frame, CODEC_MSGPACK
-                    self._send_reply(conn, f_type, f_payload, f_codec)
+                    self._send_reply(conn, f_type, f_payload, f_codec,
+                                     count=workload)
                     mark()  # first frame = the latency that matters
                 mark()  # empty stream: observe at STREAM_END
                 done(True)
                 return True
             with obs.span("server.reply", "serve"):
-                self._send_reply(conn, *out)
+                self._send_reply(conn, *out, count=workload)
             mark()
             done(True)
             return True
@@ -1583,7 +1597,8 @@ class ServeController:
         except Exception as e:  # handler errors go back as typed ERR
             mark()
             done(False)
-            return self._send_err(conn, e, with_traceback=True)
+            return self._send_err(conn, e, with_traceback=True,
+                                  count=workload)
 
     #: frame types eligible for identical-query coalescing: idempotent
     #: job launches whose reply reuse the idempotency-token cache
@@ -1832,9 +1847,10 @@ class ServeController:
             self._send_reply(conn, MsgType.OK, {"go": True})
             total_in = 0
             while True:
-                typ, codec_in, raw, segs = recv_frame_raw(
+                typ, codec_in, raw, segs, nbytes, _ = recv_frame_raw(
                     conn, chaos=self._chaos,
                     mid_frame_timeout=self.frame_timeout_s)
+                obs.REGISTRY.counter("serve.wire.bytes_in").inc(nbytes)
                 total_in += len(raw) + sum(b.nbytes for b, _ in segs)
                 if total_in > MAX_FRAME_BYTES:
                     # the streamed path keeps the single-frame sanity
@@ -3092,7 +3108,15 @@ class ServeController:
                 f"matrix ingest refused — retry after readmit",
                 slot=slot, epoch=epoch)
         dense, block_shape = tensor_from_wire(p["tensor"])
-        t = self.library.send_matrix(p["db"], p["set"], dense, block_shape)
+        # the set write: blocking, pad, host→HBM dispatch, put_tensor.
+        # The handler does not wait for the placed array (the reply
+        # goes out while the copy may still be in flight), so neither
+        # does the span
+        with obs.span("store.ingest", "storage") as sp:
+            t = self.library.send_matrix(p["db"], p["set"], dense,
+                                         block_shape)
+            if sp is not None:
+                sp.counters["bytes"] = int(np.asarray(dense).nbytes)
         if t is None:
             # storage="paged" set: the matrix went into the arena, not
             # HBM — reply from the ingested array (there is no blocked
