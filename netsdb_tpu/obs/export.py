@@ -67,6 +67,16 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("serve.wire.bytes_out", "bytes of the reply and stream frames "
                                  "that answered workload requests (OBS "
                                  "frames' replies excluded)"),
+        ("serve.wire.recv_pool.hits", "out-of-band segments of "
+                                      "RECV_POOL_MIN_BYTES or more "
+                                      "received into a recycled, "
+                                      "already-faulted arena "
+                                      "(serve/protocol.py, either side "
+                                      "of a connection)"),
+        ("serve.wire.recv_pool.misses", "out-of-band segments of "
+                                        "RECV_POOL_MIN_BYTES or more "
+                                        "that found no free arena of "
+                                        "their size and allocated one"),
         ("serve.idem.memory_hits", "idempotent retries answered from "
                                    "the in-memory reply cache"),
         ("serve.idem.persist_hits", "idempotent retries answered from "
@@ -311,6 +321,12 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("devcache.pinned_bytes", "bytes of head blocks currently "
                                   "pinned against LRU eviction "
                                   "(device_cache_pin_bytes)"),
+        ("serve.wire.recv_pool.retained_bytes", "bytes of receive "
+                                                "arenas on the pool's "
+                                                "free list, as of the "
+                                                "last pooled segment "
+                                                "received (capped at "
+                                                "RECV_POOL_MAX_BYTES)"),
         ("session.resident_bytes", "bytes of per-session decode state "
                                    "currently resident in the device "
                                    "cache"),

@@ -27,8 +27,18 @@ raw ndarray bytes ride AFTER the body as separate segments::
 
 The sender gathers header/table/body/segments with ``socket.sendmsg``
 over ``memoryview``s — the tensor bytes are never copied host-side —
-and the receiver lands each segment in its own writable buffer fed
-straight to ``np.frombuffer``. Any ``send_frame`` with the msgpack
+and the receiver lands each segment in its own writable buffer, which
+the decoded array is a view of. A segment under
+:data:`RECV_POOL_MIN_BYTES` lands in a fresh ``bytearray``. A larger
+one lands in an arena leased from the process-wide :data:`RECV_POOL`:
+memory an earlier frame has already faulted in, so the kernel's copy
+out of the socket takes no page fault per 4 KB (more than half of a
+306 MB receive into fresh memory is first touch). The arena goes back
+to the pool when the last array decoded over it is gone — a stored or
+in-flight host-to-device copy keeps it out for as long as it reads it —
+and is never cleared: the next lease is filled to its last byte or the
+read raises, so no decoder sees an earlier frame's bytes. Any
+``send_frame`` with the msgpack
 codec upgrades to codec 2 automatically when the payload holds arrays
 above :data:`OOB_MIN_BYTES`; frames without such arrays stay codec 0,
 byte-identical to v2. A per-segment checksum (``segment_checksum`` — a vectorized
@@ -52,12 +62,15 @@ layer is a trusted-cluster control plane; an optional shared token
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import socket
 import struct
+import threading
 import time
+import weakref
 from enum import IntEnum
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Deque, List, Optional, Sequence, Tuple
 
 import msgpack
 import numpy as np
@@ -87,6 +100,16 @@ _SEG_ENTRY = struct.Struct("!QI")  # nbytes(u64) | checksum(u32)
 MAX_SEGMENTS = 4096
 #: iovecs per sendmsg call — comfortably under any platform IOV_MAX
 _IOV_BATCH = 64
+
+#: out-of-band segments at or above this are received into a pooled
+#: arena (:class:`_RecvPool`); under it a page fault costs less than
+#: the lease, and the fresh ``bytearray`` stays. Arenas are sized in
+#: multiples of it, so that a segment a little larger than the last
+#: one still fits the last one's arena
+RECV_POOL_MIN_BYTES = 1 << 20
+#: the most the pool's free list retains; the oldest arena goes first
+RECV_POOL_MAX_BYTES = 1 << 30
+RECV_POOL_MAX_ARENAS = 8
 
 
 class MsgType(IntEnum):
@@ -455,18 +478,29 @@ def _unpack_hook(obj):
     return obj
 
 
-def _make_oob_hook(segments: Sequence[Any]):
+def _segment_bytes(seg: Any) -> np.ndarray:
+    """Zero-copy, WRITABLE uint8 array over one received segment: a
+    pooled :class:`_Lease` through its ``__array_interface__`` (the
+    lease is then the ultimate ``.base`` of every array derived from
+    this one, which is what keeps its arena out of the pool), anything
+    else (the ``bytearray`` of a small segment) through the buffer
+    protocol, whose writability ``np.frombuffer`` inherits."""
+    if isinstance(seg, _Lease):
+        return np.asarray(seg)
+    return np.frombuffer(seg, np.uint8)
+
+
+def _make_oob_hook(segments: Sequence[np.ndarray]):
     """Unpack hook resolving ``__ndseg__`` descriptors to zero-copy,
-    WRITABLE arrays over the received segment buffers (bytearrays —
-    ``np.frombuffer`` inherits their writability)."""
+    WRITABLE arrays over the received segments' bytes
+    (:func:`_segment_bytes`)."""
 
     def hook(obj):
         if isinstance(obj, dict):
             if "__ndseg__" in obj:
                 idx = obj["__ndseg__"]
-                return np.frombuffer(
-                    segments[idx], dtype=np.dtype(obj["d"])
-                ).reshape(obj["s"])
+                return segments[idx].view(
+                    np.dtype(obj["d"])).reshape(obj["s"])
             if obj.get("__nd__"):
                 return _inline_array(obj)
         return obj
@@ -503,7 +537,8 @@ def decode_body(body: Any, codec: int, allow_pickle: bool,
     frame-synchronized, never a torn read."""
     if codec == CODEC_MSGPACK_OOB:
         bufs = []
-        for i, (buf, crc) in enumerate(segments or ()):
+        for i, (seg, crc) in enumerate(segments or ()):
+            buf = _segment_bytes(seg)
             if segment_checksum(buf) != crc:
                 raise ValueError(
                     f"out-of-band segment {i} checksum mismatch "
@@ -610,10 +645,129 @@ def send_frame(sock: socket.socket, msg_type: int, payload: Any,
     return sum(memoryview(p).nbytes for p in parts)
 
 
+class _Lease:
+    """One received segment's hold on a pooled arena: what
+    :func:`recv_frame_raw` returns in place of a buffer for a pooled
+    segment, and the token whose lifetime IS the arena's time out of
+    the pool (``weakref.finalize`` in :meth:`_RecvPool.lease`).
+
+    A plain object on purpose. It exposes its ``nbytes`` of the arena
+    through ``__array_interface__``, so ``np.asarray(lease)`` and every
+    view, reshape or slice taken from that array keeps the lease alive
+    through its ``.base`` chain. Were the lease itself an ndarray view
+    of the arena, NumPy would collapse a derived array's base past it
+    to the arena, the view would die at once and the arena would be
+    leased again under a live array."""
+
+    __slots__ = ("_arena", "nbytes", "__weakref__")
+
+    def __init__(self, arena: np.ndarray, nbytes: int):
+        self._arena = arena
+        self.nbytes = nbytes
+
+    @property
+    def __array_interface__(self) -> dict:
+        return {"version": 3, "typestr": "|u1", "shape": (self.nbytes,),
+                "data": (self._arena.ctypes.data, False)}
+
+    def recv_view(self) -> memoryview:
+        """The bytes ``recv_into`` fills; not kept past the read (a
+        ``memoryview`` takes no weak reference and would not hold the
+        lease)."""
+        return memoryview(self._arena)[:self.nbytes]
+
+
+class _RecvPool:
+    """Free list of receive arenas whose pages are already faulted in.
+
+    :meth:`lease` hands out an arena for exactly one segment; the arena
+    comes back when the lease object is garbage, that is when nothing
+    that can read the memory is alive: not the decoded array, not a
+    view of it a set kept, not the host buffer reference the runtime
+    holds until a host-to-device copy completes. It is never returned
+    by a handler's end.
+
+    ``_mu`` guards ``_free`` and ``retained_bytes`` and is a strict
+    leaf: nothing is called under it but list operations, and it is
+    never held across a ``recv``. It is a plain ``threading.Lock`` and
+    not a ``TrackedLock`` because finalizers take it: a finalizer runs
+    wherever the last reference dies, also inside a garbage collection
+    that interrupts a thread between two bytecodes, whatever that
+    thread holds. So a finalizer may wait for nothing (the witness's
+    own mutex and the metrics registry's included): it queues the
+    arena on ``_returned``, a deque whose append is atomic, and only
+    TRIES the lock. Whoever holds the lock looks at ``_returned`` again
+    after letting go, so an arena whose finalizer lost the try is
+    admitted by the holder."""
+
+    def __init__(self, max_bytes: int = RECV_POOL_MAX_BYTES,
+                 max_arenas: int = RECV_POOL_MAX_ARENAS):
+        self.max_bytes = max_bytes
+        self.max_arenas = max_arenas
+        self._mu = threading.Lock()
+        self._free: List[np.ndarray] = []  # oldest return first
+        self._returned: Deque[np.ndarray] = collections.deque()
+        self.retained_bytes = 0
+
+    def lease(self, n: int) -> _Lease:
+        """A lease on ``n`` bytes of the smallest free arena that holds
+        them and that they fill at least half of (one 306 MB arena is
+        not spent on a 2 MB reply), else of a fresh arena (a miss)."""
+        self._admit()
+        with self._mu:
+            fits = [i for i, a in enumerate(self._free)
+                    if n <= a.nbytes <= 2 * n]
+            arena = None
+            if fits:
+                arena = self._free.pop(
+                    min(fits, key=lambda i: self._free[i].nbytes))
+                self.retained_bytes -= arena.nbytes
+            retained = self.retained_bytes
+        self._admit()
+        obs.REGISTRY.counter("serve.wire.recv_pool.hits" if fits else
+                             "serve.wire.recv_pool.misses").inc()
+        obs.REGISTRY.gauge("serve.wire.recv_pool.retained_bytes").set(
+            retained)
+        if arena is None:
+            grain = RECV_POOL_MIN_BYTES
+            arena = np.empty(-(-n // grain) * grain, np.uint8)
+        lease = _Lease(arena, n)
+        weakref.finalize(lease, self._give_back, arena)
+        return lease
+
+    def _give_back(self, arena: np.ndarray) -> None:
+        self._returned.append(arena)
+        self._admit()
+
+    def _admit(self) -> None:
+        """Move returned arenas onto the free list and drop the oldest
+        where a cap is passed. Never waits (see the class docstring)."""
+        while self._returned and self._mu.acquire(blocking=False):
+            try:
+                while self._returned:
+                    arena = self._returned.popleft()
+                    self._free.append(arena)
+                    self.retained_bytes += arena.nbytes
+                while self._free and (
+                        len(self._free) > self.max_arenas
+                        or self.retained_bytes > self.max_bytes):
+                    self.retained_bytes -= self._free.pop(0).nbytes
+            finally:
+                self._mu.release()
+
+
+#: the process-wide pool :func:`recv_frame_raw` leases from, on either
+#: side of a connection
+RECV_POOL = _RecvPool()
+
+
 def _recv_exact(sock: socket.socket, n: int,
                 mid_timeout: Optional[float] = None,
-                started: bool = False) -> memoryview:
-    """Read exactly ``n`` bytes. ``mid_timeout`` is a CUMULATIVE
+                started: bool = False,
+                view: Optional[memoryview] = None) -> memoryview:
+    """Read exactly ``n`` bytes, into ``view`` (``n`` writable bytes)
+    or else into a fresh ``bytearray``; returns what it filled, and
+    fills all of it or raises. ``mid_timeout`` is a CUMULATIVE
     deadline on finishing the read once it has started (``started=True``
     means the frame is already mid-flight, so the clock runs from byte
     0): an idle connection may block indefinitely awaiting the next
@@ -621,8 +775,8 @@ def _recv_exact(sock: socket.socket, n: int,
     budget — a peer trickling one byte per near-timeout gap cannot hold
     the thread past the deadline. Expiry raises
     :class:`ProtocolError`, never a bare socket.timeout."""
-    buf = bytearray(n)
-    view = memoryview(buf)
+    if view is None:
+        view = memoryview(bytearray(n))
     got = 0
     old_timeout: Any = False  # sentinel: False = not overridden
     deadline = None
@@ -656,7 +810,7 @@ def _recv_exact(sock: socket.socket, n: int,
     finally:
         if old_timeout is not False:
             sock.settimeout(old_timeout)
-    return memoryview(buf)
+    return view
 
 
 def recv_frame_raw(sock: socket.socket, chaos=None,
@@ -666,10 +820,16 @@ def recv_frame_raw(sock: socket.socket, chaos=None,
     """Receive one frame without decoding — servers decode separately so
     a refused codec becomes an ERR reply, not a dropped connection.
     Returns ``(type, codec, body, segments, nbytes, recv_s)``;
-    ``segments`` is the codec-2 out-of-band list of (writable buffer,
-    expected checksum) pairs, empty for other codecs — each segment
-    lands in its own buffer via ``recv_into`` (no reassembly copy) and
-    checksum verification is deferred to :func:`decode_body`.
+    ``segments`` is the codec-2 out-of-band list of (segment, expected
+    checksum) pairs, empty for other codecs — each segment lands in
+    its own memory via ``recv_into`` (no reassembly copy) and checksum
+    verification is deferred to :func:`decode_body`. A segment under
+    :data:`RECV_POOL_MIN_BYTES` is a writable buffer over a fresh
+    ``bytearray``; a larger one is a :class:`_Lease` on an arena of
+    :data:`RECV_POOL`, whose memory is reused for a later frame once
+    the pair and every array :func:`decode_body` made over it are
+    gone, and not before. Header, segment table and body are never
+    pooled.
     ``nbytes`` is what the frame took on the socket (header + segment
     table + body + segments); ``recv_s`` runs from the moment the
     header has landed to the last segment's last byte, so the idle
@@ -727,9 +887,18 @@ def recv_frame_raw(sock: socket.socket, chaos=None,
         table_bytes = _SEG_COUNT.size + len(table)
     body = _recv_exact(sock, body_len, mid_timeout=budget(),
                        started=True)
-    segments = [(_recv_exact(sock, n, mid_timeout=budget(),
-                             started=True), crc)
-                for n, crc in seg_meta]
+    segments = []
+    for n, crc in seg_meta:
+        left = budget()
+        if n < RECV_POOL_MIN_BYTES:
+            seg = _recv_exact(sock, n, mid_timeout=left, started=True)
+        else:
+            # a read that raises drops the only reference to the lease,
+            # and the arena is back in the pool
+            seg = RECV_POOL.lease(n)
+            _recv_exact(sock, n, mid_timeout=left, started=True,
+                        view=seg.recv_view())
+        segments.append((seg, crc))
     recv_s = time.perf_counter() - t_header
     nbytes = (_HEADER.size + table_bytes + body_len
               + sum(n for n, _ in seg_meta))
