@@ -335,7 +335,8 @@ _SESSION_STATE_EXEMPT = ("netsdb_tpu/serve/sessions.py",
 #: the session-state mutators (devcache session API + spill wiring)
 _SESSION_STATE_CALLS = ("session_put", "session_update",
                         "session_drop", "session_sweep",
-                        "set_session_spill")
+                        "session_evict_one", "slab_install",
+                        "slab_drop", "set_session_spill")
 
 
 @register

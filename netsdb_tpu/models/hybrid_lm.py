@@ -1,0 +1,474 @@
+"""A hybrid language model served through sessions: gated-delta-rule
+(linear-attention) layers beside full-attention layers, one state slab.
+
+The decode kind ``hybrid_lm`` of ``models/decode.py``. Weights are sets
+of the model's database, one set a tensor (``l03.w_in``, ``embed``,
+...), stored ``[out, in]`` the way ``models/ff.py`` stores its layers,
+the projections that share an input stacked into one tensor (a linear
+layer's ``w_in`` = q, k, v, output gate, beta, alpha; a full layer's
+``w_qkv``; every layer's ``w_gate_up``), so that a layer is four
+products; the
+model's *spec* (layer types, widths, slots, cache tokens a slot, prefill
+chunk lengths) is the one record of the object set ``spec`` and is what
+``SESSION_OPEN`` reads. Nothing here comes from ``Configuration``.
+
+The block (reordered norm, no biases)::
+
+    h = x + RMSNorm(Mixer(x));  y = h + RMSNorm(W_down(silu(W_gate h) * W_up h))
+
+* full-attention mixer: ``q, k, v = W_q x, W_k x, W_v x``; RMSNorm over
+  the whole width of ``q`` and ``k``; no rotary embedding; causal
+  ``softmax(q k^T / sqrt(head_dim)) v``; ``W_o``.
+* linear-attention mixer: ``q~, k~, v~ = W_q x, W_k x, W_v x`` through a
+  causal depthwise convolution of width ``conv_k`` and SiLU; per head
+  ``q = l2norm(q) / sqrt(dk)``, ``k = l2norm(k)``; ``beta = 2
+  sigmoid(W_b x)``; ``alpha = exp(-exp(A_log) softplus(W_a x +
+  dt_bias))``; the gated delta rule (``ops/delta_rule.py``); ``o =
+  RMSNorm_head(o) * silu(W_g x)``; ``W_o``.
+
+State of one session, all of it in the model's slab (a slot axis in
+every array, ``storage/devcache.SessionSlab``): ``S[lin_layer, slot,
+dk, H dv]`` float32, ``conv[lin_layer, conv_k - 1, slot, H (2 dk +
+dv)]``, for each full layer ``i`` a key cache ``k{i}[slot, heads,
+cache_tokens + margin, head_dim]`` and a value cache ``v{i}`` alike,
+``pos[slot]`` (tokens consumed) and ``tok[slot]`` (the next input
+token: the last id appended or generated, not consumed yet). The axes
+are ordered for the chip's (8, 128) tiles: a state's heads lie along
+the lanes (``dv`` = 192 alone would pad to 256), a cache's two minor
+axes are tokens and ``head_dim``, and the three rows of a convolution
+window are not a minor axis: no array is padded. Each full layer's
+caches are arrays of their own, so that a step's attention takes them
+whole as its products' operands: cut out of one array with a layer
+axis they were copied, a gigabyte a layer a step.
+
+Two programs over that slab, both donating it:
+
+* ``prefill``: one slot, one chunk of ``C`` token ids of which
+  ``n_valid`` count. Consumes them (the delta rule in its chunked form,
+  attention over the slot's cache) and produces no logits.
+* ``step``: every slot at once (row = slot, so no gather or scatter of
+  state), masked by ``active``. Consumes ``tok``, writes the argmax of
+  the float32 logits back to ``tok`` and returns ids and logits.
+
+dtypes: weights and matrix operands in the weights' dtype (bfloat16 as
+deployed), products accumulate in float32, the residual stream, norms,
+softmax, the recurrent state and logits float32; the convolution state
+and the key/value cache take the weights' dtype.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Tuple
+
+import numpy as np
+
+from netsdb_tpu.ops.attention import cache_write_rows, cached_attention
+from netsdb_tpu.ops.delta_rule import (gated_delta_chunked,
+                                       gated_delta_step_flat, heads_first,
+                                       heads_on_lanes)
+
+KIND = "hybrid_lm"
+SPEC_SET = "spec"
+LINEAR, FULL = "linear_attention", "full_attention"
+ATTN_BLOCK = 256
+
+
+# --- the spec ---------------------------------------------------------
+
+def make_spec(*, layer_types, hidden, intermediate, vocab, heads, head_dim,
+              lin_heads, lin_dk, lin_dv, conv_k=4, eps=1e-6, slots=16,
+              cache_tokens=4096, prefill_chunks=(128, 512),
+              delta_chunk=64, dtype="bfloat16",
+              xla_options=None) -> Dict[str, Any]:
+    """The record the database holds for a model. ``prefill_chunks``
+    are multiples of ``delta_chunk``, the tokens a chunk of the delta
+    rule's chunked form; the largest is the cache's margin, rounded up
+    to the attention block. ``xla_options`` ({name: value}) are handed
+    to the compiler with the model's step and prefill programs; a
+    model that names none is compiled with XLA's defaults."""
+    chunks = sorted(int(c) for c in prefill_chunks)
+    if any(c % int(delta_chunk) for c in chunks):
+        raise ValueError(f"prefill chunks {chunks} must be multiples of "
+                         f"{delta_chunk}")
+    spec = {"kind": KIND, "layer_types": list(layer_types),
+            "hidden": int(hidden), "intermediate": int(intermediate),
+            "vocab": int(vocab), "heads": int(heads),
+            "head_dim": int(head_dim), "lin_heads": int(lin_heads),
+            "lin_dk": int(lin_dk), "lin_dv": int(lin_dv),
+            "conv_k": int(conv_k), "eps": float(eps), "slots": int(slots),
+            "cache_tokens": int(cache_tokens), "prefill_chunks": chunks,
+            "delta_chunk": int(delta_chunk), "dtype": str(dtype)}
+    if xla_options:
+        spec["xla_options"] = dict(xla_options)
+    return spec
+
+
+def cache_rows(spec) -> int:
+    """Rows of a slot's key/value cache as allocated: the tokens it may
+    hold plus one largest prefill chunk (a padded chunk is written whole
+    at ``pos``), rounded up to whole attention blocks."""
+    rows = spec["cache_tokens"] + max(spec["prefill_chunks"])
+    return -(-rows // ATTN_BLOCK) * ATTN_BLOCK
+
+
+def _conv_width(spec) -> int:
+    return spec["lin_heads"] * (2 * spec["lin_dk"] + spec["lin_dv"])
+
+
+def weight_shapes(spec) -> Dict[str, Tuple[Tuple[int, int], bool]]:
+    """{set name: ((rows, cols), is_matrix)} of every weight set.
+    Matrices take the spec's dtype; vectors are float32 row vectors."""
+    d, f, v = spec["hidden"], spec["intermediate"], spec["vocab"]
+    hq = spec["heads"] * spec["head_dim"]
+    lh, dk, dv = spec["lin_heads"], spec["lin_dk"], spec["lin_dv"]
+    out = {"embed": ((v, d), True), "lm_head": ((v, d), True),
+           "final_norm": ((1, d), False)}
+    for i, kind in enumerate(spec["layer_types"]):
+        p = f"l{i:02d}."
+        out.update({p + "norm_mix": ((1, d), False),
+                    p + "norm_ffn": ((1, d), False),
+                    p + "w_gate_up": ((2 * f, d), True),
+                    p + "w_down": ((d, f), True)})
+        if kind == FULL:
+            out.update({p + "w_qkv": ((3 * hq, d), True),
+                        p + "wo": ((d, hq), True),
+                        p + "q_norm": ((1, hq), False),
+                        p + "k_norm": ((1, hq), False)})
+        elif kind == LINEAR:
+            # rows: q (H dk), k (H dk), v (H dv), gate (H dv), beta (H),
+            # alpha (H)
+            out.update({p + "w_in": ((2 * lh * (dk + dv) + 2 * lh, d), True),
+                        p + "wo": ((d, lh * dv), True),
+                        p + "conv": ((spec["conv_k"], _conv_width(spec)),
+                                     False),
+                        p + "a_log": ((1, lh), False),
+                        p + "dt_bias": ((1, lh), False),
+                        p + "o_norm": ((1, dv), False)})
+        else:
+            raise ValueError(f"layer {i}: unknown layer type {kind!r}")
+    return out
+
+
+def block_for(shape) -> Tuple[int, int]:
+    """A block shape that divides ``shape``, so that the set is stored
+    unpadded and its dense view is the stored array itself: a dimension
+    of at most 512 is one block, a larger one takes its largest
+    power-of-two divisor up to 512."""
+    def one(n):
+        if n <= 512:
+            return n
+        b = 512
+        while b > 1 and n % b:
+            b //= 2
+        return b if b >= 8 else n
+    return one(int(shape[0])), one(int(shape[1]))
+
+
+def state_layout(spec) -> Dict[str, Dict[str, Any]]:
+    """The slab's arrays: shape with the slot axis in place, dtype, the
+    slot axis, and whether a slot is zeroed when a session takes it (the
+    cache is not: what lies beyond ``pos`` is never read)."""
+    n_lin = sum(t == LINEAR for t in spec["layer_types"])
+    n_full = sum(t == FULL for t in spec["layer_types"])
+    s = spec["slots"]
+    return {
+        "S": {"shape": (n_lin, s, spec["lin_dk"],
+                        spec["lin_heads"] * spec["lin_dv"]),
+              "dtype": "float32", "slot_axis": 1, "reset": True},
+        "conv": {"shape": (n_lin, spec["conv_k"] - 1, s, _conv_width(spec)),
+                 "dtype": spec["dtype"], "slot_axis": 2, "reset": True},
+        **{f"{kv}{i}": {"shape": (s, spec["heads"], cache_rows(spec),
+                                   spec["head_dim"]),
+                         "dtype": spec["dtype"], "slot_axis": 0,
+                         "reset": False}
+           for i in range(n_full) for kv in "kv"},
+        "pos": {"shape": (s,), "dtype": "int32", "slot_axis": 0,
+                "reset": True},
+        "tok": {"shape": (s,), "dtype": "int32", "slot_axis": 0,
+                "reset": True},
+    }
+
+
+def plan_chunks(spec, n_tokens: int) -> List[Tuple[int, int]]:
+    """[(chunk length, tokens of it that count)] for a prefill of
+    ``n_tokens``: the largest length while more than the smallest is
+    left, then the smallest length that holds the rest."""
+    sizes = spec["prefill_chunks"]
+    out, left = [], int(n_tokens)
+    while left > 0:
+        size = next((c for c in sizes if c >= left), sizes[-1])
+        out.append((size, min(size, left)))
+        left -= size
+    return out
+
+
+# --- the forward pass -------------------------------------------------
+
+def _rms(x, gain, eps):
+    import jax.numpy as jnp
+    from jax import lax
+
+    x = x.astype(jnp.float32)
+    # the gain is a stored (1, n) row: it broadcasts as it is
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def _dense(x, w):
+    """``x @ w^T`` with operands in the weight's dtype, float32 out."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.dot_general(x.astype(w.dtype), w,
+                           (((x.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _ffn(p, pre, h, eps):
+    import jax
+
+    gu = _dense(h, p[pre + "w_gate_up"])
+    f = gu.shape[-1] // 2
+    a = jax.nn.silu(gu[..., :f]) * gu[..., f:]
+    return h + _rms(_dense(a, p[pre + "w_down"]), p[pre + "norm_ffn"], eps)
+
+
+def _lin_in(spec, p, pre, x):
+    """The linear layer's one input product, cut into the convolution's
+    input (q, k, v side by side), the output gate's, and the delta
+    rule's gates (log alpha, beta), float32."""
+    import jax
+    import jax.numpy as jnp
+
+    lh, w = spec["lin_heads"], _conv_width(spec)
+    proj = _dense(x, p[pre + "w_in"])
+    gate_at = w + lh * spec["lin_dv"]
+    beta = 2.0 * jax.nn.sigmoid(proj[..., gate_at:gate_at + lh])
+    dt = jax.nn.softplus(proj[..., gate_at + lh:] + p[pre + "dt_bias"])
+    log_alpha = -jnp.exp(p[pre + "a_log"]) * dt
+    return proj[..., :w], proj[..., w:gate_at], log_alpha, beta
+
+
+def _split_qkv(spec, c):
+    """Convolved activations (..., H (2 dk + dv)) -> normalised q, k and
+    v per head."""
+    import jax.numpy as jnp
+
+    lh, dk, dv = spec["lin_heads"], spec["lin_dk"], spec["lin_dv"]
+    q = c[..., :lh * dk].reshape(c.shape[:-1] + (lh, dk))
+    k = c[..., lh * dk:2 * lh * dk].reshape(c.shape[:-1] + (lh, dk))
+    v = c[..., 2 * lh * dk:].reshape(c.shape[:-1] + (lh, dv))
+
+    def l2(a):
+        return a / jnp.sqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+    return l2(q) * dk ** -0.5, l2(k), v
+
+
+def _lin_out(spec, p, pre, o, gate):
+    """Per-head RMSNorm of the rule's output, the output gate, W_o."""
+    import jax
+
+    o = _rms(o, p[pre + "o_norm"], spec["eps"])
+    o = o.reshape(o.shape[:-2] + (-1,)) * jax.nn.silu(gate)
+    return _dense(o, p[pre + "wo"])
+
+
+def _full_qkv(spec, p, pre, x):
+    """q and k under their whole-width RMSNorm, and v, float32."""
+    hq = spec["heads"] * spec["head_dim"]
+    proj = _dense(x, p[pre + "w_qkv"])
+    return (_rms(proj[..., :hq], p[pre + "q_norm"], spec["eps"]),
+            _rms(proj[..., hq:2 * hq], p[pre + "k_norm"], spec["eps"]),
+            proj[..., 2 * hq:])
+
+
+def _conv_taps(p, pre):
+    """(conv_k, width) float32; tap ``i`` multiplies ``u_{t-i}``."""
+    import jax.numpy as jnp
+
+    return p[pre + "conv"].astype(jnp.float32)
+
+
+def build_step(spec):
+    """``step(params, slab, active) -> (slab', ids, logits)``: one token
+    for every slot whose ``active`` is set; row = slot."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    eps = spec["eps"]
+    types = spec["layer_types"]
+    heads, hd = spec["heads"], spec["head_dim"]
+    keep = spec["conv_k"] - 1
+
+    def hybrid_lm_step(p, slab, active):
+        slab = dict(slab)
+        S, conv = slab["S"], slab["conv"]
+        pos, tok = slab["pos"], slab["tok"]
+        cdt = conv.dtype
+        x = p["embed"][jnp.clip(tok, 0)].astype(jnp.float32)
+        li = fi = 0
+        for i, kind in enumerate(types):
+            pre = f"l{i:02d}."
+            if kind == LINEAR:
+                u, gate, log_alpha, beta = _lin_in(spec, p, pre, x)
+                u = u.astype(cdt)
+                window = jnp.concatenate([conv[li], u[None]], axis=0)
+                taps = _conv_taps(p, pre)
+                win32 = window.astype(jnp.float32)   # oldest input first
+                c = jax.nn.silu(sum(taps[j] * win32[keep - j]
+                                    for j in range(keep + 1)))
+                q, k, v = _split_qkv(spec, c)
+                S_new, o = gated_delta_step_flat(S[li], q, k, v, log_alpha,
+                                                 beta)
+                S = S.at[li].set(jnp.where(
+                    active[:, None, None], S_new, S[li]))
+                conv = conv.at[li].set(jnp.where(
+                    active[None, :, None], window[1:], conv[li]))
+                mix = _lin_out(spec, p, pre, o, gate)
+                li += 1
+            else:
+                q, k, v = _full_qkv(spec, p, pre, x)
+                # an idle slot's row lands beyond its length, where
+                # nothing reads before the slot's own next token
+                # overwrites it
+                kc = cache_write_rows(slab[f"k{fi}"],
+                                      k.reshape(-1, heads, hd), pos)
+                vc = cache_write_rows(slab[f"v{fi}"],
+                                      v.reshape(-1, heads, hd), pos)
+                slab[f"k{fi}"], slab[f"v{fi}"] = kc, vc
+                o = cached_attention(q.reshape(-1, 1, heads, hd), kc, vc,
+                                     pos[:, None])
+                mix = _dense(o.reshape(-1, heads * hd), p[pre + "wo"])
+                fi += 1
+            h = x + _rms(mix, p[pre + "norm_mix"], eps)
+            x = _ffn(p, pre, h, eps)
+        logits = _dense(_rms(x, p["final_norm"], eps), p["lm_head"])
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        slab.update(S=S, conv=conv, pos=jnp.where(active, pos + 1, pos),
+                    tok=jnp.where(active, ids, tok))
+        return slab, ids, logits
+
+    return hybrid_lm_step
+
+
+def build_prefill(spec, chunk: int):
+    """``prefill(params, slab, slot, tokens, n_valid, next_tok) ->
+    slab'``: one slot consumes ``n_valid`` of ``chunk`` token ids (an id
+    below zero stands for the slot's own ``tok``); ``next_tok >= 0``
+    becomes the slot's ``tok``. No logits."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    eps = spec["eps"]
+    types = spec["layer_types"]
+    heads, hd = spec["heads"], spec["head_dim"]
+    keep = spec["conv_k"] - 1
+
+    def hybrid_lm_prefill(p, slab, slot, tokens, n_valid, next_tok):
+        slab = dict(slab)
+        S, conv = slab["S"], slab["conv"]
+        pos, tok = slab["pos"], slab["tok"]
+        cdt = conv.dtype
+        pos0 = pos[slot]
+        valid = jnp.arange(chunk) < n_valid
+        tokens = jnp.where(tokens < 0, tok[slot], tokens)
+        x = p["embed"][tokens].astype(jnp.float32)
+        li = fi = 0
+        last = len(types) - 1
+        for i, kind in enumerate(types):
+            pre = f"l{i:02d}."
+            if kind == LINEAR:
+                u, gate, log_alpha, beta = _lin_in(spec, p, pre, x)
+                u = u.astype(cdt)
+                seq = jnp.concatenate([conv[li, :, slot], u], axis=0)
+                taps = _conv_taps(p, pre)
+                seq32 = seq.astype(jnp.float32)
+                c = jax.nn.silu(sum(
+                    taps[j] * lax.dynamic_slice_in_dim(seq32, keep - j,
+                                                       chunk, axis=0)
+                    for j in range(keep + 1)))
+                q, k, v = _split_qkv(spec, c)
+                # a padded token leaves the state as it is
+                log_alpha = jnp.where(valid[:, None], log_alpha, 0.0)
+                beta = jnp.where(valid[:, None], beta, 0.0)
+                S_new, o = gated_delta_chunked(
+                    heads_first(S[li, slot], spec["lin_heads"]), q, k, v,
+                    log_alpha, beta, spec["delta_chunk"])
+                S = S.at[li, slot].set(heads_on_lanes(S_new))
+                conv = conv.at[li, :, slot].set(
+                    lax.dynamic_slice_in_dim(seq, n_valid, keep, axis=0))
+                mix = _lin_out(spec, p, pre, o, gate)
+                li += 1
+            else:
+                q, k, v = _full_qkv(spec, p, pre, x)
+                # the whole chunk is written at pos; what it writes past
+                # n_valid lies beyond the slot's length (the cache's margin)
+                def put(cache, rows):
+                    rows = jnp.moveaxis(rows.reshape(chunk, heads, hd), 0, 1)
+                    return lax.dynamic_update_slice(
+                        cache, rows[None].astype(cache.dtype),
+                        (slot, 0, pos0, 0))
+
+                kc, vc = put(slab[f"k{fi}"], k), put(slab[f"v{fi}"], v)
+                slab[f"k{fi}"], slab[f"v{fi}"] = kc, vc
+                q_pos = jnp.where(valid, pos0 + jnp.arange(chunk), -1)
+                o = cached_attention(q.reshape(1, chunk, heads, hd), kc, vc,
+                                     q_pos[None], row0=slot)
+                mix = _dense(o.reshape(chunk, heads * hd), p[pre + "wo"])
+                fi += 1
+            h = x + _rms(mix, p[pre + "norm_mix"], eps)
+            if i == last:
+                break     # the last layer's FFN feeds only the head
+            x = _ffn(p, pre, h, eps)
+        slab.update(S=S, conv=conv, pos=pos.at[slot].add(n_valid),
+                    tok=tok.at[slot].set(
+                        jnp.where(next_tok >= 0, next_tok, tok[slot])))
+        return slab
+
+    return hybrid_lm_prefill
+
+
+# --- deployment -------------------------------------------------------
+
+def deploy(client, db: str, spec: Dict[str, Any], weights) -> Dict[str, Any]:
+    """Create ``db`` and fill its sets: ``weights(name, shape,
+    is_matrix)`` returns each tensor (host or device array) already in
+    its dtype. ``client`` is the daemon's in-process library (the
+    wire's array codec carries no bfloat16; float32 weights may come
+    through a ``RemoteClient`` too). Returns the spec."""
+    client.create_database(db)
+    client.create_set(db, SPEC_SET)
+    client.send_data(db, SPEC_SET, [dict(spec)])
+    for name, (shape, is_matrix) in weight_shapes(spec).items():
+        client.create_set(db, name, type_name="matrix")
+        client.send_matrix(db, name, weights(name, shape, is_matrix),
+                           block_for(shape))
+    return spec
+
+
+def random_weights(spec, seed: int):
+    """A ``weights`` callback for :func:`deploy`: small seeded normal
+    weights on the host (tests and smokes; the benchmark makes its own
+    on the device)."""
+    import ml_dtypes
+
+    rng = np.random.default_rng(seed)
+    dtype = np.dtype(getattr(ml_dtypes, spec["dtype"], None)
+                     or spec["dtype"])
+
+    def make(name, shape, is_matrix):
+        leaf = name.rsplit(".", 1)[-1]
+        if is_matrix:
+            w = rng.standard_normal(shape) / np.sqrt(shape[1])
+            return w.astype(np.float32).astype(dtype)
+        if leaf == "a_log":
+            return rng.uniform(-3.0, 1.0, shape).astype(np.float32)
+        if leaf == "dt_bias":
+            return rng.uniform(-4.0, -2.0, shape).astype(np.float32)
+        if leaf == "conv":
+            return (rng.standard_normal(shape) * 0.5).astype(np.float32)
+        return (1.0 + 0.1 * rng.standard_normal(shape)).astype(np.float32)
+
+    return make

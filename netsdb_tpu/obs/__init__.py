@@ -36,6 +36,7 @@ from netsdb_tpu.obs.trace import (  # noqa: F401
     current_trace,
     enabled,
     new_query_id,
+    record_into,
     sample_qid,
     set_enabled,
     span,
@@ -46,6 +47,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "registry", "DEFAULT_RING", "QidSampler", "QueryTrace", "Span",
     "TraceRing", "add", "adopt", "attrib", "capture", "current_trace",
-    "enabled", "new_query_id", "operators", "sample_qid", "set_enabled",
+    "enabled", "new_query_id", "operators", "record_into", "sample_qid",
+    "set_enabled",
     "span", "trace",
 ]

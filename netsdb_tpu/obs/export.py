@@ -300,6 +300,22 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                       "session's home daemon that "
                                       "failed (re-marked, retried "
                                       "next housekeeping tick)"),
+        ("session.prefill_tokens", "prompt token ids consumed by "
+                                   "prefill chunks (a turn's appended "
+                                   "ids but the last, which the first "
+                                   "decode step consumes)"),
+        ("session.decode_tokens", "tokens generated by decode steps of "
+                                  "language-model sessions (one per "
+                                  "live session per step)"),
+        ("session.slab.spills", "slots of a model's session slab "
+                                "copied to the host arena (lease "
+                                "evicted under pressure or expired)"),
+        ("session.slab.revives", "session states written back from "
+                                 "the host arena into a slab slot"),
+        ("session.state_host_bytes", "bytes of session state that "
+                                     "crossed the host (spill, revive, "
+                                     "pack for a move or handoff); "
+                                     "flat across warm steps"),
     )
     gauges = (
         ("placement.epoch", "the placement map's global epoch (bumps "
@@ -330,6 +346,9 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("session.resident_bytes", "bytes of per-session decode state "
                                    "currently resident in the device "
                                    "cache"),
+        ("session.slab.slots_live", "slots of the last touched "
+                                    "model's session slab that hold a "
+                                    "session"),
         ("dedup.page_bytes", "unique model weight-page bytes resident "
                              "after cross-model deduplication "
                              "(compare against the per-model "

@@ -410,6 +410,29 @@ def adopt(captured) -> Iterator[None]:
         _current.reset(token)
 
 
+def record_into(captured, name: str, duration_s: float,
+                category: str = "", ended_ago_s: float = 0.0,
+                **counters) -> None:
+    """Record a region that ended ``ended_ago_s`` ago (just now by
+    default), ``duration_s`` long, into a :func:`capture`-d trace from
+    a thread that serves many requests' traces at once and so adopts
+    none (a decode batch leader): the span becomes a child of the span
+    that was open where the trace was captured. A no-op for None; a
+    trace that has finished meanwhile keeps the span off its pushed
+    profile."""
+    if captured is None:
+        return
+    tr, (parent, depth) = captured
+    sp = Span(name, category,
+              (time.perf_counter() - tr._t0) - float(ended_ago_s)
+              - float(duration_s), depth, next(tr._ids), parent)
+    sp.duration_s = float(duration_s)
+    if counters:
+        sp.counters.update(counters)
+    with tr._mu:
+        tr._spans.append(sp)
+
+
 @contextlib.contextmanager
 def trace(qid: Optional[str] = None, origin: str = "local",
           ring: Optional[TraceRing] = None) -> Iterator[Optional[QueryTrace]]:

@@ -88,6 +88,126 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return num / jnp.maximum(den, 1e-30)
 
 
+def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     q_pos: jax.Array, block_size: Optional[int] = None,
+                     scale: Optional[float] = None, row0=None) -> jax.Array:
+    """Attention of new queries over a per-row cache — the decode path
+    (one query a row) and the chunked-prefill path (one row, a chunk of
+    queries) of a model that keeps keys and values in slot-indexed
+    caches.
+
+    ``q`` (B, Lq, H, D). ``k_cache`` and ``v_cache`` are (rows, H, T,
+    D): tokens and head_dim are the two minor axes, so a bfloat16 cache
+    tiles without padding. With ``row0=None`` every row is attended
+    (``B`` = rows) and the caches are the products' operands as they
+    stand, never sliced or copied; with a (possibly traced) ``row0``
+    the ``B`` rows from there are. ``q_pos`` (B, Lq) int32 is the
+    position of each query: key ``j`` of row ``b`` is visible to query
+    ``i`` when ``j <= q_pos[b, i]``. A query with ``q_pos < 0`` sees
+    nothing and yields zeros.
+
+    ``block_size=None`` reads a row's whole cache in one pass (masked
+    beyond each query's position): the fewest operations, and what a
+    batch of rows near their cache's end costs anyway. With a
+    ``block_size`` (a divisor of ``T``) the cache is walked in blocks
+    with the online softmax and the walk stops after the block that
+    holds the largest position: what lies beyond the longest row's
+    length is never read. Products take the cache's dtype as operands
+    and accumulate in float32; returns float32 (B, Lq, H, D)."""
+    b, lq, h, d = q.shape
+    t = k_cache.shape[2]
+    block_size = block_size or t
+    if t % block_size:
+        raise ValueError(f"cache length {t} is not a multiple of the "
+                         f"block {block_size}")
+    scale = scale if scale is not None else d ** -0.5
+    qs = jnp.moveaxis((q.astype(jnp.float32) * scale).astype(k_cache.dtype),
+                      1, 2)                                # (B, H, Lq, D)
+    whole = row0 is None and block_size == t
+
+    def block(cache, i):
+        if whole:
+            return cache
+        start = (jnp.asarray(0 if row0 is None else row0, jnp.int32),
+                 jnp.int32(0), jnp.asarray(i * block_size, jnp.int32),
+                 jnp.int32(0))
+        return jax.lax.dynamic_slice(cache, start, (b, h, block_size, d))
+
+    def body(i, carry):
+        num, den, mx = carry
+        logits = jnp.einsum("bhqd,bhkd->bhqk", qs, block(k_cache, i),
+                            preferred_element_type=jnp.float32)
+        k_pos = i * block_size + jnp.arange(block_size)
+        mask = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
+        logits = jnp.where(mask, logits, NEG_INF)
+        new_max = jnp.maximum(mx, logits.max(-1, keepdims=True))
+        corr = jnp.exp(mx - new_max)
+        p = jnp.where(mask, jnp.exp(logits - new_max), 0.0)
+        den = den * corr + p.sum(-1, keepdims=True)
+        num = num * corr + jnp.einsum(
+            "bhqk,bhkd->bhqd", p.astype(v_cache.dtype), block(v_cache, i),
+            preferred_element_type=jnp.float32)
+        return num, den, new_max
+
+    carry = (jnp.zeros((b, h, lq, d), jnp.float32),
+             jnp.zeros((b, h, lq, 1), jnp.float32),
+             jnp.full((b, h, lq, 1), NEG_INF, jnp.float32))
+    if block_size == t:
+        num, den, _ = body(0, carry)
+    else:
+        n_blocks = jnp.minimum(
+            (jnp.max(q_pos) + block_size) // block_size, t // block_size)
+        num, den, _ = jax.lax.fori_loop(0, n_blocks, body, carry)
+    out = num / jnp.maximum(den, 1e-30)
+    return jnp.moveaxis(out, 1, 2)
+
+
+def cache_write_rows(cache: jax.Array, new: jax.Array,
+                     pos: jax.Array) -> jax.Array:
+    """Write one token's row into every row of a cache, in place:
+    ``cache[b, :, pos[b], :] = new[b]`` for ``cache`` (B, H, T, D),
+    ``new`` (B, H, D) and ``pos`` (B,) int32 — a decode step's append
+    for a whole batch in ONE kernel (``cache_write_rows`` in a device
+    trace), where a scatter makes XLA re-lay the cache and a
+    ``dynamic_update_slice`` a row is B operations.
+
+    The kernel walks the batch; for row ``b`` the block of ``ROWS``
+    tokens that holds ``pos[b]`` (found from the scalar-prefetched
+    ``pos``) is read, the one token replaced, and the block written
+    back to the aliased cache. ``T`` must be a multiple of 16."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from netsdb_tpu.ops.common import pallas_interpret
+
+    bsz, h, t, d = cache.shape
+    rows = 16
+    if t % rows:
+        raise ValueError(f"cache length {t} is not a multiple of {rows}")
+
+    def kernel(pos_ref, new_ref, cache_ref, out_ref):
+        at = pos_ref[pl.program_id(0)] % rows
+        blk = cache_ref[...]                               # (1, H, rows, D)
+        token = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
+        fresh = jnp.broadcast_to(new_ref[...][:, :, None, :], blk.shape)
+        out_ref[...] = jnp.where(token == at, fresh, blk)
+
+    def block_of(b, pos_ref):
+        return b, 0, pos_ref[b] // rows, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(bsz,),
+        in_specs=[pl.BlockSpec((1, h, d), lambda b, pos_ref: (b, 0, 0)),
+                  pl.BlockSpec((1, h, rows, d), block_of)],
+        out_specs=pl.BlockSpec((1, h, rows, d), block_of))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        input_output_aliases={2: 0}, name="cache_write_rows",
+        interpret=pallas_interpret())(
+            pos.astype(jnp.int32), new.astype(cache.dtype), cache)
+
+
 def split_qkv_heads(qkv: jax.Array, num_heads: int):
     """Packed (B,S,3E) projection → q/k/v (B,H,S,D) — THE layout
     convention (split into thirds, then head reshape/transpose); every

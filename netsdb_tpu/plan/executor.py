@@ -98,7 +98,9 @@ def compiled_cache_keys() -> List[str]:
 
 
 def _cached_jit(key: str, fn, donate_argnums: tuple = (),
-                region: Optional[str] = None) -> Any:
+                region: Optional[str] = None,
+                name: Optional[str] = None,
+                compiler_options: Optional[Dict[str, Any]] = None) -> Any:
     """compiled-cache get-or-insert with the ONE LRU discipline (all
     call sites: fold steps, eager traceable nodes, fusion-region
     programs, whole-plan programs). The wrapper is published BEFORE
@@ -114,7 +116,12 @@ def _cached_jit(key: str, fn, donate_argnums: tuple = (),
     ``region`` names the fusion region this program compiles
     (``"job:fingerprint"``) — its retraces tick the per-region map
     ``compile_stats()["region_traces"]`` alongside the global
-    ``traces`` counter."""
+    ``traces`` counter.
+
+    ``name`` names the jitted wrapper, and with it the XLA module a
+    device trace shows (``jit_<name>``); unnamed programs keep the
+    wrapper's own name. ``compiler_options`` are this one program's
+    XLA options (``jax.jit``'s own argument)."""
     with _cache_lock:
         cached = _compiled_cache.get(key)
         if cached is not None:
@@ -138,7 +145,10 @@ def _cached_jit(key: str, fn, donate_argnums: tuple = (),
         obs.operators.op_add("traces")
         return fn(*args, **kwargs)
 
-    jfn = jax.jit(counted, donate_argnums=tuple(donate_argnums))
+    if name:
+        counted.__name__ = counted.__qualname__ = name
+    jfn = jax.jit(counted, donate_argnums=tuple(donate_argnums),
+                  compiler_options=compiler_options or None)
     with _cache_lock:
         _compile_stats["misses"] += 1
         jfn = _compiled_cache.setdefault(key, jfn)
@@ -1266,3 +1276,19 @@ def clear_compiled_cache() -> None:
     with _cache_lock:
         _compiled_cache.clear()
         _region_traces.clear()
+
+
+def drop_compiled(prefix: str) -> int:
+    """Drop the cached programs whose key starts with ``prefix`` (one
+    tenant of the LRU clearing its own programs, e.g. the decode
+    programs of ``models/decode.py``). Returns how many went."""
+    with _cache_lock:
+        keys = [k for k in _compiled_cache if k.startswith(prefix)]
+        for k in keys:
+            del _compiled_cache[k]
+    return len(keys)
+
+
+#: the compiled-program LRU for programs built outside this module
+#: (the session decode programs): same get-or-insert, same counters.
+cached_jit = _cached_jit

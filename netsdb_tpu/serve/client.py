@@ -60,6 +60,7 @@ from netsdb_tpu.serve.protocol import (
     PROTO_VERSION,
     QUERY_ID_KEY,
     SESSION_KEY,
+    session_output_set,
     SHARD_SLOT_KEY,
     MsgType,
     ProtocolError,
@@ -2140,17 +2141,28 @@ class SessionHandle:
         self.owner = owner
         return owner
 
-    def generate(self, x, deadline_s: float = 30.0) -> np.ndarray:
-        """One decode step: returns the model's output row for this
-        session. Retries typed-retryable failures (owner moves,
-        failovers, deaths) under ``deadline_s`` with ONE idempotency
-        token for the whole logical step."""
+    def generate(self, x=None, deadline_s: float = 30.0, *,
+                 tokens=None, new_tokens: Optional[int] = None
+                 ) -> np.ndarray:
+        """One frame. For a kind that steps on an input row, ``x`` is
+        the row and the return is the model's output row. For a
+        language model, ``tokens`` are the int32 ids to append (a
+        turn's prompt) and ``new_tokens`` how many to generate: the
+        return is the ids the daemon chose, and the float32 logits of
+        the last of them stay in the daemon (``last_logits``). Retries
+        typed-retryable failures (owner moves, failovers, deaths) under
+        ``deadline_s`` with ONE idempotency token for the whole frame."""
         if self._closed:
             raise RuntimeError(f"session {self.sid!r} is closed")
         payload = {"db": self.db, "set": self.sid, "sid": self.sid,
-                   "x": np.asarray(x, np.float32),
                    SESSION_KEY: self.sid,
                    IDEMPOTENCY_KEY: uuid.uuid4().hex}
+        if x is not None:
+            payload["x"] = np.asarray(x, np.float32)
+        else:
+            payload["tokens"] = np.asarray(
+                () if tokens is None else tokens, np.int32)
+            payload["new_tokens"] = int(new_tokens or 0)
         deadline = deadline_after(deadline_s)
         attempt = 0
         while True:
@@ -2164,7 +2176,7 @@ class SessionHandle:
                     self.moves += 1
                     self.owner = new_owner
                 self.steps = int(rep.get("steps", self.steps + 1))
-                return np.asarray(rep["y"])
+                return np.asarray(rep["y"] if "y" in rep else rep["ids"])
             except SessionMovedError as e:
                 self.moves += 1
                 self.owner = getattr(e, "owner_addr", None) or \
@@ -2193,6 +2205,12 @@ class SessionHandle:
                     "DeadlineExceeded",
                     f"generate deadline of {deadline_s}s exhausted "
                     f"after {attempt} attempt(s)")
+
+    def last_logits(self) -> np.ndarray:
+        """The float32 logits row of the last frame's last step (a
+        language model), read from the session's output set."""
+        return np.asarray(self._client.get_tensor(
+            self.db, session_output_set(self.sid)).to_dense()).reshape(-1)
 
     def _safe_lookup(self, deadline, cause) -> str:
         """Owner re-discovery that tolerates the election window: a

@@ -346,6 +346,15 @@ SHARD_SLOT_KEY = "__slot__"
 #: analytics never starve behind a decode loop (or vice versa).
 SESSION_KEY = "__session__"
 
+
+def session_output_set(sid: str) -> str:
+    """The set of the model's database that holds the float32 logits
+    row of a session's last frame's last step (a language-model decode
+    kind): a GENERATE reply carries the chosen ids only, and a caller
+    that wants the row reads this set with GET_TENSOR instead of paying
+    for a vocabulary-wide row on the wire with every frame."""
+    return f"logits.{sid}"
+
 #: frame types that mutate daemon state or launch jobs — the set the
 #: client attaches idempotency tokens to before retrying. Reads are
 #: naturally idempotent and retried bare. (BULK_BEGIN carries its

@@ -2,33 +2,53 @@
 
 A *session* is a named, TTL'd decode loop over one registered model
 (``models/decode.py``): ``SESSION_OPEN`` binds ``sid → (model, owner,
-ttl)``, each ``GENERATE`` advances the session's recurrent state by
-one step, ``SESSION_CLOSE`` drops it. Three stores cooperate, fastest
-first:
+ttl)``, each ``GENERATE`` advances the session's state by one frame (a
+toy kind: one step on an input row; a language model: a TURN — token
+ids appended, then a number of new tokens generated), ``SESSION_CLOSE``
+drops it. Three stores cooperate, fastest first:
 
-* **Device cache** (``storage/devcache.py`` session entries) — the hot
-  copy: one MUTABLE entry per ``(session, model, layer)``, updated in
-  place every step. The methods mutating it are called ONLY from this
-  module (the ``session-state-mutation`` lint rule).
-* **Host arena** (:class:`SessionArena`) — where evicted/expired
-  layers land via the devcache spill callback, and where a session
-  revives from after pressure, TTL expiry, or owner failover. A warm
-  decode step never touches it (``arena.reads`` is the structural
-  gate's counter).
+* **The model's slab** (``storage/devcache.SessionSlab``) — the hot
+  copy: per registered model one set of device arrays with a slot
+  axis, every slot one session's whole state (recurrent state,
+  convolution window, key/value cache, position: whatever the kind's
+  layout declares). A session LEASES a slot — one MUTABLE entry of the
+  device cache per ``(session, model)``, charged the slot's bytes —
+  from open to close or eviction. A warm step takes the slab, runs ONE
+  program over every slot (row = slot, the idle ones masked) that
+  donates it, and stores what comes back: no state crosses the host
+  (``session.state_host_bytes`` stays flat). The methods mutating the
+  cache's session entries are called ONLY from this module (the
+  ``session-state-mutation`` lint rule).
+* **Host arena** (:class:`SessionArena`) — where an evicted or expired
+  lease's slot lands via the devcache spill callback (one slot's
+  slices copied to the host), and where a session revives from after
+  pressure, TTL expiry, or owner failover (the slices written back
+  into a free slot). A warm decode step never touches it
+  (``arena.reads`` is the structural gate's counter).
 * **The replicated session table** (:class:`SessionTable`) — sid →
   metadata. Not replicated by itself: the MIRRORED ``SESSION_OPEN`` /
   ``GENERATE`` / ``SESSION_CLOSE`` frames replay at every follower,
-  which re-derives the same table (and the same devcache/arena state,
+  which re-derives the same table (and the same slab/arena state,
   since decode is deterministic) — the HA-log-shipping discipline the
   data plane already uses, reused verbatim for sessions.
 
-Every layer value is stored STEP-TAGGED (``{"step": n, "v": array}``)
-in both the devcache and the arena. The newest copy of each layer is
-always in exactly one of the two (resident beats arena; the arena
-keeps the highest-step spill), so a revive assembled layer-by-layer
-is consistent by construction — and a torn assembly (which would mean
-a bookkeeping bug, not a race) raises instead of silently decoding
-from mixed steps.
+State is STEP-TAGGED: the lease carries the step its slot is at, and
+every layer spilled to the arena carries the step it was read at. The
+newest copy of a session's state is in exactly one of the two
+(resident beats arena; the arena keeps the highest-step spill). A slot
+is read and written whole under the slab's lock, so a revive is
+consistent by construction — and a torn assembly (layers of mixed
+steps in the arena, which would mean a bookkeeping bug, not a race)
+raises instead of silently decoding from mixed steps.
+
+Batching is continuous (``sched/sessions.DecodeBatcher``): a model's
+leader thread runs :meth:`SessionManager._run_batch` once an
+iteration over the frames that are live. An iteration admits the
+frames that joined (seats their sessions), dispatches at most ONE
+chunk of ONE joining turn's prompt and then one decode step over every
+turn that is past its prompt, and retires the turns whose last step's
+outputs have reached the host — one step behind the dispatch, so that
+the device has the next program queued while the host reads.
 
 Ownership and stickiness: the pool leader places each session
 deterministically (itself, or one live worker by sid hash), pushing
@@ -36,25 +56,34 @@ deterministically (itself, or one live worker by sid hash), pushing
 first session per (owner, model) — to a worker owner. A frame landing
 on a non-owner answers the typed retryable ``SessionMoved`` carrying
 the owner's address; the client re-points and retries under the SAME
-idempotency token, so a step is never double-applied to one state
-copy, and a re-applied step after failover recomputes bit-identically
+idempotency token, so a frame is never double-applied to one state
+copy, and a re-applied frame after failover recomputes bit-identically
 from the last durable state."""
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from netsdb_tpu import obs
 from netsdb_tpu.models import decode as _decode
 from netsdb_tpu.serve.errors import ServeFault, SessionMoved, SessionUnknown
-from netsdb_tpu.serve.protocol import MsgType, CODEC_PICKLE
-from netsdb_tpu.serve.sched.sessions import DecodeBatcher
+from netsdb_tpu.serve.protocol import (CODEC_PICKLE, MsgType,
+                                       session_output_set)
+from netsdb_tpu.serve.sched.sessions import CONTINUE, DecodeBatcher
+from netsdb_tpu.storage.devcache import SessionSlab
 from netsdb_tpu.utils.locks import TrackedLock
+
+#: the devcache "layer" name of a session's slot lease
+LEASE = "slot"
+
+
+output_set = session_output_set
 
 
 def _host(value: Any) -> np.ndarray:
@@ -248,14 +277,23 @@ class SessionManager:
         self.ttl_s = float(getattr(cfg, "session_ttl_s", 600.0))
         self.state_cap = int(getattr(cfg, "session_state_bytes",
                                      16 << 20))
+        batch_max = int(getattr(cfg, "decode_batch_max", 8))
         self.runtime = _decode.DecodeRuntime(
             ctl.library,
-            model_dedup=bool(getattr(cfg, "model_dedup", False)))
+            model_dedup=bool(getattr(cfg, "model_dedup", False)),
+            slots=batch_max)
         self.table = SessionTable()
         self.arena = SessionArena()
-        self.batcher = DecodeBatcher(
-            self._run_batch,
-            max_batch=int(getattr(cfg, "decode_batch_max", 8)))
+        self.batcher = DecodeBatcher(self._run_batch, max_batch=batch_max)
+        # model -> its slab (also registered with the device cache; kept
+        # here too because the spill callback runs UNDER the cache's
+        # lock and must not ask the cache for it)
+        self._slabs: Dict[str, SessionSlab] = {}
+        self._slabs_mu = TrackedLock("SessionManager._slabs_mu")
+        # model -> {"turns": sid -> the live turn, "steps": dispatched
+        # steps whose outputs the host has not read yet}. Touched by the
+        # model's batch leader alone (one iteration at a time)
+        self._lanes: Dict[str, Dict[str, Any]] = {}
         # models whose dense weights already shipped to an owner —
         # later sessions of the same (owner, model) adopt weight-less.
         # Guarded by _shipped_mu (handler threads race on it) and
@@ -267,7 +305,7 @@ class SessionManager:
         self._shipped: set = set()
         self._shipped_mu = TrackedLock("SessionManager._shipped_mu")
         # per-session last-applied idempotency record
-        # {token, steps, y}: the daemon-local idempotency cache only
+        # {token, steps, out (the reply's arrays)}: the daemon-local idempotency cache only
         # dedupes retries that land on the SAME daemon — this record
         # travels WITH the state (spill push, move, handoff, adopt),
         # so a retry under the same token landing at the session's
@@ -346,117 +384,201 @@ class SessionManager:
     def _cache(self):
         return self._ctl.library.store.device_cache()
 
+    def _slab(self, db: str) -> SessionSlab:
+        """``db``'s slab, allocated (zeroed, whole) on first use."""
+        with self._slabs_mu:
+            slab = self._slabs.get(db)
+        if slab is None:
+            # made and installed outside _slabs_mu: the spill callback
+            # takes that lock UNDER the cache's (first install wins)
+            slab = self._cache().slab_install(db, SessionSlab(
+                db, self.runtime.new_slab(db), self.runtime.slots(db),
+                self.runtime.slot_nbytes(db)))
+            with self._slabs_mu:
+                self._slabs[db] = slab
+            self.batcher.set_max_batch(
+                db, max(self.batcher.max_batch, slab.slots))
+        return slab
+
     def _on_spill(self, sid: str, model: str, layer: str,
                   value: Any) -> None:
         """Devcache eviction/expiry escape hatch — LEAF (runs under
-        the cache lock): host-copy the layer into the arena, tagged
-        with its own step."""
+        the cache lock): copy the lease's slot to the host, every layer
+        tagged with the lease's step, into the arena, and free the
+        slot."""
         try:
-            rec = value if isinstance(value, dict) else {
-                "step": self.table.steps(sid), "v": value}
-            self.arena.merge_layer(
-                sid, model, layer, int(rec.get("step", 0)),
-                _host(rec["v"]), steps_hint=self.table.steps(sid))
+            with self._slabs_mu:
+                slab = self._slabs.get(model)
+            if slab is None or layer != LEASE:
+                return
+            with slab.mu:
+                host = self.runtime.read_slot(model, slab.arrays,
+                                              int(value["slot"]))
+                slab.give(int(value["slot"]))
+            self._to_arena(sid, model, host, int(value.get("step", 0)))
+            obs.REGISTRY.counter("session.slab.spills").inc()
         except Exception as e:  # noqa: BLE001 — spill must never
             # take the cache down with it; the arena just misses
             # this copy (counted, last fault kept for stats())
             self._last_spill_fault = repr(e)
             obs.REGISTRY.counter("session.spill_errors").inc()
 
-    def _install_state(self, sid: str, db: str, ttl_s: float,
-                       state: Dict[str, Any], step: int) -> None:
-        for layer, v in state.items():
-            self._cache().session_put(sid, db, layer,
-                                      {"step": int(step), "v": v},
-                                      ttl_s)
+    def _to_arena(self, sid: str, db: str, host: Dict[str, np.ndarray],
+                  step: int) -> None:
+        for layer, v in host.items():
+            self.arena.merge_layer(sid, db, layer, step, v,
+                                   steps_hint=self.table.steps(sid))
+        obs.REGISTRY.counter("session.state_host_bytes").inc(
+            sum(v.nbytes for v in host.values()))
 
-    def _load_state(self, sid: str, db: str,
-                    ttl_s: float) -> Tuple[Dict[str, Any], int]:
-        """Assemble the session's CURRENT state layer by layer:
-        newest copy wins — the resident devcache entry, unless the
-        arena's spill for that layer is NEWER (then the arena copy
-        revives and re-installs). A resident copy can legitimately be
-        stale: a mirror follower replays ``op=open`` owning the
-        session itself and installs init state at step 0, while a
+    def _take_slot(self, db: str, slab: SessionSlab) -> Optional[int]:
+        """A free slot of ``db``'s slab; where none is free, the least
+        recently used lease of a session that is not in a live turn is
+        evicted (spilled) to make one. None when every slot is in a
+        live turn."""
+        with slab.mu:
+            slot = slab.take()
+        if slot is None:
+            busy = set(self._lanes.get(db, {}).get("turns", ()))
+            if self._cache().session_evict_one(db, skip=busy) is None:
+                return None
+            with slab.mu:
+                slot = slab.take()
+        return slot
+
+    def _seat(self, sid: str, db: str, ttl_s: float
+              ) -> Optional[Tuple[int, int, bool]]:
+        """The session's CURRENT state in a slot: ``(slot, step,
+        leased)``, or None when no slot can be had right now (every
+        slot is in a live turn). Newest copy wins — the resident
+        lease's slot, unless the arena's spill is NEWER (then the arena
+        copy revives into the slot). A resident copy can legitimately
+        be stale: a mirror follower replays ``op=open`` owning the
+        session itself and seats init state at step 0, while a
         worker-owned session's durability arrives only via mirrored
-        ``op=spill`` merges into the arena — after promotion the
-        step-0 resident layers would otherwise assemble consistently
-        and silently rewind the session. All layers must land on one
-        step — a mixed assembly is a torn state and raises rather
-        than decoding garbage."""
-        layers = self.runtime.state_layers(db)
-        out: Dict[str, Any] = {}
-        steps_seen = set()
+        ``op=spill`` merges into the arena — after promotion the step-0
+        resident slot would otherwise silently rewind the session. All
+        layers of a revive must carry one step — a mixed assembly is a
+        torn state and raises rather than decoding garbage. ``leased``
+        is False when the device cache's budget cannot hold one slot:
+        the slot is then the frame's alone and goes back to the arena
+        when the frame retires."""
+        slab = self._slab(db)
+        nbytes = slab.slot_nbytes
+        lease = self._cache().session_get(sid, db, LEASE)
         # the arena's high-water step, read WITHOUT a read tick: on a
-        # warm step every resident layer is at least this new, so the
+        # warm step the resident slot is at least this new, so the
         # zero-warm-arena-reads gate still holds
         arena_steps = self.arena.steps(sid, db)
-        for layer in layers:
-            rec = self._cache().session_get(sid, db, layer)
-            if rec is not None and int(rec["step"]) < arena_steps:
-                newer = self.arena.get_layer(sid, db, layer)
-                if newer is not None \
-                        and int(newer["step"]) > int(rec["step"]):
-                    rec = newer
-                    self._cache().session_put(sid, db, layer,
-                                              dict(rec), ttl_s)
-            if rec is None:
-                rec = self.arena.get_layer(sid, db, layer)
-                if rec is not None:
-                    self._cache().session_put(sid, db, layer,
-                                              dict(rec), ttl_s)
-            if rec is None:
-                if self.table.steps(sid) == 0 and arena_steps == 0:
-                    rec = {"step": 0,
-                           "v": self.runtime.init_state(db)[layer]}
-                    self._cache().session_put(sid, db, layer,
-                                              dict(rec), ttl_s)
-                else:
+        if lease is not None and int(lease["step"]) >= arena_steps:
+            self.table.set_steps(sid, int(lease["step"]))
+            return int(lease["slot"]), int(lease["step"]), True
+        slot = int(lease["slot"]) if lease is not None \
+            else self._take_slot(db, slab)
+        if slot is None:
+            return None
+        layers = {name: self.arena.get_layer(sid, db, name)
+                  for name in self.runtime.state_layout(db)}
+        found = [r for r in layers.values() if r is not None]
+        try:
+            if not found:
+                if self.table.steps(sid) != 0 or arena_steps != 0:
                     raise SessionUnknown(
-                        f"session {sid!r} state layer {layer!r} lost "
-                        f"(not resident, no arena spill)")
-            out[layer] = rec["v"]
-            steps_seen.add(int(rec["step"]))
-        if len(steps_seen) > 1:
-            raise ServeFault(
-                f"session {sid!r} state torn across steps "
-                f"{sorted(steps_seen)}")
-        step = steps_seen.pop() if steps_seen else 0
+                        f"session {sid!r} state lost (not resident, "
+                        f"no arena spill)")
+                step = 0
+                with slab.mu:
+                    slab.arrays = self.runtime.zero_slot(
+                        db, slab.arrays, slot)
+            else:
+                steps_seen = {int(r["step"]) for r in found}
+                if len(found) != len(layers) or len(steps_seen) > 1:
+                    raise ServeFault(
+                        f"session {sid!r} state torn across steps "
+                        f"{sorted(steps_seen)} ({len(found)} of "
+                        f"{len(layers)} layers)")
+                step = steps_seen.pop()
+                values = {n: r["v"] for n, r in layers.items()}
+                with slab.mu:
+                    slab.arrays = self.runtime.write_slot(
+                        db, slab.arrays, slot, values)
+                obs.REGISTRY.counter("session.slab.revives").inc()
+                obs.REGISTRY.counter("session.state_host_bytes").inc(
+                    sum(v.nbytes for v in values.values()))
+        except BaseException:
+            if lease is None:
+                with slab.mu:
+                    slab.give(slot)
+            raise
+        rec = {"slot": slot, "step": step}
+        leased = self._cache().session_put(sid, db, LEASE, rec, ttl_s,
+                                           nbytes=nbytes)
+        obs.REGISTRY.gauge("session.slab.slots_live").set(slab.live())
         self.table.set_steps(sid, step)
-        return out, step
+        return slot, step, bool(leased)
 
-    def _save_state(self, sid: str, db: str, ttl_s: float,
-                    state: Dict[str, Any], step: int) -> None:
-        for layer, v in state.items():
-            rec = {"step": int(step), "v": v}
-            if self._cache().session_update(sid, db, layer, rec):
-                continue
-            if not self._cache().session_put(sid, db, layer, rec,
-                                             ttl_s):
-                # budget-rejected (the layer alone exceeds the whole
-                # cache budget, so eviction can't make room): the
-                # advanced state must still land somewhere durable —
-                # straight into the arena, same as any spill, so the
-                # next step revives it instead of raising
-                # SessionUnknown over silently-dropped state
-                self.arena.merge_layer(sid, db, layer, int(step),
-                                       _host(v), steps_hint=int(step))
-                obs.REGISTRY.counter("session.budget_spills").inc()
+    def _unseat(self, sid: str, db: str, slot: int, step: int) -> None:
+        """A frame's own slot (no lease: the budget holds none) back to
+        the arena and the free list — the advanced state must still
+        land somewhere durable, so the next frame revives it instead of
+        raising SessionUnknown over silently-dropped state."""
+        slab = self._slab(db)
+        with slab.mu:
+            host = self.runtime.read_slot(db, slab.arrays, slot)
+            slab.give(slot)
+        self._to_arena(sid, db, host, step)
+        obs.REGISTRY.counter("session.budget_spills").inc()
+
+    def _release(self, sid: str) -> int:
+        """Drop the session's leases WITHOUT a spill and free their
+        slots (close, move, handoff: the state went where it had to go,
+        or nowhere)."""
+        row = self.table.get(sid)
+        if row is not None:
+            lease = self._cache().session_get(sid, row["db"], LEASE,
+                                              touch=False)
+            with self._slabs_mu:
+                slab = self._slabs.get(row["db"])
+            if lease is not None and slab is not None:
+                with slab.mu:
+                    slab.give(int(lease["slot"]))
+                obs.REGISTRY.gauge("session.slab.slots_live").set(
+                    slab.live())
+        return self._cache().session_drop(sid)
+
+    def _install_state(self, sid: str, db: str, ttl_s: float) -> None:
+        """A fresh session's init state into a slot (open, adopt)."""
+        seat = self._seat(sid, db, ttl_s)
+        if seat is not None and not seat[2]:
+            # no lease to hold the slot between frames: init state is
+            # what the next frame's seat makes again
+            slab = self._slab(db)
+            with slab.mu:
+                slab.give(seat[0])
 
     def _pack(self, sid: str, db: str) -> Dict[str, Any]:
-        """The session's full host-side state (devcache first, arena
-        fallback per layer) — the op=spill/handoff payload. The
+        """The session's full host-side state (the resident slot first,
+        arena fallback) — the op=spill/handoff payload. The
         last-applied idempotency record rides along so the dedup
         guarantee survives the relocation."""
         layers: Dict[str, Dict[str, Any]] = {}
-        for layer in self.runtime.state_layers(db):
-            rec = self._cache().session_get(sid, db, layer,
-                                            touch=False)
-            if rec is None:
+        lease = self._cache().session_get(sid, db, LEASE, touch=False)
+        if lease is not None \
+                and int(lease["step"]) >= self.arena.steps(sid, db):
+            slab = self._slab(db)
+            with slab.mu:
+                host = self.runtime.read_slot(db, slab.arrays,
+                                              int(lease["slot"]))
+            obs.REGISTRY.counter("session.state_host_bytes").inc(
+                sum(v.nbytes for v in host.values()))
+            layers = {n: {"step": int(lease["step"]), "v": v}
+                      for n, v in host.items()}
+        else:
+            for layer in self.runtime.state_layout(db):
                 rec = self.arena.get_layer(sid, db, layer)
-            if rec is not None:
-                layers[layer] = {"step": int(rec["step"]),
-                                 "v": _host(rec["v"])}
+                if rec is not None:
+                    layers[layer] = {"step": int(rec["step"]),
+                                     "v": _host(rec["v"])}
         out = {"layers": layers,
                "steps": max([self.table.steps(sid),
                              self.arena.steps(sid, db)]
@@ -477,7 +599,8 @@ class SessionManager:
                 return None
             return {"token": last["token"],
                     "steps": int(last["steps"]),
-                    "y": _host(last["y"])}
+                    "out": {k: _host(v)
+                            for k, v in last["out"].items()}}
 
     def _note_applied(self, sid: str,
                       rec: Optional[Dict[str, Any]]) -> None:
@@ -492,7 +615,7 @@ class SessionManager:
                     or int(rec.get("steps", 0)) >= int(cur["steps"]):
                 self._applied[sid] = {"token": rec["token"],
                                       "steps": int(rec.get("steps", 0)),
-                                      "y": rec["y"]}
+                                      "out": dict(rec["out"])}
 
     # --- the batched decode step --------------------------------------
     def _sid_lock(self, sid: str) -> TrackedLock:
@@ -514,71 +637,294 @@ class SessionManager:
 
     def _run_batch_locked(self, db: str,
                           reqs: List[Dict[str, Any]]) -> List[Any]:
-        with obs.span("session.batch", "serve"):
-            results: List[Any] = [None] * len(reqs)
-            live: List[int] = []
-            states, steps, ttls = [], [], []
-            me = self._me()
-            for i, r in enumerate(reqs):
-                sid = r["sid"]
-                row = self.table.get(sid)
-                if row is None:
-                    results[i] = SessionUnknown(
-                        f"unknown session {sid!r}")
-                    continue
-                if row["owner"] != me:
-                    # a handoff/move won the sid lock while this step
-                    # sat in the coalesce queue: bounce ONLY this
-                    # request typed-retryable, keep the rest batched
-                    results[i] = SessionMoved(
-                        f"session {sid!r} moved to {row['owner']}",
-                        owner_addr=row["owner"])
-                    continue
-                tok = r.get("tok")
-                if tok:
-                    with self._applied_mu:
-                        last = self._applied.get(sid)
-                    if last is not None and last["token"] == tok:
-                        # retry of an applied-but-unanswered step whose
-                        # record travelled here with the state (the
-                        # daemon-local idempotency cache can't have
-                        # seen this token): replay the recorded reply,
-                        # never advance the state twice under one token
-                        results[i] = {"y": last["y"],
-                                      "steps": int(last["steps"])}
-                        continue
-                ttl = float(row["ttl_s"])
-                try:
-                    st, step = self._load_state(sid, db, ttl)
-                except ServeFault as e:
-                    results[i] = e
-                    continue
-                live.append(i)
-                states.append(st)
-                steps.append(step)
-                ttls.append(ttl)
-            if live:
-                xs = [np.asarray(reqs[i]["x"], np.float32)
-                      for i in live]
-                with obs.span("session.device", "serve"):
-                    new, outs = self.runtime.step_batch(db, states, xs)
-                for j, i in enumerate(live):
-                    sid = reqs[i]["sid"]
-                    step = steps[j] + 1
-                    self._save_state(sid, db, ttls[j], new[j], step)
-                    self.table.set_steps(sid, step)
-                    results[i] = {"y": outs[j], "steps": step}
-                    tok = reqs[i].get("tok")
-                    if tok:
-                        with self._applied_mu:
-                            self._applied[sid] = {"token": tok,
-                                                  "steps": step,
-                                                  "y": outs[j]}
-                obs.REGISTRY.counter("session.decode_steps").inc(
-                    len(live))
-                obs.REGISTRY.counter("session.batch_occupancy").inc(
-                    len(live))
-            return results
+        """ONE iteration over the live frames of ``db`` (module
+        docstring): admit, one prefill chunk, one decode step, harvest
+        and retire."""
+        t_batch = time.perf_counter()
+        lane = self._lanes.setdefault(
+            db, {"turns": {}, "steps": collections.deque()})
+        results: List[Any] = [CONTINUE] * len(reqs)
+        for i, r in enumerate(reqs):
+            try:
+                if "_turn" not in r:
+                    results[i] = self._admit(db, lane, r)
+                else:
+                    self._reseat(db, r["_turn"])
+            except (ServeFault, SessionMoved, SessionUnknown) as e:
+                results[i] = e
+                self._drop_turn(db, lane, r)
+        turns = [r["_turn"] for i, r in enumerate(reqs)
+                 if results[i] is CONTINUE and "_turn" in r]
+        dispatched = False
+        if turns:
+            slab = self._slab(db)
+            joining = [t for t in turns if t["chunks"]]
+            if joining:
+                self._prefill_chunk(db, slab, min(
+                    joining, key=lambda t: t["admitted"]))
+            ready = [t for t in turns if not t["chunks"] and t["n"] > 0]
+            if ready:
+                self._decode_step(db, slab, lane, ready)
+                dispatched = True
+        # the outputs of every dispatched step but the newest: the
+        # device has that one queued while the host reads these
+        while len(lane["steps"]) > (1 if dispatched else 0):
+            self._harvest(lane["steps"].popleft())
+        for i, r in enumerate(reqs):
+            t = r.get("_turn")
+            if results[i] is CONTINUE and t is not None \
+                    and not t["chunks"] and t["n"] == 0 \
+                    and t["unread"] == 0:
+                results[i] = self._retire(db, lane, r)
+        if turns:
+            obs.record_into(turns[0]["trace"], "session.batch",
+                            time.perf_counter() - t_batch, "serve")
+        return results
+
+    def _admit(self, db: str, lane: Dict[str, Any],
+               r: Dict[str, Any]) -> Any:
+        """Seat one joining frame's session and lay out its turn.
+        Returns CONTINUE (admitted, or no slot yet: it is offered again
+        next iteration), a recorded reply (a retry of an applied
+        frame), or raises the frame's own typed fault."""
+        t0 = time.perf_counter()
+        sid = r["sid"]
+        row = self.table.get(sid)
+        if row is None:
+            raise SessionUnknown(f"unknown session {sid!r}")
+        if row["owner"] != self._me():
+            # a handoff/move won the sid lock while this frame sat in
+            # the queue: bounce ONLY this frame typed-retryable, keep
+            # the rest batched
+            raise SessionMoved(
+                f"session {sid!r} moved to {row['owner']}",
+                owner_addr=row["owner"])
+        tok = r.get("tok")
+        if tok:
+            with self._applied_mu:
+                last = self._applied.get(sid)
+            if last is not None and last["token"] == tok:
+                # retry of an applied-but-unanswered frame whose
+                # record travelled here with the state (the
+                # daemon-local idempotency cache can't have seen this
+                # token): replay the recorded reply, never advance
+                # the state twice under one token
+                return dict(last["out"], steps=int(last["steps"]))
+        ttl = float(row["ttl_s"])
+        seat = self._seat(sid, db, ttl)
+        if seat is None:
+            return CONTINUE
+        slot, step, leased = seat
+        turn = {"sid": sid, "slot": slot, "leased": leased, "ttl": ttl,
+                "step": step, "tok": tok, "trace": r.get("trace"),
+                "admitted": time.perf_counter(), "chunks": [], "n": 1,
+                "x": None, "unread": 0, "outs": [], "logits": None}
+        if self.runtime.takes_x(db):
+            turn["x"] = np.asarray(r["x"], np.float32)
+            turn["advance"] = 1
+        else:
+            tokens = np.asarray(r.get("tokens", ()), np.int32).reshape(-1)
+            turn["n"] = int(r.get("new_tokens", 0))
+            turn["advance"] = len(tokens) + turn["n"]
+            try:
+                turn["chunks"] = self._lay_out(db, step, tokens, turn["n"])
+            except ServeFault:
+                if not leased:
+                    self._unseat(sid, db, slot, step)
+                raise
+        r["_turn"] = turn
+        lane["turns"][sid] = turn
+        # the frame's wait for the decode scheduler: from its handler's
+        # submit to the iteration that seated it (a step boundary, and
+        # a free slot); a ``server.sched.*`` span like the lanes' own
+        now = time.perf_counter()
+        obs.record_into(turn["trace"], "server.sched.session_wait",
+                        max(0.0, t0 - r.get("queued", t0)), "serve",
+                        ended_ago_s=now - t0)
+        obs.record_into(turn["trace"], "session.admit", now - t0, "serve")
+        return CONTINUE
+
+    def _lay_out(self, db: str, step: int, tokens: np.ndarray,
+                 n: int) -> List[Tuple[np.ndarray, int, int]]:
+        """The prefill chunks of a language-model turn: ``[(ids of one
+        chunk length, how many count, the id that becomes the slot's
+        next input or -1)]``. The sequence to consume is the id the
+        last frame left unconsumed (-1 stands for it: the program reads
+        it from the slab) and then ``tokens``; all of it but its last id
+        goes through prefill, the last is the first decode step's
+        input. ``step`` is the session's history length so far."""
+        spec = self.runtime.spec(db)
+        seq = np.concatenate([np.full(1 if step else 0, -1, np.int32),
+                              tokens])
+        if len(seq) == 0:
+            raise ServeFault("a session's first frame must append "
+                             "token ids")
+        if len(tokens) and (tokens.min() < 0
+                            or tokens.max() >= spec["vocab"]):
+            raise ServeFault(f"token ids outside [0, {spec['vocab']})")
+        if step + len(tokens) + n - 1 > spec["cache_tokens"]:
+            raise ServeFault(
+                f"the frame would take the session to "
+                f"{step + len(tokens) + n} tokens; a slot caches "
+                f"{spec['cache_tokens']}")
+        body, last = seq[:-1], int(seq[-1])
+        plan = self.runtime.plan_prefill(db, len(body))
+        if not plan and last >= 0:
+            plan = [(spec["prefill_chunks"][0], 0)]   # only sets tok
+        chunks, at = [], 0
+        for j, (size, count) in enumerate(plan):
+            ids = np.zeros(size, np.int32)
+            ids[:count] = body[at:at + count]
+            at += count
+            chunks.append((ids, count, last if j == len(plan) - 1 else -1))
+        return chunks
+
+    def _reseat(self, db: str, turn: Dict[str, Any]) -> None:
+        """A live turn whose lease was evicted or expired under it (the
+        cache spilled its slot, as it stood, to the arena): take a slot
+        again and go on from exactly there. Refreshes the lease's
+        recencies otherwise."""
+        if not turn["leased"]:
+            return
+        rec = {"slot": turn["slot"], "step": turn["step"]}
+        if self._cache().session_update(turn["sid"], db, LEASE, rec):
+            return
+        seat = self._seat(turn["sid"], db, turn["ttl"])
+        if seat is None:
+            raise ServeFault(f"session {turn['sid']!r} lost its slot "
+                             f"mid-frame and none is free")
+        turn["slot"], _, turn["leased"] = seat
+
+    def _prefill_chunk(self, db: str, slab: SessionSlab,
+                       turn: Dict[str, Any]) -> None:
+        ids, count, next_tok = turn["chunks"].pop(0)
+        t0 = time.perf_counter()
+        with slab.mu:
+            slab.arrays = self.runtime.prefill(
+                db, slab.arrays, turn["slot"], ids, count, next_tok)
+        obs.REGISTRY.counter("session.prefill_tokens").inc(count)
+        # the host's dispatch only: the program runs behind it, and its
+        # device seconds are the device trace's to give
+        obs.record_into(turn["trace"], "session.prefill",
+                        time.perf_counter() - t0, "serve", tokens=count)
+
+    def _decode_step(self, db: str, slab: SessionSlab,
+                     lane: Dict[str, Any],
+                     ready: List[Dict[str, Any]]) -> None:
+        t0 = time.perf_counter()
+        active = np.zeros(slab.slots, bool)
+        xs = None
+        if self.runtime.takes_x(db):
+            xs = np.zeros((slab.slots, self.runtime.spec(db)["hidden"]),
+                          np.float32)
+        for t in ready:
+            active[t["slot"]] = True
+            if xs is not None:
+                xs[t["slot"]] = t["x"]
+        with slab.mu:
+            slab.arrays, outs = self.runtime.step(db, slab.arrays,
+                                                  active, xs)
+        for t in ready:
+            t["n"] -= 1
+            t["unread"] += 1
+        lane["steps"].append({"outs": outs, "turns": list(ready),
+                              "t0": t0, "lane": lane})
+        obs.record_into(ready[0]["trace"], "session.device",
+                        time.perf_counter() - t0, "serve")
+        obs.REGISTRY.counter("session.decode_steps").inc(len(ready))
+        obs.REGISTRY.counter("session.batch_occupancy").inc(len(ready))
+        if xs is None:
+            obs.REGISTRY.counter("session.decode_tokens").inc(len(ready))
+
+    def _harvest(self, step: Dict[str, Any]) -> None:
+        """Read one dispatched step's outputs to the host (this is
+        where the host waits for the device) and hand each turn its
+        row."""
+        outs, turns, lane = step["outs"], step["turns"], step["lane"]
+        key = "y" if "y" in outs else "ids"
+        # the wait is a poll of the array's readiness, not a blocking
+        # copy, and that is for a profiler's sake, not the request's: a
+        # blocking read is one step-long host event a step in a trace of
+        # the runtime's calls, thousands a minute, and the benchmark's
+        # reader of idle gaps walks them all for every gap (a traced
+        # window then took 1,000 s to reduce for 620; chip runs, PR 27).
+        # It costs a step up to half a millisecond of its 22 to 27; a
+        # plain ``np.asarray`` is the right read once that reader is
+        # repaired (PERF.md section 7)
+        while not outs[key].is_ready():
+            time.sleep(0.0005)
+        host = np.asarray(outs[key])
+        for t in turns:
+            t["outs"].append(host[t["slot"]])
+            t["unread"] -= 1
+            if "logits" in outs and t["n"] == 0 and t["unread"] == 0:
+                t["logits"] = outs["logits"]
+        # a step's span runs from when the device was free for it (the
+        # step before it was read, or its own dispatch if later) to its
+        # outputs on the host: consecutive steps tile the timeline, and
+        # a prefill chunk dispatched between two steps falls into the
+        # later one's span
+        now = time.perf_counter()
+        start = max(step["t0"], lane.get("read_at", 0.0))
+        lane["read_at"] = now
+        obs.record_into(turns[0]["trace"], "session.step", now - start,
+                        "serve", rows=len(turns))
+
+    def _drop_turn(self, db: str, lane: Dict[str, Any],
+                   r: Dict[str, Any]) -> None:
+        turn = r.pop("_turn", None)
+        if turn is not None:
+            lane["turns"].pop(turn["sid"], None)
+
+    def _retire(self, db: str, lane: Dict[str, Any],
+                r: Dict[str, Any]) -> Dict[str, Any]:
+        """A turn whose last output is on the host: advance the step
+        tag, write the logits row, record the applied token, answer."""
+        t0 = time.perf_counter()
+        turn = r["_turn"]
+        sid = turn["sid"]
+        step = turn["step"] + turn["advance"]
+        if turn["x"] is not None:
+            out = {"y": turn["outs"][-1]}
+        else:
+            out = {"ids": np.asarray(turn["outs"], np.int32).reshape(-1)}
+            if turn["logits"] is not None:
+                self._write_logits(db, sid, turn["logits"], turn["slot"])
+        if turn["leased"]:
+            rec = {"slot": turn["slot"], "step": step}
+            if not self._cache().session_update(sid, db, LEASE, rec):
+                # evicted between the last step and now: the arena holds
+                # the state under the old tag; tag it forward
+                self._retag(sid, db, step)
+        else:
+            self._unseat(sid, db, turn["slot"], step)
+        self.table.set_steps(sid, step)
+        if turn["tok"]:
+            with self._applied_mu:
+                self._applied[sid] = {"token": turn["tok"], "steps": step,
+                                      "out": out}
+        self._drop_turn(db, lane, r)
+        obs.record_into(turn["trace"], "session.retire",
+                        time.perf_counter() - t0, "serve")
+        return dict(out, steps=step)
+
+    def _retag(self, sid: str, db: str, step: int) -> None:
+        slot = self.arena.snapshot_slot(sid, db)
+        if slot is not None:
+            self.arena.merge_state(
+                sid, db, {n: {"step": step, "v": rec["v"]}
+                          for n, rec in slot["layers"].items()}, step,
+                dirty=True)
+
+    def _write_logits(self, db: str, sid: str, logits, slot: int) -> None:
+        """The frame's last float32 logits row into the session's
+        output set (device to store, no host copy)."""
+        lib = self._ctl.library
+        name = output_set(sid)
+        if not lib.set_exists(db, name):
+            lib.create_set(db, name, type_name="matrix")
+        row = self.runtime.row_of(logits, slot)
+        lib.send_matrix(db, name, row, self.runtime.block_for(db, row.shape))
 
     # --- frame handlers (called from ServeController) ------------------
     def handle_open(self, p: Dict[str, Any]):
@@ -605,11 +951,15 @@ class SessionManager:
         heads = p.get("heads")
         spec = self.runtime.register_model(
             db, kind, client=p.get("client"), heads=heads)
-        nbytes = self.runtime.state_nbytes(db)
-        if nbytes > self.state_cap:
+        nbytes = self.runtime.slot_nbytes(db)
+        # the daemon's own cap binds the kinds whose state it sizes; a
+        # model whose database states its slots and cache is held to
+        # what the device cache can lease instead
+        if nbytes > self.state_cap and not self.runtime.stores_spec(db):
             raise ServeFault(
                 f"session state ({nbytes}B) exceeds "
                 f"session_state_bytes ({self.state_cap}B)")
+        self._slab(db)
         existing = self.table.get(sid)
         if existing is not None:  # idempotent re-open
             return MsgType.OK, {"sid": sid, "owner": existing["owner"],
@@ -626,8 +976,7 @@ class SessionManager:
                 owner = self._me()
         self.table.open(sid, db, kind, owner, ttl_s, home=self._me())
         if owner == self._me():
-            self._install_state(sid, db, ttl_s,
-                                self.runtime.init_state(db), 0)
+            self._install_state(sid, db, ttl_s)
         obs.REGISTRY.counter("session.opened").inc()
         self._ensure_housekeeping(ttl_s)
         return MsgType.OK, {"sid": sid, "owner": owner, "spec": spec,
@@ -648,8 +997,9 @@ class SessionManager:
             # two concurrent opens may both ship — benign: the ingest
             # is idempotent; what must never happen is a weight-LESS
             # adopt at an owner that doesn't hold the model
-            payload["weights"] = self._export_weights(db, kind)
-            payload["block"] = [32, 32]
+            payload["weights"] = self._export_weights(db)
+            if self.runtime.stores_spec(db):
+                payload["spec"] = dict(spec)
         self._ctl.shards.peer_request(owner, MsgType.SESSION_OPEN,
                                       payload, codec=CODEC_PICKLE)
         with self._shipped_mu:
@@ -663,11 +1013,9 @@ class SessionManager:
         with self._shipped_mu:
             self._shipped = {e for e in self._shipped if e[0] != addr}
 
-    def _export_weights(self, db: str, kind: str) -> Dict[str, np.ndarray]:
-        names = (_decode.LSTM_WEIGHTS if kind == "lstm"
-                 else _decode.TRANSFORMER_WEIGHTS)
+    def _export_weights(self, db: str) -> Dict[str, np.ndarray]:
         out = {}
-        for n in names:
+        for n in self.runtime.weight_names(db):
             t = self._ctl.library.get_tensor(db, n)
             out[n] = np.array(t.data[:t.meta.shape[0],
                                      :t.meta.shape[1]])
@@ -679,9 +1027,10 @@ class SessionManager:
         kind = str(p.get("kind", "lstm"))
         ttl_s = float(p.get("ttl_s") or self.ttl_s)
         if p.get("weights"):
-            self._install_model_local(db, kind, p["weights"],
-                                      tuple(p.get("block") or (32, 32)))
+            self.runtime.install_model(db, kind, p["weights"],
+                                       p.get("spec"))
         self.runtime.register_model(db, kind, heads=p.get("heads"))
+        self._slab(db)
         self.table.open(sid, db, kind, self._me(), ttl_s,
                         home=p.get("home"))
         self.table.set_owner(sid, self._me(), home=p.get("home"))
@@ -693,35 +1042,10 @@ class SessionManager:
             self.table.set_steps(sid, int(state.get("steps", steps)))
             self._note_applied(sid, state.get("applied"))
         elif steps == 0:
-            self._install_state(sid, db, ttl_s,
-                                self.runtime.init_state(db), 0)
+            self._install_state(sid, db, ttl_s)
         self._ensure_housekeeping(ttl_s)
         return MsgType.OK, {"sid": sid, "owner": self._me(),
                             "steps": self.table.steps(sid)}
-
-    def _install_model_local(self, db: str, kind: str,
-                             weights: Dict[str, np.ndarray],
-                             block: Tuple[int, int]) -> None:
-        """Ingest shipped dense weights through this daemon's OWN
-        library (create_set + send_matrix), so the worker's
-        register_model walks the same store path — fingerprints, and
-        the dedup pooling wiring, trigger here exactly as at the
-        leader."""
-        lib = self._ctl.library
-        try:
-            lib.create_database(db)
-        except Exception as e:  # noqa: BLE001 — exists
-            del e
-        for name, w in weights.items():
-            w = np.asarray(w, np.float32)
-            if w.ndim == 1:
-                w = w.reshape(-1, 1)
-            shape = (block[0], 1) if w.shape[1] == 1 else tuple(block)
-            try:
-                lib.create_set(db, name, type_name="matrix")
-            except Exception as e:  # noqa: BLE001 — exists
-                del e
-            lib.send_matrix(db, name, w, block_shape=shape)
 
     def _op_spill(self, p):
         sid = str(p["sid"])
@@ -775,7 +1099,7 @@ class SessionManager:
                 # failure, and the next step revives from this copy)
                 self.arena.merge_state(sid, db, state["layers"],
                                        state["steps"])
-                self._cache().session_drop(sid)
+                self._release(sid)
         else:
             rep = self._ctl.shards.peer_request(
                 row["owner"], MsgType.SESSION_OPEN,
@@ -806,7 +1130,7 @@ class SessionManager:
             raise SessionUnknown(f"unknown session {sid!r}")
         with self._sid_lock(sid):
             state = self._pack(sid, row["db"])
-            self._cache().session_drop(sid)
+            self._release(sid)
             self.arena.drop(sid)
             home = row.get("home") or self._me()
             self.table.set_owner(sid, home)
@@ -841,13 +1165,16 @@ class SessionManager:
         # by the dispatcher; local import — server imports this module)
         from netsdb_tpu.serve.server import _idem_token_var
 
+        req = {"sid": sid, "tok": _idem_token_var.get()}
+        for key in ("x", "tokens", "new_tokens"):
+            if key in p:
+                req[key] = p[key]
         with obs.span("session.coalesce", "serve"):
-            out = self.batcher.submit(
-                db, sid, {"sid": sid, "x": p["x"],
-                          "tok": _idem_token_var.get()})
-        return MsgType.OK, {"sid": sid, "y": out["y"],
-                            "steps": out["steps"],
-                            "owner": self._me()}, CODEC_PICKLE
+            req["trace"] = obs.capture()
+            req["queued"] = time.perf_counter()
+            out = self.batcher.submit(db, sid, req)
+        return MsgType.OK, dict(out, sid=sid,
+                                owner=self._me()), CODEC_PICKLE
 
     def handle_close(self, p: Dict[str, Any]):
         sid = str(p.get("sid") or p.get("set"))
@@ -862,9 +1189,12 @@ class SessionManager:
             except Exception as e:  # noqa: BLE001 — the owner's
                 del e  # TTL sweep collects what this forward missed
         with self._sid_lock(sid):
-            dropped = self._cache().session_drop(sid)
+            dropped = self._release(sid)
             self.arena.drop(sid)
             closed = self.table.close(sid)
+            name = output_set(sid)
+            if self._ctl.library.set_exists(row["db"], name):
+                self._ctl.library.remove_set(row["db"], name)
         with self._applied_mu:
             self._applied.pop(sid, None)
         # the per-sid lock is deliberately NOT popped: a thread that

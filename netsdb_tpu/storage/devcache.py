@@ -130,6 +130,57 @@ def _value_nbytes(value) -> int:
     return 64  # scalars / ints riding along with blocks
 
 
+class SessionSlab:
+    """One decode model's session state on the device: a dict of arrays
+    that each carry a SLOT axis (``models/decode.py`` declares the
+    layout), every slot one session's whole state — recurrent state,
+    convolution window, key/value cache, position — side by side.
+
+    A session leases a slot from open to close or eviction (the lease is
+    a session entry of the :class:`DeviceBlockCache`, charged the
+    slot's bytes). A decode step takes ``arrays`` under :attr:`mu`,
+    dispatches a program that DONATES them, and stores what it returns:
+    the state of every slot advances in place and never crosses the
+    host. Spill, revive, move and handoff copy one slot's slices.
+
+    :attr:`mu` guards ``arrays`` and the free list. It nests under the
+    cache's lock (the spill callback reads a slot while the cache drops
+    its lease) and under nothing else; a step holds it for a dispatch,
+    never across a transfer it waits on."""
+
+    def __init__(self, model: str, arrays: Dict[str, Any], slots: int,
+                 slot_nbytes: int):
+        self.model = str(model)
+        self.mu = TrackedLock("SessionSlab.mu")
+        self.arrays = arrays
+        self.slots = int(slots)
+        self.slot_nbytes = int(slot_nbytes)
+        self._free = list(range(self.slots))
+
+    @property
+    def nbytes(self) -> int:
+        return self.slots * self.slot_nbytes
+
+    def take(self) -> Optional[int]:
+        """The lowest free slot, or None (caller holds :attr:`mu`)."""
+        if not self._free:
+            return None
+        self._free.sort()
+        return self._free.pop(0)
+
+    def give(self, slot: int) -> None:
+        """Return ``slot`` (caller holds :attr:`mu`)."""
+        if slot not in self._free:
+            self._free.append(int(slot))
+
+    def live(self) -> int:
+        return self.slots - len(self._free)
+
+    def release_all(self) -> None:
+        with self.mu:
+            self._free = list(range(self.slots))
+
+
 class DeviceBlockCache:
     """LRU cache of placed set blocks under one byte budget.
 
@@ -216,6 +267,11 @@ class DeviceBlockCache:
         # {"deadline": monotonic expiry, "ttl": seconds,
         #  "expired": bool (set by the sweep for counter attribution)}.
         self._session_meta: Dict[Tuple, Dict[str, Any]] = {}
+        # model -> SessionSlab: the device arrays the leases index into.
+        # The slab is allocated whole when its model registers; the
+        # budget is charged by LEASE (a session holding a slot between
+        # steps), so that pressure and TTL free slots, not memory
+        self._slabs: Dict[str, "SessionSlab"] = {}
         self._session_spill_cb: Optional[
             Callable[[str, str, str, Any], None]] = None
         self._stats.update({"session_evictions": 0,
@@ -712,6 +768,8 @@ class DeviceBlockCache:
             self._entries.clear()
             self._by_scope.clear()
             self._session_meta.clear()
+            for sl in self._slabs.values():
+                sl.release_all()
             self._pinned.clear()
             self._pinned_bytes = 0
             self._pin_hw.clear()
@@ -743,14 +801,18 @@ class DeviceBlockCache:
                 self._session_on = True
 
     def session_put(self, sid: str, model: str, layer: str, value: Any,
-                    ttl_s: float, client: Optional[str] = None) -> bool:
+                    ttl_s: float, client: Optional[str] = None,
+                    nbytes: Optional[int] = None) -> bool:
         """Install (or replace) one session state entry. Unlike set
         blocks, session entries install even on a budget-less cache —
         an operator who disabled the block cache still gets sessions,
         just with no eviction pressure. Returns False only when the
-        entry cannot fit under an enabled budget."""
+        entry cannot fit under an enabled budget. ``nbytes`` states
+        the entry's size where the value does not carry it: a slot
+        LEASE (``{"slot", "step"}``) is charged the bytes of the slot
+        it holds in the model's :class:`SessionSlab`."""
         key = (session_scope(sid), str(model), str(layer))
-        nbytes = _value_nbytes(value)
+        nbytes = _value_nbytes(value) if nbytes is None else int(nbytes)
         with self._mu:
             self._session_on = True
             if self.enabled and nbytes > self._budget:
@@ -800,19 +862,23 @@ class DeviceBlockCache:
             return entry[0][0]
 
     def session_update(self, sid: str, model: str, layer: str,
-                       value: Any) -> bool:
+                       value: Any, nbytes: Optional[int] = None) -> bool:
         """Swap one resident entry's value IN PLACE (the decode step's
-        state advance): same key, new blocks, bytes re-accounted, LRU
+        state advance): same key, new value, bytes re-accounted, LRU
         and TTL refreshed. Returns False when the entry is not
         resident — the caller re-installs via :meth:`session_put`
-        (the revive-from-arena path) instead of mutating a ghost."""
+        (the revive-from-arena path) instead of mutating a ghost.
+        ``nbytes`` as for :meth:`session_put`; a value that carries no
+        size of its own keeps the entry's."""
         key = (session_scope(sid), str(model), str(layer))
-        nbytes = _value_nbytes(value)
+        if nbytes is None:
+            nbytes = _value_nbytes(value)
         with self._mu:
             entry = self._entries.get(key)
             meta = self._session_meta.get(key)
             if entry is None or meta is None:
                 return False
+            nbytes = int(nbytes) or entry[1]
             self._bytes += nbytes - entry[1]
             self._entries[key] = ([value], nbytes)
             self._entries.move_to_end(key)
@@ -822,6 +888,42 @@ class DeviceBlockCache:
         obs.REGISTRY.gauge("session.resident_bytes").set(
             self.session_resident_bytes())
         return True
+
+    def session_evict_one(self, model: str, skip=()) -> Optional[str]:
+        """Evict (spilling) the least recently used session entry of
+        ``model`` whose session is not in ``skip`` — how a model whose
+        slab is full makes room for one more session. Returns the
+        evicted session's id, or None when every entry is skipped."""
+        with self._mu:
+            for key in self._entries:
+                if key not in self._session_meta or key[1] != str(model):
+                    continue
+                sid = str(key[0])[len(SESSION_SCOPE_PREFIX):]
+                if sid in skip:
+                    continue
+                self._drop_entry_locked(key)
+                break
+            else:
+                return None
+        obs.REGISTRY.gauge("session.resident_bytes").set(
+            self.session_resident_bytes())
+        return sid
+
+    # --- session slabs: one model's state of every slot, on the device --
+    def slab_install(self, model: str, slab: "SessionSlab"
+                     ) -> "SessionSlab":
+        """Keep ``slab`` as ``model``'s (first install wins)."""
+        with self._mu:
+            self._session_on = True
+            return self._slabs.setdefault(str(model), slab)
+
+    def slab(self, model: str) -> Optional["SessionSlab"]:
+        with self._mu:
+            return self._slabs.get(str(model))
+
+    def slab_drop(self, model: str) -> bool:
+        with self._mu:
+            return self._slabs.pop(str(model), None) is not None
 
     def session_drop(self, sid: str) -> int:
         """Drop EVERY entry of one session with NO spill (the
@@ -878,6 +980,8 @@ class DeviceBlockCache:
             out["entries"] = len(self._entries)
             out["budget_bytes"] = self._budget
             if self._session_on:
+                out["session_slab_bytes"] = sum(
+                    sl.nbytes for sl in self._slabs.values())
                 out["session_entries"] = len(self._session_meta)
                 out["session_bytes"] = sum(
                     self._entries[k][1] for k in self._session_meta

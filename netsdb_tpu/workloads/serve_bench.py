@@ -1814,13 +1814,10 @@ def run_sessions_bench(sessions: int = 8, steps: int = 32,
         rt = decode_mod.DecodeRuntime(leader.library)
         rt.register_model("m", kind)
         for i in range(sessions):
-            st = rt.init_state("m")
+            solo = rt.solo_session("m")
             for s in range(-1, steps):
-                new, ys = rt.step_batch(
-                    "m", [st], [x_row(i, s if s >= 0 else -1)])
-                st = new[0]
-                if s >= 0 and not np.array_equal(
-                        np.asarray(ys[0]), outputs[i][s]):
+                y = solo.step(x_row(i, s if s >= 0 else -1))
+                if s >= 0 and not np.array_equal(y, outputs[i][s]):
                     byte_equal = False
         out["byte_equal"] = byte_equal
         out["one_program"] = out["traces_delta"] == 0

@@ -1,0 +1,416 @@
+"""The hybrid language model (gated-delta-rule layers beside full
+attention) served through sessions, at a small size on the CPU.
+
+What is held here, each against the plain reference
+(``netsdb_tpu/models/reference/hybrid_lm.py``: float32, no cache, no
+batching, the delta rule token by token) or against the program
+itself:
+
+* the chunked delta rule equals the token recurrence, also across a
+  chunk boundary, with ``beta > 1``, and with a masked (padded) tail;
+* sessions (prefill, then decode over several turns, 3 sessions
+  batched) equal the reference's full forward pass, logits compared;
+* a session alone equals the same session in a batch, bit for bit;
+* a slot reused after a close equals a fresh daemon's;
+* spill and revive of BOTH kinds of state (recurrent and cache) equal
+  an uninterrupted run;
+* a retried frame under one idempotency token is applied once;
+* a warm step moves no state across the host and compiles nothing.
+"""
+
+import contextlib
+import os
+import threading
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from netsdb_tpu import obs
+from netsdb_tpu.config import Configuration
+from netsdb_tpu.models import decode as decode_mod
+from netsdb_tpu.models import hybrid_lm
+from netsdb_tpu.models.reference import hybrid_lm as reference
+from netsdb_tpu.ops import delta_rule
+from netsdb_tpu.serve.client import RemoteClient
+from netsdb_tpu.serve.protocol import (CODEC_PICKLE, IDEMPOTENCY_KEY,
+                                       MsgType)
+from netsdb_tpu.serve.server import ServeController
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TYPES = (["linear_attention"] * 3 + ["full_attention"]) * 2   # 2 periods
+VOCAB = 384
+SPEC = hybrid_lm.make_spec(
+    layer_types=TYPES, hidden=128, intermediate=256, vocab=VOCAB, heads=4,
+    head_dim=32, lin_heads=4, lin_dk=16, lin_dv=32, slots=4,
+    cache_tokens=512, prefill_chunks=(64, 128))
+# the reference reads a model's published config keys
+CFG = {"hidden_size": 128, "intermediate_size": 256, "vocab_size": VOCAB,
+       "num_attention_heads": 4, "num_hidden_layers": len(TYPES),
+       "layer_types": TYPES, "linear_num_key_heads": 4,
+       "linear_key_head_dim": 16, "linear_value_head_dim": 32,
+       "linear_conv_kernel_dim": 4, "rms_norm_eps": 1e-6}
+# bfloat16 operands on both sides, but a rounding that falls the other
+# way on one side moves a logit by about a bfloat16 step of the
+# activations; float32 weights (below) agree to 1e-4
+LOGIT_TOL = 0.25
+
+
+def _counter(name):
+    return obs.REGISTRY.counter(name).value
+
+
+@contextlib.contextmanager
+def _daemon(tmp_path, name="d0", **cfg_kw):
+    ctl = ServeController(
+        Configuration(root_dir=str(tmp_path / name), **cfg_kw), port=0)
+    ctl.start()
+    try:
+        yield ctl
+    finally:
+        ctl.shutdown()
+
+
+def _deploy(ctl, spec=SPEC, seed=5, db="lm"):
+    # through the daemon's own library, as a deployment fills it: the
+    # wire's array codec carries no bfloat16
+    hybrid_lm.deploy(ctl.library, db, spec,
+                     hybrid_lm.random_weights(spec, seed))
+    return RemoteClient(ctl.advertise_addr)
+
+
+def _weights_of(ctl, db="lm"):
+    def weights(name, shape, rows=None):
+        w = np.asarray(ctl.library.get_tensor(db, name).to_dense(),
+                       np.float32).reshape(shape)
+        return w if rows is None else w[np.asarray(rows)]
+    return weights
+
+
+def _prompt(rng, n):
+    return rng.integers(0, VOCAB, n).astype(np.int32)
+
+
+# --- the delta rule's three forms --------------------------------------
+
+def _rule_inputs(rng, length, beta_hi):
+    h, dk, dv = 3, 16, 24
+    q = rng.standard_normal((length, h, dk)).astype(np.float32)
+    k = rng.standard_normal((length, h, dk)).astype(np.float32)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * 4
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((length, h, dv)).astype(np.float32)
+    log_alpha = (-np.exp(rng.uniform(-3, 1, (length, h))) * 0.3
+                 ).astype(np.float32)
+    beta = rng.uniform(0, beta_hi, (length, h)).astype(np.float32)
+    s0 = rng.standard_normal((h, dk, dv)).astype(np.float32)
+    return s0, q, k, v, log_alpha, beta
+
+
+@pytest.mark.parametrize("length,beta_hi", [(64, 1.0), (192, 1.0),
+                                            (128, 2.0)])
+def test_chunked_delta_rule_equals_the_recurrence(length, beta_hi):
+    args = _rule_inputs(np.random.default_rng(length), length, beta_hi)
+    s_r, o_r = delta_rule.gated_delta_recurrent(*args)
+    s_c, o_c = delta_rule.gated_delta_chunked(*args, chunk=64)
+    np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_r), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_r), atol=2e-5)
+
+
+def test_a_masked_tail_leaves_the_state_alone():
+    s0, q, k, v, la, beta = _rule_inputs(np.random.default_rng(1), 128, 2.0)
+    valid = 75          # crosses the chunk boundary at 64
+    la[valid:], beta[valid:] = 0.0, 0.0
+    s_c, o_c = delta_rule.gated_delta_chunked(s0, q, k, v, la, beta, 64)
+    s_r, o_r = delta_rule.gated_delta_recurrent(
+        s0, q[:valid], k[:valid], v[:valid], la[:valid], beta[:valid])
+    np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_r), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(o_c)[:valid], np.asarray(o_r),
+                               atol=2e-5)
+
+
+def test_one_step_equals_one_token_of_the_recurrence():
+    s0, q, k, v, la, beta = _rule_inputs(np.random.default_rng(2), 4, 2.0)
+    s, outs = jnp.asarray(s0)[None], []
+    for t in range(4):
+        s, o = delta_rule.gated_delta_step(
+            s, q[t][None], k[t][None], v[t][None], la[t][None],
+            beta[t][None])
+        outs.append(np.asarray(o[0]))
+    s_r, o_r = delta_rule.gated_delta_recurrent(s0, q, k, v, la, beta)
+    np.testing.assert_allclose(np.asarray(s[0]), np.asarray(s_r), atol=1e-6)
+    np.testing.assert_allclose(np.stack(outs), np.asarray(o_r), atol=1e-6)
+
+
+def test_the_two_reference_files_are_one():
+    with open(os.path.join(ROOT, "netsdb_tpu", "models", "reference",
+                           "hybrid_lm.py")) as a, \
+            open(os.path.join(ROOT, "benchmark", "configs",
+                              "olmo-hybrid-7b-16l_reference.py")) as b:
+        assert a.read() == b.read()
+
+
+# --- sessions against the reference ------------------------------------
+
+def _turns(handles, plans, rng):
+    """Run the planned turns of every session, the sessions of one
+    round concurrently; returns each session's history and, per turn,
+    (ids, logits of the last step)."""
+    hist = {i: [] for i in handles}
+    got = {i: [] for i in handles}
+    for round_ in range(max(len(p) for p in plans.values())):
+        errors = []
+
+        def drive(i):
+            try:
+                if round_ >= len(plans[i]):
+                    return
+                n_prompt, n_new = plans[i][round_]
+                prompt = _prompt(np.random.default_rng(
+                    1000 * i + round_), n_prompt)
+                ids = handles[i].generate(tokens=prompt, new_tokens=n_new,
+                                          deadline_s=120.0)
+                hist[i] += list(prompt) + list(ids)
+                got[i].append((list(ids), handles[i].last_logits()))
+            except Exception as e:  # noqa: BLE001 — surfaced below
+                errors.append((i, e))
+
+        ts = [threading.Thread(target=drive, args=(i,)) for i in handles]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=300)
+        assert errors == []
+    return hist, got
+
+
+@pytest.mark.parametrize("dtype,tol", [("bfloat16", LOGIT_TOL),
+                                       ("float32", 2e-4)])
+def test_sessions_equal_the_reference_forward(tmp_path, dtype, tol,
+                                              monkeypatch):
+    """3 sessions batched, prefill then decode over several turns
+    (prompts of 150 and 70 tokens cross the 64- and 128-token chunks;
+    a later turn appends nothing and only generates): the logits of
+    every turn's last step, and the ids it chose, against the
+    reference's full forward over the whole history."""
+    spec = dict(SPEC, dtype=dtype)
+    if dtype == "float32":
+        # the reference rounds operands to the deployment's bfloat16;
+        # with float32 weights the same equations must agree closely
+        monkeypatch.setattr(reference, "to_bfloat16",
+                            lambda a: np.asarray(a, np.float32))
+    with _daemon(tmp_path) as ctl:
+        c = _deploy(ctl, spec)
+        clients = [RemoteClient(ctl.advertise_addr) for _ in range(3)]
+        handles = {i: clients[i].open_session("lm", kind="hybrid_lm")
+                   for i in range(3)}
+        plans = {0: [(150, 5), (64, 4), (0, 2)], 1: [(70, 3), (129, 2)],
+                 2: [(200, 4)]}
+        host0 = _counter("session.state_host_bytes")
+        hist, got = _turns(handles, plans, np.random.default_rng(0))
+        assert _counter("session.state_host_bytes") == host0
+        assert ctl.sessions.arena.stats()["reads"] == 0
+        weights = _weights_of(ctl)
+        for i, turns in got.items():
+            ids, logits = turns[-1]
+            ref = reference.forward(CFG, weights,
+                                    [np.asarray(hist[i][:-1])],
+                                    [len(ids)])[0]
+            assert int(np.argmax(logits)) == ids[-1]
+            assert np.abs(logits - ref[-1]).max() <= tol
+            chosen = ref[np.arange(len(ids)), np.asarray(ids)]
+            assert (ref.max(-1) - chosen).max() <= 2 * tol
+            assert handles[i].steps == len(hist[i])
+        for h in handles.values():
+            h.close()
+        for cc in clients + [c]:
+            cc.close()
+
+
+def _one_session(ctl, plan, seed=7, sid=None):
+    c = RemoteClient(ctl.advertise_addr)
+    h = c.open_session("lm", kind="hybrid_lm", session_id=sid)
+    out = []
+    for n_prompt, n_new in plan:
+        prompt = _prompt(np.random.default_rng(seed + n_prompt), n_prompt)
+        ids = h.generate(tokens=prompt, new_tokens=n_new, deadline_s=120.0)
+        out.append((ids.tobytes(), h.last_logits().tobytes()))
+    return c, h, out
+
+
+PLAN = [(100, 4), (64, 3)]
+
+
+def test_alone_equals_batched_bit_for_bit(tmp_path):
+    with _daemon(tmp_path) as ctl:
+        _deploy(ctl).close()
+        c0, h0, alone = _one_session(ctl, PLAN)
+        h0.close()
+        c0.close()
+        # the same session again, now beside two others in the batch
+        clients = [RemoteClient(ctl.advertise_addr) for _ in range(2)]
+        others = {i: clients[i].open_session("lm", kind="hybrid_lm")
+                  for i in range(2)}
+        result = {}
+        t = threading.Thread(target=lambda: result.update(
+            run=_one_session(ctl, PLAN)))
+        t.start()
+        _turns(others, {0: [(150, 6), (70, 6)], 1: [(90, 8)]}, None)
+        t.join(timeout=300)
+        c1, h1, batched = result["run"]
+        assert batched == alone
+        assert ctl.sessions.batcher.snapshot()["max_occupancy"] >= 2
+        for h in list(others.values()) + [h1]:
+            h.close()
+        for cc in clients + [c1]:
+            cc.close()
+
+
+def test_a_reused_slot_equals_a_fresh_daemons(tmp_path):
+    with _daemon(tmp_path, "fresh") as ctl:
+        _deploy(ctl).close()
+        c, h, fresh = _one_session(ctl, PLAN)
+        h.close()
+        c.close()
+    with _daemon(tmp_path, "reused") as ctl:
+        _deploy(ctl).close()
+        c, h, _ = _one_session(ctl, [(180, 9), (100, 5)], seed=99)
+        slot = ctl.library.store.device_cache().session_get(
+            h.sid, "lm", "slot", touch=False)["slot"]
+        h.close()
+        c, h, reused = _one_session(ctl, PLAN)
+        assert ctl.library.store.device_cache().session_get(
+            h.sid, "lm", "slot", touch=False)["slot"] == slot
+        assert reused == fresh
+        h.close()
+        c.close()
+
+
+def test_spill_and_revive_of_both_kinds_of_state(tmp_path):
+    """Between two turns the lease expires: the slot's recurrent state,
+    convolution window and key/value cache all go to the arena and come
+    back; the next turn equals an uninterrupted run's."""
+    with _daemon(tmp_path, "steady") as ctl:
+        _deploy(ctl).close()
+        c, h, steady = _one_session(ctl, PLAN)
+        h.close()
+        c.close()
+    with _daemon(tmp_path, "spilled") as ctl:
+        _deploy(ctl).close()
+        c = RemoteClient(ctl.advertise_addr)
+        h = c.open_session("lm", kind="hybrid_lm")
+        spills0 = _counter("session.slab.spills")
+        revives0 = _counter("session.slab.revives")
+        out = []
+        for n_prompt, n_new in PLAN:
+            prompt = _prompt(np.random.default_rng(7 + n_prompt), n_prompt)
+            ids = h.generate(tokens=prompt, new_tokens=n_new)
+            out.append((ids.tobytes(), h.last_logits().tobytes()))
+            ctl.library.store.device_cache().session_sweep(now=1e18)
+            layers = ctl.sessions.arena.snapshot_slot(h.sid, "lm")["layers"]
+            assert {"S", "conv", "k0", "v1", "pos", "tok"} <= set(layers)
+            assert np.abs(layers["S"]["v"]).max() > 0
+            assert np.abs(np.asarray(layers["k0"]["v"],
+                                     np.float32)).max() > 0
+        assert out == steady
+        assert _counter("session.slab.spills") == spills0 + 2
+        assert _counter("session.slab.revives") == revives0 + 1
+        assert ctl.sessions.arena.stats()["reads"] > 0
+        h.close()
+        c.close()
+
+
+def test_a_retried_frame_is_applied_once(tmp_path):
+    with _daemon(tmp_path) as ctl:
+        c = _deploy(ctl)
+        h = c.open_session("lm", kind="hybrid_lm")
+        frame = {"db": "lm", "set": h.sid, "sid": h.sid,
+                 "tokens": _prompt(np.random.default_rng(3), 90),
+                 "new_tokens": 4, IDEMPOTENCY_KEY: "turn-1-token"}
+        rep1 = c._request(MsgType.GENERATE, dict(frame), codec=CODEC_PICKLE)
+        assert rep1["steps"] == 94
+        # the daemon's own token cache forgets; the record that travels
+        # with the session does not
+        ctl._idem = type(ctl._idem)()
+        rep2 = c._request(MsgType.GENERATE, dict(frame), codec=CODEC_PICKLE)
+        assert rep2["steps"] == 94
+        assert np.asarray(rep2["ids"]).tobytes() \
+            == np.asarray(rep1["ids"]).tobytes()
+        assert ctl.sessions.table.steps(h.sid) == 94
+        rep3 = c._request(MsgType.GENERATE,
+                          dict(frame, tokens=np.zeros(0, np.int32),
+                               **{IDEMPOTENCY_KEY: "turn-2-token"}),
+                          codec=CODEC_PICKLE)
+        assert rep3["steps"] == 98
+        h.close()
+        c.close()
+
+
+def test_warm_turns_compile_nothing_and_one_copy_of_the_weights(tmp_path):
+    with _daemon(tmp_path) as ctl:
+        c = _deploy(ctl)
+        h = c.open_session("lm", kind="hybrid_lm")
+        rng = np.random.default_rng(11)
+        h.generate(tokens=_prompt(rng, 150), new_tokens=3)   # 128 + 64
+        misses0 = c.collect_stats()["metrics"]["compile"]["misses"]
+        traces0 = decode_mod.decode_stats()["traces"]
+        h.generate(tokens=_prompt(rng, 130), new_tokens=5)
+        h.generate(tokens=_prompt(rng, 60), new_tokens=2)
+        assert c.collect_stats()["metrics"]["compile"]["misses"] == misses0
+        assert decode_mod.decode_stats()["traces"] == traces0
+        # the registered parameters ARE the stored arrays
+        reg = ctl.sessions.runtime._reg("lm")
+        for name in ("embed", "l00.w_in", "l03.w_qkv", "l00.conv"):
+            assert reg["params"][name] is ctl.library.get_tensor(
+                "lm", name).data
+        with pytest.raises(Exception, match="caches"):
+            h.generate(tokens=_prompt(rng, 200), new_tokens=40)
+        h.close()
+        c.close()
+
+
+@pytest.mark.parametrize("options,refused", [
+    ({"xla_cpu_enable_fast_min_max": True}, None),
+    ({"no_such_xla_option": "1"}, "no_such_xla_option")])
+def test_a_specs_xla_options_reach_the_compiler(tmp_path, options,
+                                                refused):
+    """A model is compiled with XLA's defaults unless its spec, the
+    database's record, names options: then the step and prefill
+    programs are compiled with them (an unknown one is refused by
+    name, which is the proof that the compiler was handed it)."""
+    assert "xla_options" not in SPEC
+    spec = dict(SPEC, xla_options=options)
+    with _daemon(tmp_path) as ctl:
+        _deploy(ctl, spec).close()
+        rt = ctl.sessions.runtime
+        assert rt.register_model("lm", "hybrid_lm")["xla_options"] == options
+        slab = rt.new_slab("lm")
+        ids = np.zeros(64, np.int32)
+        if refused:
+            with pytest.raises(Exception, match=refused):
+                rt.prefill("lm", slab, 0, ids, 3, 5)
+        else:
+            slab = rt.prefill("lm", slab, 0, ids, 3, 5)
+            slab, outs = rt.step("lm", slab, np.arange(4) == 0)
+            assert outs["ids"].shape == (4,)
+
+
+def test_shipped_weights_and_spec_install_as_the_deployment_did(tmp_path):
+    """What a daemon ships to a worker that adopts a session (dense
+    weights and the spec) installs through the worker's own library to
+    the same registered model: same spec, same stored blocks, no copy."""
+    with _daemon(tmp_path) as ctl:
+        _deploy(ctl).close()
+        rt = ctl.sessions.runtime
+        spec = rt.register_model("lm", "hybrid_lm")
+        assert rt.stores_spec("lm")
+        shipped = ctl.sessions._export_weights("lm")
+        rt.install_model("lm2", "hybrid_lm", shipped, spec)
+        assert rt.register_model("lm2", "hybrid_lm") == spec
+        for name in ("embed", "l00.w_in", "l03.w_qkv", "l00.conv"):
+            a = ctl.library.get_tensor("lm", name)
+            b = ctl.library.get_tensor("lm2", name)
+            assert a.meta.block_shape == b.meta.block_shape
+            assert rt._reg("lm2")["params"][name] is b.data
+            np.testing.assert_array_equal(np.asarray(a.data, np.float32),
+                                          np.asarray(b.data, np.float32))

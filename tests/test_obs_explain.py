@@ -57,6 +57,12 @@ def _shape(tree):
 
 # ------------------------------------------------------- local client
 def test_local_explain_returns_tree_with_per_node_counters(tmp_path):
+    # cold by construction: the compiled-program cache is the process's,
+    # and under ``-n 6 --dist loadfile`` whichever file ran on this
+    # worker before may have compiled this very fold (the same key),
+    # leaving the "cold" run here without a trace to count
+    from netsdb_tpu.plan import executor
+    executor.clear_compiled_cache()
     c = _paged_client(tmp_path)
     results, tree = c.execute_computations(rdag.q06_sink("d"),
                                            job_name="q06", explain=True)

@@ -79,12 +79,8 @@ def _solo_outputs(library, db, kind, xs):
     correctness gate for coalescing."""
     rt = decode_mod.DecodeRuntime(library)
     rt.register_model(db, kind)
-    st = rt.init_state(db)
-    outs = []
-    for x in xs:
-        new, ys = rt.step_batch(db, [st], [np.asarray(x, np.float32)])
-        st = new[0]
-        outs.append(np.asarray(ys[0]))
+    solo = rt.solo_session(db)
+    outs = [solo.step(x) for x in xs]
     return outs
 
 
@@ -350,6 +346,21 @@ def test_get_trace_decomposes_decode_spans(tmp_path):
         names = {s["name"] for p in server for s in p["spans"]}
         assert {"session.coalesce", "session.batch",
                 "session.device"} <= names, names
+        # the frame's wait for the decode scheduler is a scheduler
+        # span like a lane's, a child of the coalesce span it lies in
+        for p in server:
+            by_name = {s["name"]: s for s in p["spans"]}
+            if "server.sched.session_wait" not in by_name:
+                continue
+            wait, co = (by_name["server.sched.session_wait"],
+                        by_name["session.coalesce"])
+            assert wait["parent"] == co["id"]
+            assert co["start_s"] <= wait["start_s"] + 1e-6
+            assert wait["start_s"] + wait["duration_s"] \
+                <= by_name["session.admit"]["start_s"] + 1e-3
+            break
+        else:
+            raise AssertionError(names)
         h.close()
         c.close()
 
@@ -690,4 +701,88 @@ def test_live_session_move_zero_failed_requests(tmp_path):
         for g, w in zip(got, want):
             assert g.tobytes() == w.tobytes()
         h.close()
+        c.close()
+
+
+# --- every kind on the one state path (PR 27) --------------------------
+
+@pytest.mark.parametrize("kind", ["lstm", "transformer_layer"])
+def test_every_kind_steps_on_the_slab_and_moves_no_state(tmp_path, kind):
+    """Both toy kinds declare their state as a layout and step on the
+    model's slab: 3 sessions batched equal their solo twins byte for
+    byte (the transformer layer's ring cache wraps: 70 steps over 64
+    entries), a warm step copies no state across the host, and a
+    closed session's slot is the next session's."""
+    decode_mod.clear_decode_programs()
+    with _daemon(tmp_path) as ctl:
+        c = RemoteClient(ctl.advertise_addr)
+        deploy_decode_model(c, "m1", kind=kind, hidden=HID, seed=31)
+        clients = [RemoteClient(ctl.advertise_addr) for _ in range(3)]
+        handles = [cc.open_session("m1", kind=kind) for cc in clients]
+        n_steps = 70 if kind == "transformer_layer" else 6
+        host0 = _counter("session.state_host_bytes")
+        outs = {i: [] for i in range(3)}
+        errors = []
+
+        def drive(i):
+            try:
+                for s in range(n_steps):
+                    outs[i].append(np.asarray(
+                        handles[i].generate(_x(i, s))))
+            except Exception as e:  # noqa: BLE001 — surfaced below
+                errors.append((i, e))
+
+        ts = [threading.Thread(target=drive, args=(i,)) for i in range(3)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert errors == []
+        assert _counter("session.state_host_bytes") == host0
+        assert decode_mod.decode_stats()["traces"] == 1
+        cache = ctl.library.store.device_cache()
+        slots = sorted(cache.session_get(h.sid, "m1", "slot",
+                                         touch=False)["slot"]
+                       for h in handles)
+        assert slots == [0, 1, 2]
+        for i in range(3):
+            want = _solo_outputs(ctl.library, "m1", kind,
+                                 [_x(i, s) for s in range(n_steps)])
+            for g, w in zip(outs[i], want):
+                assert g.tobytes() == w.tobytes()
+        handles[1].close()
+        again = clients[1].open_session("m1", kind=kind)
+        assert cache.session_get(again.sid, "m1", "slot",
+                                 touch=False)["slot"] == 1
+        assert cache.slab("m1").live() == 3
+        for h in (handles[0], handles[2], again):
+            h.close()
+        assert cache.slab("m1").live() == 0
+        for cc in clients + [c]:
+            cc.close()
+
+
+def test_more_sessions_than_slots_spill_and_revive(tmp_path):
+    """Nine sessions on a slab of eight slots: the least recently used
+    lease is spilled to make room, and comes back when its session
+    steps again — every stream still equals its solo twin."""
+    with _daemon(tmp_path) as ctl:
+        c = RemoteClient(ctl.advertise_addr)
+        deploy_decode_model(c, "m1", kind="lstm", hidden=HID, seed=33)
+        handles = [c.open_session("m1", kind="lstm") for _ in range(9)]
+        assert ctl.library.store.device_cache().slab("m1").slots == 8
+        spills0 = _counter("session.slab.spills")
+        outs = {i: [] for i in range(9)}
+        for s in range(3):
+            for i, h in enumerate(handles):
+                outs[i].append(np.asarray(h.generate(_x(i, s))))
+        assert _counter("session.slab.spills") > spills0
+        assert _counter("session.slab.revives") > 0
+        for i in range(9):
+            want = _solo_outputs(ctl.library, "m1", "lstm",
+                                 [_x(i, s) for s in range(3)])
+            for g, w in zip(outs[i], want):
+                assert g.tobytes() == w.tobytes()
+        for h in handles:
+            h.close()
         c.close()
