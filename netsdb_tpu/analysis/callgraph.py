@@ -107,17 +107,22 @@ def local_aliases(fn: ast.AST) -> Dict[str, ast.AST]:
 
 def own_nodes(fn: ast.AST) -> Iterable[ast.AST]:
     """``fn``'s nodes EXCLUDING nested def/class subtrees — nested
-    functions are project functions of their own."""
-    stack = [fn]
-    while stack:
-        node = stack.pop()
-        if node is not fn and isinstance(
-                node, (ast.FunctionDef, ast.AsyncFunctionDef,
-                       ast.ClassDef)):
-            yield node  # the def node itself (for parent→nested edges)
-            continue
-        yield node
-        stack.extend(ast.iter_child_nodes(node))
+    functions are project functions of their own. Walked once a parsed
+    tree and kept on ``fn``: the edge and thread-root passes both ask,
+    and so does a warm re-run (the parse cache keeps the trees)."""
+    nodes = getattr(fn, "_own_nodes", None)
+    if nodes is None:
+        nodes = []
+        stack = [fn]
+        while stack:
+            node = stack.pop()
+            nodes.append(node)  # a nested def itself: parent→nested edge
+            if node is fn or not isinstance(
+                    node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                           ast.ClassDef)):
+                stack.extend(ast.iter_child_nodes(node))
+        fn._own_nodes = nodes
+    return nodes
 
 
 class CallGraph:

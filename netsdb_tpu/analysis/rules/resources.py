@@ -81,16 +81,23 @@ class IterCloseRule(Rule):
     @staticmethod
     def _own_nodes(fn: ast.AST) -> Iterable[ast.AST]:
         """The function's nodes EXCLUDING nested def subtrees (those
-        are visited as their own functions — own close scope)."""
-        stack = [fn]
-        while stack:
-            node = stack.pop()
-            if node is not fn and isinstance(
-                    node, (ast.FunctionDef, ast.AsyncFunctionDef,
-                           ast.ClassDef)):
-                continue
-            yield node
-            stack.extend(ast.iter_child_nodes(node))
+        are visited as their own functions — own close scope). Walked
+        once a parsed tree and kept on ``fn``: both passes of
+        ``_check_fn`` ask, and so does a warm re-run."""
+        nodes = getattr(fn, "_own_body_nodes", None)
+        if nodes is None:
+            nodes = []
+            stack = [fn]
+            while stack:
+                node = stack.pop()
+                if node is not fn and isinstance(
+                        node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                               ast.ClassDef)):
+                    continue
+                nodes.append(node)
+                stack.extend(ast.iter_child_nodes(node))
+            fn._own_body_nodes = nodes
+        return nodes
 
     def _check_fn(self, mod: Module, fn: ast.AST) -> Iterable[Diagnostic]:
         owned: Set[int] = set()  # id() of producer Call nodes accounted

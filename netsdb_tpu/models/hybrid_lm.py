@@ -27,19 +27,22 @@ The block (reordered norm, no biases)::
   RMSNorm_head(o) * silu(W_g x)``; ``W_o``.
 
 State of one session, all of it in the model's slab (a slot axis in
-every array, ``storage/devcache.SessionSlab``): ``S[lin_layer, slot,
-dk, H dv]`` float32, ``conv[lin_layer, conv_k - 1, slot, H (2 dk +
-dv)]``, for each full layer ``i`` a key cache ``k{i}[slot, heads,
-cache_tokens + margin, head_dim]`` and a value cache ``v{i}`` alike,
+every array, ``storage/devcache.SessionSlab``): for each linear layer
+``i`` a recurrent state ``S{i}[slot, dk, H dv]`` float32,
+``conv[lin_layer, conv_k - 1, slot, H (2 dk + dv)]``, for each full
+layer ``i`` a key cache ``k{i}[slot, heads, cache_tokens + margin,
+head_dim]`` and a value cache ``v{i}`` alike,
 ``pos[slot]`` (tokens consumed) and ``tok[slot]`` (the next input
 token: the last id appended or generated, not consumed yet). The axes
 are ordered for the chip's (8, 128) tiles: a state's heads lie along
 the lanes (``dv`` = 192 alone would pad to 256), a cache's two minor
 axes are tokens and ``head_dim``, and the three rows of a convolution
 window are not a minor axis: no array is padded. Each full layer's
-caches are arrays of their own, so that a step's attention takes them
-whole as its products' operands: cut out of one array with a layer
-axis they were copied, a gigabyte a layer a step.
+caches and each linear layer's states are arrays of their own: a
+step's attention takes the caches whole as its products' operands, and
+its delta-rule kernel writes the donated states in place. Cut out of
+one array with a layer axis they were copied, a gigabyte a layer a
+step.
 
 Two programs over that slab, both donating it:
 
@@ -62,10 +65,11 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
+from netsdb_tpu import obs
 from netsdb_tpu.ops.attention import cache_write_rows, cached_attention
 from netsdb_tpu.ops.delta_rule import (gated_delta_chunked,
                                        gated_delta_step_flat, heads_first,
-                                       heads_on_lanes)
+                                       heads_on_lanes, step_kernel_fits)
 
 KIND = "hybrid_lm"
 SPEC_SET = "spec"
@@ -172,9 +176,10 @@ def state_layout(spec) -> Dict[str, Dict[str, Any]]:
     n_full = sum(t == FULL for t in spec["layer_types"])
     s = spec["slots"]
     return {
-        "S": {"shape": (n_lin, s, spec["lin_dk"],
-                        spec["lin_heads"] * spec["lin_dv"]),
-              "dtype": "float32", "slot_axis": 1, "reset": True},
+        **{f"S{i}": {"shape": (s, spec["lin_dk"],
+                               spec["lin_heads"] * spec["lin_dv"]),
+                     "dtype": "float32", "slot_axis": 0, "reset": True}
+           for i in range(n_lin)},
         "conv": {"shape": (n_lin, spec["conv_k"] - 1, s, _conv_width(spec)),
                  "dtype": spec["dtype"], "slot_axis": 2, "reset": True},
         **{f"{kv}{i}": {"shape": (s, spec["heads"], cache_rows(spec),
@@ -300,10 +305,15 @@ def build_step(spec):
     types = spec["layer_types"]
     heads, hd = spec["heads"], spec["head_dim"]
     keep = spec["conv_k"] - 1
+    fits = step_kernel_fits(spec["lin_dk"],
+                            spec["lin_heads"] * spec["lin_dv"])
 
     def hybrid_lm_step(p, slab, active):
+        # runs when the program is traced, once a compiled program
+        obs.REGISTRY.gauge("decode.gdn_step.fused_layers").set(
+            sum(t == LINEAR for t in types) if fits else 0)
         slab = dict(slab)
-        S, conv = slab["S"], slab["conv"]
+        conv = slab["conv"]
         pos, tok = slab["pos"], slab["tok"]
         cdt = conv.dtype
         x = p["embed"][jnp.clip(tok, 0)].astype(jnp.float32)
@@ -319,10 +329,11 @@ def build_step(spec):
                 c = jax.nn.silu(sum(taps[j] * win32[keep - j]
                                     for j in range(keep + 1)))
                 q, k, v = _split_qkv(spec, c)
-                S_new, o = gated_delta_step_flat(S[li], q, k, v, log_alpha,
-                                                 beta)
-                S = S.at[li].set(jnp.where(
-                    active[:, None, None], S_new, S[li]))
+                # an idle slot's token leaves the state as it is
+                log_alpha = jnp.where(active[:, None], log_alpha, 0.0)
+                beta = jnp.where(active[:, None], beta, 0.0)
+                slab[f"S{li}"], o = gated_delta_step_flat(
+                    slab[f"S{li}"], q, k, v, log_alpha, beta)
                 conv = conv.at[li].set(jnp.where(
                     active[None, :, None], window[1:], conv[li]))
                 mix = _lin_out(spec, p, pre, o, gate)
@@ -345,7 +356,7 @@ def build_step(spec):
             x = _ffn(p, pre, h, eps)
         logits = _dense(_rms(x, p["final_norm"], eps), p["lm_head"])
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        slab.update(S=S, conv=conv, pos=jnp.where(active, pos + 1, pos),
+        slab.update(conv=conv, pos=jnp.where(active, pos + 1, pos),
                     tok=jnp.where(active, ids, tok))
         return slab, ids, logits
 
@@ -368,7 +379,7 @@ def build_prefill(spec, chunk: int):
 
     def hybrid_lm_prefill(p, slab, slot, tokens, n_valid, next_tok):
         slab = dict(slab)
-        S, conv = slab["S"], slab["conv"]
+        conv = slab["conv"]
         pos, tok = slab["pos"], slab["tok"]
         cdt = conv.dtype
         pos0 = pos[slot]
@@ -393,10 +404,11 @@ def build_prefill(spec, chunk: int):
                 # a padded token leaves the state as it is
                 log_alpha = jnp.where(valid[:, None], log_alpha, 0.0)
                 beta = jnp.where(valid[:, None], beta, 0.0)
+                S = slab[f"S{li}"]
                 S_new, o = gated_delta_chunked(
-                    heads_first(S[li, slot], spec["lin_heads"]), q, k, v,
+                    heads_first(S[slot], spec["lin_heads"]), q, k, v,
                     log_alpha, beta, spec["delta_chunk"])
-                S = S.at[li, slot].set(heads_on_lanes(S_new))
+                slab[f"S{li}"] = S.at[slot].set(heads_on_lanes(S_new))
                 conv = conv.at[li, :, slot].set(
                     lax.dynamic_slice_in_dim(seq, n_valid, keep, axis=0))
                 mix = _lin_out(spec, p, pre, o, gate)
@@ -422,7 +434,7 @@ def build_prefill(spec, chunk: int):
             if i == last:
                 break     # the last layer's FFN feeds only the head
             x = _ffn(p, pre, h, eps)
-        slab.update(S=S, conv=conv, pos=pos.at[slot].add(n_valid),
+        slab.update(conv=conv, pos=pos.at[slot].add(n_valid),
                     tok=tok.at[slot].set(
                         jnp.where(next_tok >= 0, next_tok, tok[slot])))
         return slab

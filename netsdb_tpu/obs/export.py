@@ -349,6 +349,13 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("session.slab.slots_live", "slots of the last touched "
                                     "model's session slab that hold a "
                                     "session"),
+        ("decode.gdn_step.fused_layers", "linear-attention layers of "
+                                         "the last traced hybrid_lm "
+                                         "step program whose one-token "
+                                         "delta-rule update is the "
+                                         "in-place kernel (0: the "
+                                         "state's shape took the XLA "
+                                         "form)"),
         ("dedup.page_bytes", "unique model weight-page bytes resident "
                              "after cross-model deduplication "
                              "(compare against the per-model "

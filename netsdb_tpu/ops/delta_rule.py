@@ -11,7 +11,13 @@ Three formulations, all float32 at ``Precision.HIGHEST``:
 
 * :func:`gated_delta_step` — one token for a batch of states (decode);
   :func:`gated_delta_step_flat` is the same update on states stored
-  with the heads along the lanes, ``(dk, H dv)``.
+  with the heads along the lanes, ``(dk, H dv)``, and what the decode
+  step calls. Where ``dk`` is a multiple of 8 and ``H dv`` of 128 (the
+  published 96 and 30 x 192) it runs as one Pallas kernel,
+  ``gdn_step``, that reads each state tile once and writes it over
+  itself; any other shape takes :func:`gated_delta_step_flat_xla`, the
+  same arithmetic in plain XLA, which stays as the fallback and as
+  what the kernel is tested against. Nothing but the shape chooses.
 * :func:`gated_delta_chunked` — a whole sequence in chunks of ``chunk``
   tokens (prefill): inside a chunk the rule is solved in its WY form
   (one unit-lower-triangular solve a chunk, all chunks at once), and
@@ -41,8 +47,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _HI = jax.lax.Precision.HIGHEST
+LANES = 128
+# a tile of (96, 1920) float32 is 737 kB: read and written, each
+# double-buffered, 2.9 MB of a core's 16 MiB of scoped VMEM
+MAX_BLOCK_LANES = 2048
 
 
 def gated_delta_step(S, q, k, v, log_alpha, beta):
@@ -57,6 +68,12 @@ def gated_delta_step(S, q, k, v, log_alpha, beta):
     return S2, o
 
 
+def step_kernel_fits(dk: int, lanes: int) -> bool:
+    """Whether :func:`gated_delta_step_flat` runs its kernel on states
+    ``(dk, lanes)``: whole (8, 128) float32 tiles, nothing padded."""
+    return dk % 8 == 0 and lanes % LANES == 0
+
+
 def gated_delta_step_flat(S, q, k, v, log_alpha, beta):
     """One token on states stored ``(B, dk, H dv)``: the layout in
     which a float32 state tiles without padding on a TPU (``dv`` need
@@ -66,6 +83,18 @@ def gated_delta_step_flat(S, q, k, v, log_alpha, beta):
     dk); ``v`` (B, H, dv); ``log_alpha``, ``beta`` (B, H). Returns
     ``(S', o)`` with ``o`` (B, H, dv); the arithmetic of
     :func:`gated_delta_step` up to the order of the sums over ``dk``.
+
+    Where the shape fits (:func:`step_kernel_fits`) the update is one
+    kernel that reads each state tile once and writes it over itself;
+    any other shape takes :func:`gated_delta_step_flat_xla`."""
+    if step_kernel_fits(S.shape[1], S.shape[2]):
+        return _gated_delta_step_kernel(S, q, k, v, log_alpha, beta)
+    return gated_delta_step_flat_xla(S, q, k, v, log_alpha, beta)
+
+
+def gated_delta_step_flat_xla(S, q, k, v, log_alpha, beta):
+    """:func:`gated_delta_step_flat` in plain XLA: the form for shapes
+    the kernel does not take, and what the kernel is tested against.
 
     A head's value is spread over its ``dv`` lanes by a product with
     the 0/1 matrix ``E[h, h dv + j] = 1`` at ``Precision.HIGHEST``
@@ -89,6 +118,97 @@ def gated_delta_step_flat(S, q, k, v, log_alpha, beta):
     S2 = Sd + k_x * u
     o = jnp.sum(q_x * S2, axis=1)
     return S2, o.reshape(b, h, dv)
+
+
+def _bf16_pieces(x):
+    """A float32 array as three bfloat16 arrays that sum to it
+    exactly: its mantissa's 24 bits cut into three runs of eight by
+    masking, never by rounding, so each piece and each remainder is
+    exact (and a compiler that keeps excess precision across a
+    convert has nothing to keep)."""
+    def top(a):
+        bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+
+    hi = top(x)
+    mid = top(x - hi)
+    lo = x - hi - mid
+    return [p.astype(jnp.bfloat16) for p in (hi, mid, lo)]
+
+
+def _gated_delta_step_kernel(S, q, k, v, log_alpha, beta):
+    """The update as one Pallas kernel (``gdn_step`` in a device
+    trace) over tiles ``(1 slot, dk, lane block)`` of the state, which
+    it reads once and writes in place.
+
+    What a tile needs of ``k``, ``q``, ``alpha`` and ``beta`` is spread
+    over the tile's lanes in VMEM: the small operand ``x`` (rows: ``k``
+    by ``dk``, ``q`` by ``dk``, ``alpha``, ``beta``; columns: the three
+    bfloat16 pieces of each head's value) times the 0/1 matrix
+    ``E[piece * H + h, h dv + j] = 1`` in ONE bfloat16 pass with
+    float32 accumulation — exact, since the pieces sum to the value and
+    every other term is a zero. The arithmetic on the tile is float32
+    on the vector unit, a column of 128 lanes at a time."""
+    from jax.experimental import pallas as pl
+
+    from netsdb_tpu.ops.common import pallas_interpret
+
+    b, h, dk = k.shape
+    dv = v.shape[-1]
+    lanes = h * dv
+    block = _lane_block(lanes)
+    rows = 2 * dk + 16                   # k, q, alpha, beta, 14 of zeros
+    depth = -(-3 * h // 16) * 16         # the pieces, to bfloat16 tiles
+
+    gates = jnp.stack([jnp.exp(log_alpha), beta], axis=1)       # (B, 2, H)
+    x = jnp.concatenate(
+        [jnp.swapaxes(k, 1, 2), jnp.swapaxes(q, 1, 2), gates], axis=1)
+    x = jnp.pad(jnp.concatenate(_bf16_pieces(x), axis=2),
+                ((0, 0), (0, rows - x.shape[1]), (0, depth - 3 * h)))
+    E = np.zeros((depth, lanes), np.float32)
+    E[:3 * h] = np.tile(np.repeat(np.eye(h), dv, axis=1), (3, 1))
+    E = jnp.asarray(E, jnp.bfloat16)
+
+    def kernel(x_ref, e_ref, v_ref, s_ref, s_out_ref, o_ref):
+        xs = x_ref[0]
+        for c in range(block // LANES):
+            at = slice(c * LANES, (c + 1) * LANES)
+            spread = jnp.dot(xs, e_ref[:, at],
+                             preferred_element_type=jnp.float32)
+            k_x, q_x = spread[:dk], spread[dk:2 * dk]
+            alpha = spread[2 * dk:2 * dk + 1]
+            beta_x = spread[2 * dk + 1:2 * dk + 2]
+            Sd = s_ref[0, :, at] * alpha
+            kS = jnp.sum(k_x * Sd, axis=0, keepdims=True)
+            u = beta_x * (v_ref[0, :, at] - kS)
+            S2 = Sd + k_x * u
+            s_out_ref[0, :, at] = S2
+            o_ref[0, :, at] = jnp.sum(q_x * S2, axis=0, keepdims=True)
+
+    tile = pl.BlockSpec((1, dk, block), lambda j, i: (i, 0, j))
+    row = pl.BlockSpec((1, 1, block), lambda j, i: (i, 0, j))
+    # lane block outermost: E's block stays put while the slots pass
+    S2, o = pl.pallas_call(
+        kernel, grid=(lanes // block, b),
+        in_specs=[pl.BlockSpec((1, rows, depth), lambda j, i: (i, 0, 0)),
+                  pl.BlockSpec((depth, block), lambda j, i: (0, j)),
+                  row, tile],
+        out_specs=[tile, row],
+        out_shape=[jax.ShapeDtypeStruct(S.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, lanes), jnp.float32)],
+        input_output_aliases={3: 0}, name="gdn_step",
+        interpret=pallas_interpret())(
+            x, E, v.reshape(b, 1, lanes), S)
+    return S2, o.reshape(b, h, dv)
+
+
+def _lane_block(lanes: int) -> int:
+    """Lanes of a state tile: the widest run of whole 128-lane columns
+    that divides ``lanes`` and stays within ``MAX_BLOCK_LANES``."""
+    cols = lanes // LANES
+    return LANES * max(d for d in range(1, cols + 1)
+                       if cols % d == 0 and d * LANES <= MAX_BLOCK_LANES)
 
 
 def heads_first(S_flat, heads: int):

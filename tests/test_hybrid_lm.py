@@ -22,6 +22,7 @@ import contextlib
 import os
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -140,6 +141,46 @@ def test_one_step_equals_one_token_of_the_recurrence():
     s_r, o_r = delta_rule.gated_delta_recurrent(s0, q, k, v, la, beta)
     np.testing.assert_allclose(np.asarray(s[0]), np.asarray(s_r), atol=1e-6)
     np.testing.assert_allclose(np.stack(outs), np.asarray(o_r), atol=1e-6)
+
+
+def test_the_step_calls_the_update_through_the_modules_global(monkeypatch):
+    """The benchmark's fault (``benchmark/tests/faults_lm.py``) puts a
+    wrapper of six positional arguments, one layer's states ``(slots,
+    dk, H dv)`` first, in place of ``hybrid_lm.gated_delta_step_flat``
+    and calls ``delta_rule.gated_delta_step_flat`` with ``1.5 * beta``:
+    the step program must be built through that seam, so its logits
+    change."""
+    make = hybrid_lm.random_weights(SPEC, 3)
+    params = {n: jnp.asarray(make(n, shape, m)) for n, (shape, m)
+              in hybrid_lm.weight_shapes(SPEC).items()}
+    active = np.arange(SPEC["slots"]) != 2
+
+    def two_steps():
+        slab = {n: jnp.zeros(e["shape"], e["dtype"])
+                for n, e in hybrid_lm.state_layout(SPEC).items()}
+        slab["tok"] = jnp.arange(SPEC["slots"], dtype=jnp.int32) + 7
+        step = jax.jit(hybrid_lm.build_step(SPEC))
+        slab, _, _ = step(params, slab, active)
+        slab, _, logits = step(params, slab, active)
+        return slab, np.asarray(logits)
+
+    slab, logits = two_steps()
+    # the idle slot's states are as they were
+    assert not np.asarray(slab["S0"])[2].any()
+    assert np.asarray(slab["S0"])[1].any()
+    seen = []
+
+    def altered(S, q, k, v, log_alpha, beta):
+        seen.append(S.shape)
+        return delta_rule.gated_delta_step_flat(S, q, k, v, log_alpha,
+                                                1.5 * beta)
+
+    monkeypatch.setattr(hybrid_lm, "gated_delta_step_flat", altered)
+    _, faulty = two_steps()
+    lin = SPEC["lin_heads"] * SPEC["lin_dv"]
+    assert seen == [(SPEC["slots"], SPEC["lin_dk"], lin)] * TYPES.count(
+        "linear_attention")
+    assert np.abs(faulty - logits)[active].max() > 1e-3
 
 
 def test_the_two_reference_files_are_one():
@@ -308,8 +349,9 @@ def test_spill_and_revive_of_both_kinds_of_state(tmp_path):
             out.append((ids.tobytes(), h.last_logits().tobytes()))
             ctl.library.store.device_cache().session_sweep(now=1e18)
             layers = ctl.sessions.arena.snapshot_slot(h.sid, "lm")["layers"]
-            assert {"S", "conv", "k0", "v1", "pos", "tok"} <= set(layers)
-            assert np.abs(layers["S"]["v"]).max() > 0
+            assert {"S0", "S5", "conv", "k0", "v1", "pos",
+                    "tok"} <= set(layers)
+            assert np.abs(layers["S0"]["v"]).max() > 0
             assert np.abs(np.asarray(layers["k0"]["v"],
                                      np.float32)).max() > 0
         assert out == steady
