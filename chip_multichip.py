@@ -16,8 +16,8 @@ loopback socket), so nothing here competes for a device:
 4. ``parallel.ring.ring_attention(impl=None)`` at S 8192 over the four
    chips takes the flash-carry kernel and equals the reference;
 5. (recorded, not judged) on which device the arrays of a 4-daemon
-   in-process pool land — the ``run_serving_bench`` set-up, which binds
-   no daemon to a device.
+   in-process pool (a leader and three shard workers) land: the set-up
+   binds no daemon to a device.
 
 Run by a builder on a four-chip host: ``python chip_multichip.py``. It is
 not part of the driver's check (``chip_smoke.py`` is). ``--dryrun-cpu``
@@ -210,8 +210,8 @@ def ring(sz: Dict[str, Any], dryrun: bool) -> Dict[str, Any]:
 
 def pool_landing(sz: Dict[str, Any]) -> Dict[str, Any]:
     """OBSERVATION ONLY: a 4-daemon in-process pool (leader + 3 shard
-    workers, as ``workloads.serve_bench.run_serving_bench`` builds it)
-    scores one FF batch; report which device each daemon's stored
+    workers, each a ``ServeController`` on a loopback port) scores one
+    FF batch; report which device each daemon's stored
     weights and routed batch slice sit on. No daemon binds a device, so the
     expectation from the code is: all on device 0."""
     from netsdb_tpu.config import Configuration

@@ -52,7 +52,8 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, "chip_smoke_out")
 
-# the flagship FF at the width bench.py uses, and what shares its daemon
+# the flagship FF (1024 -> 4096 -> 1024 over 16,384 rows), and what shares
+# its daemon
 FULL = dict(
     features=1024, hidden=4096, labels=1024, block=(512, 512),
     batch=16384, ff_requests=3,
@@ -513,8 +514,8 @@ def kernel_child(dryrun: bool) -> int:
           f"flash_attention_step chain vs attention: "
           f"{out['ring_step_err']:.3e}")
 
-    # the transformer layer through an in-process Client (weights from
-    # sets, as workloads/transformer_bench.py sets it up)
+    # the transformer layer through an in-process Client, its weights
+    # read from sets
     from netsdb_tpu.client import Client
     from netsdb_tpu.config import Configuration
     from netsdb_tpu.models.transformer import TransformerLayerModel

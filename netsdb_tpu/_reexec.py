@@ -7,9 +7,8 @@ the process with the venv interpreter re-running the ORIGINAL command
 line (recovered from ``/proc/self/cmdline``, so ``-m pkg.submodule``
 targets re-run exactly as requested rather than being rewritten).
 
-Must not import anything outside the stdlib, and is loaded by file path
-from ``bench.py`` (importing the package would re-trigger the very
-ModuleNotFoundError being handled).
+Must not import anything outside the stdlib (importing the package
+would re-trigger the very ModuleNotFoundError being handled).
 """
 
 from __future__ import annotations

@@ -31,9 +31,8 @@ _UPLOAD_EXEMPT = ("netsdb_tpu/plan/staging.py",
                   "netsdb_tpu/storage/devcache.py")
 #: protocol.py metadata codec — the only pickle-allowed functions
 _PICKLE_OK_FUNCS = {"encode_body", "decode_body"}
-#: print() is the OUTPUT of these (operator CLI / bench scripts)
+#: print() is the OUTPUT of these (the operator CLI and its re-exec)
 _PRINT_EXEMPT = ("netsdb_tpu/cli.py", "netsdb_tpu/_reexec.py")
-_PRINT_EXEMPT_DIRS = ("netsdb_tpu/workloads/",)
 
 _LOOP_NODES = (ast.For, ast.While, ast.AsyncFor, ast.ListComp,
                ast.SetComp, ast.DictComp, ast.GeneratorExp)
@@ -262,18 +261,15 @@ class ModuleDictCounterRule(Rule):
 
 @register
 class PrintBanRule(Rule):
-    """``print()`` outside cli.py / workloads / _reexec."""
+    """``print()`` outside cli.py / _reexec."""
 
     id = "print-ban"
     rationale = ("daemons and libraries report through the logger or "
                  "the metrics registry, never stdout")
 
     def select(self, mod: Module) -> bool:
-        if not mod.rel.startswith("netsdb_tpu/"):
-            return False
-        if mod.rel in _PRINT_EXEMPT:
-            return False
-        return not mod.rel.startswith(_PRINT_EXEMPT_DIRS)
+        return mod.rel.startswith("netsdb_tpu/") \
+            and mod.rel not in _PRINT_EXEMPT
 
     def check_module(self, mod: Module) -> Iterable[Diagnostic]:
         for node in mod.walk():
@@ -282,7 +278,7 @@ class PrintBanRule(Rule):
                     and node.func.id == "print":
                 yield self.diag(
                     mod, node,
-                    "print() outside cli.py/workloads/ — use "
+                    "print() outside cli.py — use "
                     "utils.profiling.get_logger or a registry counter")
 
 

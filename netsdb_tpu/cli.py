@@ -7,7 +7,6 @@ Single-controller JAX needs no resident servers, so the operator surface
 is one CLI:
 
     python -m netsdb_tpu info                 # cluster/devices (ResourceManager)
-    python -m netsdb_tpu bench                # the benchmark harness
     python -m netsdb_tpu pdml PROG.pdml       # run a LA DSL program
     python -m netsdb_tpu demo-ff [...]        # FFTest.cc equivalent
     python -m netsdb_tpu tpch [--query q01]   # TPC-H demo queries
@@ -29,18 +28,6 @@ def _cmd_info(args) -> int:
     info = cluster_info()
     info["backend"] = jax.default_backend()
     print(json.dumps(info, indent=2))
-    return 0
-
-
-def _cmd_bench(args) -> int:
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    import bench
-
-    bench.main()
     return 0
 
 
@@ -104,59 +91,6 @@ def _cmd_tpch(args) -> int:
         print(f"{q}: {n} rows in {dt*1e3:.1f} ms")
         if args.print_values:
             print(rows)
-    return 0
-
-
-def _cmd_tpch_bench(args) -> int:
-    """Columnar TPC-H on device at dbgen scale — the perf counterpart of
-    ``tpch`` (reference baseline: BASELINE.md query times)."""
-    import json
-
-    from netsdb_tpu.relational import bench
-
-    res = bench.main(sf=args.sf, iters=args.iters)
-    print(json.dumps(res, indent=2))
-    return 0
-
-
-def _cmd_transformer_bench(args) -> int:
-    from netsdb_tpu.workloads.transformer_bench import bench_transformer_layer
-
-    print(json.dumps(bench_transformer_layer(
-        seq_lens=tuple(args.seq), batch=args.batch, embed=args.embed,
-        heads=args.heads)))
-    return 0
-
-
-def _cmd_reddit_bench(args) -> int:
-    from netsdb_tpu.workloads.reddit_columnar import bench_label_propagation
-
-    print(json.dumps(bench_label_propagation(rows=args.rows,
-                                             n_authors=args.authors)))
-    return 0
-
-
-def _cmd_ooc_bench(args) -> int:
-    from netsdb_tpu.relational.outofcore import bench_out_of_core
-
-    print(json.dumps(bench_out_of_core(rows=args.rows,
-                                       pool_bytes=args.pool_mb << 20)))
-    return 0
-
-
-def _cmd_paged_api_bench(args) -> int:
-    from netsdb_tpu.relational.outofcore import bench_paged_set_api
-
-    print(json.dumps(bench_paged_set_api(rows=args.rows,
-                                         pool_bytes=args.pool_mb << 20),
-                     default=str))
-    return 0
-
-
-def _cmd_lsh_bench(args) -> int:
-    from netsdb_tpu.dedup.lsh import bench_lsh_zoo
-
-    print(json.dumps(bench_lsh_zoo(n_models=args.models)))
     return 0
 
 
@@ -548,121 +482,6 @@ def _cmd_selftest(args) -> int:
         step(name, fn)
     print(f"{len(steps) - len(failures)}/{len(steps)} passed")
     return 1 if failures else 0
-
-
-def _cmd_la_bench(args) -> int:
-    """The reference's headline LA tasks (Gram / linreg / matmul at
-    200000x1000 scale — BASELINE.md rows 1-3) via the PDML DSL."""
-    from netsdb_tpu.workloads import la_tasks
-
-    tasks = list(la_tasks.TASKS) if args.task == "all" else [args.task]
-    for t in tasks:
-        res = la_tasks.run_task(t, rows=args.rows, cols=args.cols,
-                                block=args.block, iters=args.iters)
-        print(json.dumps(res))
-    return 0
-
-
-def _cmd_conv_bench(args) -> int:
-    """Conv2d batch-latency p50 (both modes) vs the reference's ATen
-    CPU path at its documented shapes."""
-    from netsdb_tpu.workloads.conv_bench import run_conv_bench
-
-    print(json.dumps(run_conv_bench(
-        batch=args.batch, hw=args.hw, cin=args.cin, cout=args.cout,
-        k=args.k, iters=args.iters,
-        compute_dtype=args.compute_dtype), indent=2))
-    return 0
-
-
-def _cmd_model_bench(args) -> int:
-    """word2vec / LSTM / text-classifier inference throughput vs the
-    netsDB-equivalent CPU path (no reference-published numbers exist)."""
-    from netsdb_tpu.workloads.model_bench import run_model_bench
-
-    print(json.dumps(run_model_bench(scale=args.scale), indent=2))
-    return 0
-
-
-def _cmd_attention_bench(args) -> int:
-    """Long-context flash-vs-naive attention (beyond-reference)."""
-    from netsdb_tpu.workloads.attention_bench import bench_attention
-
-    seqs = [int(s) for s in args.seqs.split(",")]
-    print(json.dumps(bench_attention(seq_lens=seqs, batch=args.batch,
-                                     heads=args.heads,
-                                     head_dim=args.head_dim), indent=2))
-    return 0
-
-
-def _cmd_micro_bench(args) -> int:
-    if getattr(args, "summa", False):
-        # the SUMMA A/B needs a mesh: on a single-accelerator (or
-        # CPU-only) box, force the virtual host-platform mesh BEFORE
-        # jax initializes its backends — the same fixture tier-1 uses
-        import os as _os
-
-        # jax reads XLA_FLAGS at BACKEND initialization (the first
-        # devices()/computation), not at import — setting it here is
-        # early enough as long as nothing above dispatched to a device
-        _flags = _os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in _flags:
-            _os.environ.setdefault("JAX_PLATFORMS", "cpu")
-            _os.environ["XLA_FLAGS"] = (
-                _flags + " --xla_force_host_platform_device_count=4"
-            ).strip()
-    from netsdb_tpu.workloads import micro_bench
-
-    if getattr(args, "staging", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_staging(), indent=2))
-        return 0
-    if getattr(args, "bucket_sweep", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_bucket_sweep(), indent=2))
-        return 0
-    if getattr(args, "obs_overhead", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_obs_overhead(), indent=2))
-        return 0
-    if getattr(args, "explain_overhead", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_explain_overhead(), indent=2))
-        return 0
-    if getattr(args, "lint_overhead", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_lint_overhead(), indent=2))
-        return 0
-    if getattr(args, "fusion", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_fusion(), indent=2))
-        return 0
-    if getattr(args, "summa", False):
-        import json
-
-        print(json.dumps(micro_bench.bench_summa(), indent=2,
-                         default=str))
-        return 0
-    names = None
-    if args.only is not None:
-        names = [n.strip() for n in args.only.split(",") if n.strip()]
-        if not names:
-            print(f"--only given but no benchmark names; available: "
-                  f"{', '.join(micro_bench.BENCHMARKS)}", file=sys.stderr)
-            return 2
-        unknown = [n for n in names if n not in micro_bench.BENCHMARKS]
-        if unknown:
-            print(f"unknown benchmark(s) {unknown}; available: "
-                  f"{', '.join(micro_bench.BENCHMARKS)}", file=sys.stderr)
-            return 2
-    micro_bench.run_all(names=names)
-    return 0
 
 
 def _cmd_serve(args) -> int:
@@ -1183,56 +1002,11 @@ def _cmd_lint(args) -> int:
     return 1 if diags else 0
 
 
-def _cmd_serve_bench(args) -> int:
-    if getattr(args, "fusion_distributed", False):
-        from netsdb_tpu.workloads.serve_bench import (
-            run_fusion_distributed_bench)
-
-        out = run_fusion_distributed_bench(
-            daemons=getattr(args, "daemons", 4))
-    elif getattr(args, "scale", False):
-        from netsdb_tpu.workloads.serve_bench import run_scaleout_bench
-
-        out = run_scaleout_bench(daemons=getattr(args, "daemons", 4))
-    elif getattr(args, "rebalance", False):
-        from netsdb_tpu.workloads.serve_bench import run_rebalance_bench
-
-        out = run_rebalance_bench(daemons=getattr(args, "daemons", 4))
-    elif getattr(args, "scheduler", False):
-        from netsdb_tpu.workloads.serve_bench import run_scheduler_bench
-
-        out = run_scheduler_bench(
-            clients=args.clients if args.clients is not None else 8)
-    elif getattr(args, "partial_cache", False):
-        from netsdb_tpu.workloads.serve_bench import run_partial_cache_bench
-
-        out = run_partial_cache_bench()
-    elif getattr(args, "device_cache", False):
-        from netsdb_tpu.workloads.serve_bench import run_device_cache_bench
-
-        out = run_device_cache_bench()
-    elif getattr(args, "data_plane", False):
-        from netsdb_tpu.workloads.serve_bench import run_data_plane_bench
-
-        out = run_data_plane_bench(table_mb=args.table_mb)
-    else:
-        from netsdb_tpu.workloads.serve_bench import run_serve_bench
-
-        out = run_serve_bench(clients=args.clients
-                              if args.clients is not None else 2,
-                              jobs_per_client=args.jobs,
-                              batch=args.batch, port=args.port,
-                              platform=args.platform)
-    print(json.dumps(out, indent=2))
-    return 0
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="netsdb_tpu")
     sub = parser.add_subparsers(dest="cmd", required=True)
 
     sub.add_parser("info", help="cluster and device info")
-    sub.add_parser("bench", help="run the benchmark harness")
 
     p = sub.add_parser("pdml", help="run a PDML linear-algebra program")
     p.add_argument("file")
@@ -1245,75 +1019,6 @@ def main(argv=None) -> int:
     p.add_argument("--labels", type=int, default=10)
     p.add_argument("--block", type=int, default=256)
 
-    p = sub.add_parser("la-bench",
-                       help="headline LA tasks (Gram/linreg/matmul) vs "
-                            "the reference's published numbers")
-    p.add_argument("--task", default="all",
-                   choices=["all", "gram", "linreg", "matmul"])
-    p.add_argument("--rows", type=int, default=200000)
-    p.add_argument("--cols", type=int, default=1000)
-    p.add_argument("--block", type=int, default=1000)
-    p.add_argument("--iters", type=int, default=5)
-
-    p = sub.add_parser("conv-bench",
-                       help="conv2d batch latency p50 vs ATen CPU path")
-    p.add_argument("--batch", type=int, default=64)
-    p.add_argument("--hw", type=int, default=112)
-    p.add_argument("--cin", type=int, default=3)
-    p.add_argument("--cout", type=int, default=64)
-    p.add_argument("--k", type=int, default=7)
-    p.add_argument("--iters", type=int, default=20)
-    p.add_argument("--compute-dtype", default=None)
-
-    p = sub.add_parser("model-bench",
-                       help="word2vec/LSTM/text-classifier throughput "
-                            "vs netsDB-equivalent CPU path")
-    p.add_argument("--scale", type=float, default=1.0,
-                   help="multiplier on all benchmark dimensions")
-
-    p = sub.add_parser("attention-bench",
-                       help="flash vs naive attention at long seq lens")
-    p.add_argument("--seqs", default="1024,2048,4096,8192")
-    p.add_argument("--batch", type=int, default=2)
-    p.add_argument("--heads", type=int, default=8)
-    p.add_argument("--head-dim", type=int, default=128)
-
-    p = sub.add_parser("micro-bench",
-                       help="runtime micro-benchmarks (serviceBenchmarks)")
-    p.add_argument("--only", default=None,
-                   help="comma-separated benchmark names")
-    p.add_argument("--staging", action="store_true",
-                   help="overlapped vs synchronous device staging on "
-                        "the out-of-core matmul and fold streams")
-    p.add_argument("--bucket-sweep", action="store_true",
-                   help="pad-waste vs trace-count per shape-ladder "
-                        "density (the bucket_density knob: 2 vs 4 "
-                        "buckets per octave)")
-    p.add_argument("--obs-overhead", action="store_true",
-                   help="cost of always-on query tracing on the staged "
-                        "fold stream (traced vs untraced; < 3%% is the "
-                        "budget)")
-    p.add_argument("--explain-overhead", action="store_true",
-                   help="cost of per-node operator attribution on the "
-                        "staged fold stream (explain on vs off; < 1%% "
-                        "budget, ~0 when off)")
-    p.add_argument("--lint-overhead", action="store_true",
-                   help="cost of the runtime lock-order witness on "
-                        "the staged fold stream (witness on vs off; "
-                        "< 2%% budget, ~0 when off)")
-    p.add_argument("--fusion", action="store_true",
-                   help="fusion-aware plan compilation paired A/B "
-                        "(plan_fusion on vs off on the staged fold "
-                        "stream + a resident-spine mixed plan; "
-                        "reports plan_fusion_speedup + trace counts)")
-    p.add_argument("--summa", action="store_true",
-                   help="distributed linear algebra paired A/B: SUMMA "
-                        "panel staging vs replicated operands on the "
-                        "virtual mesh (per-host staged bytes ~1/N, "
-                        "byte-equality gated) + reshard-via-"
-                        "collectives vs re-stage-from-arena (zero "
-                        "arena reads proof)")
-
     sub.add_parser("selftest",
                    help="scripted integration sequence (integratedTests.py)")
 
@@ -1323,12 +1028,6 @@ def main(argv=None) -> int:
                             "q14", "q17", "q22"])
     p.add_argument("--scale", type=int, default=1)
     p.add_argument("--print-values", action="store_true")
-
-    p = sub.add_parser("tpch-bench",
-                       help="columnar TPC-H device benchmark (dbgen scale)")
-    p.add_argument("--sf", type=float, default=0.1,
-                   help="TPC-H scale factor (lineitem ≈ 6M rows at sf=1)")
-    p.add_argument("--iters", type=int, default=10)
 
     p = sub.add_parser("serve", help="run the resident controller daemon "
                        "(ref MasterMain: the server that owns the device "
@@ -1367,58 +1066,6 @@ def main(argv=None) -> int:
                    help="force a jax platform (e.g. cpu) — env overrides "
                    "are ignored by the ambient plugin, only jax.config "
                    "works, so the daemon must set it itself")
-
-    p = sub.add_parser("serve-bench",
-                       help="FF inference throughput over the RPC hop, "
-                       "concurrent client processes against one daemon")
-    p.add_argument("--clients", type=int, default=None,
-                   help="concurrent clients (default: 2, or 8 for "
-                        "--scheduler); explicit values always win")
-    p.add_argument("--jobs", type=int, default=8,
-                   help="inference jobs per client")
-    p.add_argument("--batch", type=int, default=16384)
-    p.add_argument("--port", type=int, default=0,
-                   help="0 = spawn a private daemon on an ephemeral port")
-    p.add_argument("--platform", default=None,
-                   help="jax platform for the spawned daemon (e.g. cpu)")
-    p.add_argument("--data-plane", action="store_true",
-                   help="v3 data-plane numbers instead: single-frame vs "
-                   "streamed pipelined ingest MB/s, scan MB/s, zero-copy "
-                   "tensor push/pull, hedged-read p99")
-    p.add_argument("--table-mb", type=int, default=64)
-    p.add_argument("--device-cache", action="store_true",
-                   help="cold vs warm EXECUTE latency over a "
-                        "device-cache-resident paged set instead "
-                        "(hit/miss counters included)")
-    p.add_argument("--partial-cache", action="store_true",
-                   help="partial-run caching paired A/B instead: "
-                        "warm re-query after a 1%% append under "
-                        "dirty-range vs whole-run invalidation")
-    p.add_argument("--scheduler", action="store_true",
-                   help="query-scheduler paired A/B instead: N "
-                        "concurrent identical cold EXECUTEs, "
-                        "scheduler on vs off (executions run, "
-                        "devcache installs, coalesce hits, p50/p99)")
-    p.add_argument("--scale", action="store_true",
-                   help="horizontal scale-out instead: paired 1 vs N "
-                        "daemon arm — aggregate routed-ingest MB/s, "
-                        "cold scatter-gather q01 QPS and "
-                        "byte-equality incl. a distributed-shuffle "
-                        "join")
-    p.add_argument("--daemons", type=int, default=4,
-                   help="pool size for --scale (leader + N-1 shards)")
-    p.add_argument("--rebalance", action="store_true",
-                   help="self-rebalancing paired A/B instead: a "
-                        "4-daemon pool under an 80/20 skewed mix "
-                        "registers a 5th daemon mid-run — rebalance "
-                        "on vs frozen (recovery throughput ratio, "
-                        "zero failed requests, exact totals)")
-    p.add_argument("--fusion-distributed", action="store_true",
-                   help="distributed fusion paired A/B instead: "
-                        "4-daemon scatter q01 + 3-sink fan under "
-                        "the optimal mapper vs greedy vs "
-                        "plan_fusion=off — one-program-per-shard, "
-                        "one-subplan fan and byte-equality gates")
 
     p = sub.add_parser("obs",
                        help="observability readout of a running daemon: "
@@ -1527,36 +1174,6 @@ def main(argv=None) -> int:
                        "the live backend and persist per device kind")
     p.add_argument("--no-persist", action="store_true")
 
-    p = sub.add_parser("transformer-bench",
-                       help="set-backed long-context transformer layer "
-                       "forward (flash attention), tokens/s + TFLOP/s")
-    p.add_argument("--seq", type=int, nargs="+", default=[4096, 8192])
-    p.add_argument("--batch", type=int, default=2)
-    p.add_argument("--embed", type=int, default=1024)
-    p.add_argument("--heads", type=int, default=8)
-
-    p = sub.add_parser("reddit-bench",
-                       help="columnar reddit label propagation at scale")
-    p.add_argument("--rows", type=int, default=1_000_000)
-    p.add_argument("--authors", type=int, default=50_000)
-
-    p = sub.add_parser("ooc-bench",
-                       help="out-of-core TPC-H q01/q06 through the paged "
-                       "store under a pool cap")
-    p.add_argument("--rows", type=int, default=60_000_000)
-    p.add_argument("--pool-mb", type=int, default=1024)
-
-    p = sub.add_parser("paged-api-bench",
-                       help="SF10-scale q01 + one-pass grace q03 through "
-                            "the SET-API paged path (create_set(storage="
-                            "'paged') + suite/q03 sinks) under a pool cap")
-    p.add_argument("--rows", type=int, default=60_000_000)
-    p.add_argument("--pool-mb", type=int, default=1024)
-
-    p = sub.add_parser("lsh-bench",
-                       help="LSH dedup index over a synthetic model zoo")
-    p.add_argument("--models", type=int, default=100)
-
     p = sub.add_parser("ab-bench",
                        help="live placement-advisor A/B (Lachesis loop)")
     p.add_argument("--rounds", type=int, default=4)
@@ -1568,22 +1185,13 @@ def main(argv=None) -> int:
         from netsdb_tpu.config import enable_compilation_cache
 
         enable_compilation_cache()  # every CLI path shares the plan cache
-    return {"info": _cmd_info, "bench": _cmd_bench, "pdml": _cmd_pdml,
+    return {"info": _cmd_info, "pdml": _cmd_pdml,
             "lint": _cmd_lint,
             "autotune": _cmd_autotune,
-            "transformer-bench": _cmd_transformer_bench,
-            "reddit-bench": _cmd_reddit_bench,
-            "ooc-bench": _cmd_ooc_bench,
-            "paged-api-bench": _cmd_paged_api_bench,
-            "lsh-bench": _cmd_lsh_bench,
             "ab-bench": _cmd_ab_bench,
-            "serve": _cmd_serve, "serve-bench": _cmd_serve_bench,
+            "serve": _cmd_serve,
             "obs": _cmd_obs,
             "demo-ff": _cmd_demo_ff, "tpch": _cmd_tpch,
-            "micro-bench": _cmd_micro_bench, "tpch-bench": _cmd_tpch_bench,
-            "model-bench": _cmd_model_bench,
-            "attention-bench": _cmd_attention_bench,
-            "la-bench": _cmd_la_bench, "conv-bench": _cmd_conv_bench,
             "selftest": _cmd_selftest}[args.cmd](args)
 
 

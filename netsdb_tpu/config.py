@@ -56,8 +56,7 @@ class Configuration:
     stream_prefetch_pages: int = 2
     # device staging double-buffer depth: how many blocks ahead the
     # background thread runs jax.device_put (with the set's sharding)
-    # of the consumer's fold step; 0 = synchronous device_put (the
-    # baseline path `micro-bench --staging` compares against).
+    # of the consumer's fold step; 0 = synchronous device_put.
     stage_depth: int = 2
     # pad streamed row chunks up to the fixed bucket ladder
     # (plan/staging.bucket_rows: powers of two and 1.5x powers of two)
@@ -68,8 +67,6 @@ class Configuration:
     # buckets per octave in the shape ladder: 2 (default — {2^k,
     # 3*2^(k-1)}, <50% pad worst case) or 4 (2^(k-1)*{1.25,1.5,1.75}
     # rungs added — <25% pad at twice the compiles per octave).
-    # `micro_bench --bucket-sweep` reports pad-waste vs trace-count
-    # per density (the ROADMAP ladder-tuning item).
     bucket_density: int = 2
     # --- fusion-aware plan compilation (plan/fusion.py) ---
     # master switch for the region mapper: on, the streamed executor
@@ -203,7 +200,7 @@ class Configuration:
     # wall/device time, rows, chunk + cache/compile counters) into its
     # profile and the cross-query operator ledger; off, only explicit
     # EXECUTE(explain=True) requests record. Cost rides the trace
-    # sampling knob — `micro_bench --explain-overhead` pins it < 1%.
+    # sampling knob.
     obs_explain: bool = True
     # continuous telemetry history (obs/history.py): the daemon
     # snapshots the registry's numeric surface every
@@ -357,8 +354,7 @@ class Configuration:
     # into one bounded process graph and flags cycles — potential
     # AB/BA deadlocks that never fired. The tier-1 suite enables it via
     # conftest; production defaults off (disabled cost: one global
-    # read + is-None check per acquisition; enabled cost pinned < 2%
-    # by `micro_bench --lint-overhead`).
+    # read + is-None check per acquisition).
     lock_witness: bool = False
     # --- execution ---
     num_threads: int = 4  # host-side IO/pipeline threads (not device parallelism)

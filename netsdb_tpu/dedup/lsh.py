@@ -210,48 +210,3 @@ def dedup_model_zoo(models: Dict[str, BlockedTensor],
             "all_pairs": total_pairs,
             "pair_work_fraction": (index.verified_pairs / total_pairs
                                    if total_pairs else 0.0)}
-
-
-def bench_lsh_zoo(n_models: int = 100, blocks_per_model: int = 8,
-                  block: int = 256, n_families: int = 10,
-                  noise: float = 1e-4, seed: int = 0
-                  ) -> Dict[str, object]:
-    """100 synthetic model variants (n_families base models, each with
-    near-duplicate fine-tuned copies) indexed + grouped, with measured
-    build and probe time — the model-zoo scale test."""
-    import time
-
-    rng = np.random.default_rng(seed)
-    bases = [rng.standard_normal((blocks_per_model * block, block)
-                                 ).astype(np.float32)
-             for _ in range(n_families)]
-    models = {}
-    truth = {}
-    for i in range(n_models):
-        fam = i % n_families
-        dense = bases[fam] + noise * rng.standard_normal(
-            bases[fam].shape).astype(np.float32)
-        models[f"model{i}"] = BlockedTensor.from_dense(dense,
-                                                       (block, block))
-        truth[f"model{i}"] = fam
-
-    t0 = time.perf_counter()
-    index = LSHIndex()
-    for name, t in models.items():
-        index.add_model(name, t)
-    build_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    groups = index.near_duplicate_groups()
-    probe_s = time.perf_counter() - t0
-
-    # grading: every group must be family-pure, and each (family, block
-    # position) should unite all its variants
-    pure = all(len({truth[name] for name, _ in g}) == 1 for g in groups)
-    n = len(index._sigs)
-    return {"models": n_models, "blocks": n,
-            "build_s": round(build_s, 3), "probe_s": round(probe_s, 3),
-            "groups": len(groups), "groups_family_pure": pure,
-            "verified_pairs": index.verified_pairs,
-            "all_pairs": n * (n - 1) // 2,
-            "index_stats": index.stats()}

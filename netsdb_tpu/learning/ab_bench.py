@@ -606,7 +606,7 @@ def bench_rebalance_ab(rows: int = 24_000, rounds: int = 2,
     from netsdb_tpu.learning.advisor import rebalance_candidates
     from netsdb_tpu.serve.client import RemoteClient
     from netsdb_tpu.serve.server import ServeController
-    from netsdb_tpu.workloads.serve_bench import scaleout_table
+    from netsdb_tpu.workloads.scaleout import scaleout_table
 
     hdb = HistoryDB(history_path)
     cands = list(rebalance_candidates())

@@ -14,8 +14,8 @@ the decode loop wants exactly two disciplines:
   ``plan/staging.bucket_rows`` ladder, so batch churn between 1 and
   ``decode_batch_max`` live sessions re-dispatches a cached executable
   instead of retracing. :func:`decode_stats`'s ``traces`` counter is
-  the proof — the sessions bench pins it to the number of distinct
-  (model-shape, bucket) pairs.
+  the proof: it stops at the number of distinct (model-shape, bucket)
+  pairs.
 * **O(1) per-step state.** The LSTM carries ``(h, c)``; the
   transformer layer carries a RING-BUFFER KV cache of fixed
   ``kv_max`` entries (position writes at ``pos % kv_max`` — the
@@ -25,7 +25,7 @@ Every step function is ROW-INDEPENDENT: row ``i`` of the output
 depends only on row ``i`` of the inputs and the (shared) weights, so
 a session decoded inside a padded batch of 8 produces bit-identical
 outputs to the same session decoded alone — the byte-equality gate
-``bench.py --sessions`` enforces, and the property that lets HA
+``tests/test_sessions.py`` enforces, and the property that lets HA
 followers replay mirrored GENERATE frames solo yet converge on the
 leader's exact state.
 

@@ -220,7 +220,7 @@ class FFModel:
             sink, job_name=f"{self.db}-inference-fused-{out_mode}")
         return next(iter(results.values()))
 
-    # --- pure-function forms (for jit/bench/sharding) -----------------
+    # --- pure-function forms (for jit/sharding) -----------------------
     def params_from_store(self, client: Client) -> FFParams:
         return FFParams(
             w1=client.get_tensor(self.db, "w1"),

@@ -29,9 +29,7 @@ scale-out pool (``serve/shard.py``), in three pieces:
 
 ``explain=True`` scoring returns the per-layer EXPLAIN decomposition:
 the coordinator slot's annotated operator tree plus the full
-per-shard forest, every node marked with the daemon that executed it
-— what ``bench.py --serve``'s ``ff_inference_rows_per_sec_per_chip``
-headline renders.
+per-shard forest, every node marked with the daemon that executed it.
 """
 
 from __future__ import annotations

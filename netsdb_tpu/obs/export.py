@@ -197,7 +197,6 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("obs.traces.client", "completed client-origin query traces"),
         ("obs.traces.server", "completed server-origin query traces"),
         ("obs.traces.local", "completed local-origin query traces"),
-        ("obs.traces.bench", "completed bench-origin query traces"),
         ("obs.qid_sampled_out", "requests that skipped tracing under "
                                 "1-in-N qid sampling"),
         ("obs.slow_queries", "profiles persisted to the slowlog ring"),

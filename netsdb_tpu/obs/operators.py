@@ -20,9 +20,7 @@ Mechanics mirror the query trace exactly:
   the op record on the consumer's thread at stream construction and
   tick counters explicitly (the ``StagedStream`` discipline);
 * cost discipline: with no recorder installed, :func:`op_add` is one
-  context-var read and an ``is None`` check; ``micro_bench
-  --explain-overhead`` pins the recorded-path cost on the staged fold
-  stream (< 1% is the budget).
+  context-var read and an ``is None`` check.
 
 The finished tree (node id = TOPO POSITION — stable across plan
 rebuilds, unlike the process-global ``node_id``) lands in three

@@ -38,9 +38,9 @@ a deadline.
 Cost discipline: tracing is ALWAYS ON (``config.obs_enabled`` is the
 kill switch). The no-trace fast path of :func:`span` is one context-var
 read and one ``is None`` check; with a trace active, a span is two
-``perf_counter`` reads and one list append under a lock.
-``micro_bench --obs-overhead`` pins the end-to-end cost on the staged
-fold stream (< 3% is the budget).
+``perf_counter`` reads and one list append under a lock. What it costs
+end to end is measured on the chip, spans on against spans off
+(``PERF.md`` §3, "Cost of the instrument").
 
 Completed traces land in a bounded :class:`TraceRing` — the daemon
 keeps the last N query profiles for the ``GET_TRACE`` frame; client

@@ -156,8 +156,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     the v5e VPU softmax chain that cannot overlap the two MXU passes.
     A triangular-grid variant that schedules only lower-triangle
     blocks measured the same — dead blocks were already free — and was
-    removed. The `attention-bench` guard asserts flash >= 0.92x of the
-    tuned jax kernel at 8k so these claims stay earned."""
+    removed."""
     import math
 
     b, h, s, d = q.shape

@@ -29,9 +29,9 @@ Algorithm (1-d mesh of N participants, C = A·B):
 * Output C rows land row-sharded like A; each participant's tile is
   pulled per shard and stitched into the host result in block order.
 
-Staged bytes per participant ≈ (|A| + |B|) / N — the panel-staging
-proof ``micro_bench --summa`` measures against the replicated-operand
-baseline (every participant stages everything).
+Staged bytes per participant ≈ (|A| + |B|) / N, against the
+replicated-operand baseline in which every participant stages
+everything.
 
 Device-cache integration: staged A blocks ride the SAME block-granular
 :class:`~netsdb_tpu.storage.devcache.DeviceBlockCache` entries as every
@@ -163,8 +163,7 @@ def summa_matmul_streamed(store, name: str, rhs: np.ndarray,
     round program. ``cache``/``cache_scope`` opt the staged A blocks
     into the block-granular device cache (partial mode) under the
     SUMMA mesh label; ``stats_out`` (a dict) receives the run's
-    per-participant staged-byte table and round/broadcast counts —
-    the bench's panel-staging proof."""
+    per-participant staged-byte table and round/broadcast counts."""
     import contextlib
 
     import jax

@@ -33,7 +33,7 @@ _ids = itertools.count()
 #: (a) maps any row-slice to exactly the matching row-slice of the
 #: whole-input result and (b) preserves the chunk contract AND the
 #: schema surface (see the ``rowwise`` docstring below). The in-repo
-#: members are the bench/suite pre-chain transforms under the
+#: members are the suite's pre-chain transforms under the
 #: ``pre:`` namespace: affine per-row column maps (``pre:affine``),
 #: column projections/renames-free selections (``pre:project``) and
 #: per-row scaling (``pre:scale``).

@@ -73,8 +73,6 @@ def bucket_rows(n: int, density: int = 2) -> int:
       their shard count without a second padding round.
     * ``4``: 2^(k-1)·{1.25, 1.5, 1.75} plus 2^k — padding <25% worst
       case at twice the compile count (one XLA program per bucket).
-      ``micro_bench --bucket-sweep`` measures the pad-waste vs
-      trace-count trade per density (the ROADMAP ladder-tuning item).
 
     Every distinct row count inside a bucket's span compiles to the
     SAME XLA program either way."""
@@ -265,8 +263,7 @@ class StagedStream:
     """Iterator over ``place(item)`` for each item of ``source``, with
     ``place`` running up to ``depth`` items ahead on a background
     thread.  ``depth <= 0`` degenerates to the synchronous inline path
-    (the baseline the staging bench compares against — no thread, no
-    overlap, same results)."""
+    (no thread, no overlap, same results)."""
 
     def __init__(self, source: Iterable, place: Callable[[Any], Any],
                  depth: int = 2, name: str = "stage",

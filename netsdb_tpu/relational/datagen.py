@@ -1,40 +1,23 @@
-"""TPC-H device benchmark: columnar queries at dbgen-like scale.
-
-The reference's only published end-to-end numbers are TPC-H query
-times on its CPU cluster (SURVEY.md §6 / BASELINE.md: Q01 13.4-17.9 s,
-Q02 77-94 s, Q04 188-210 s, RUN_STAT traces in
-``/root/reference/model-inference/../gen_trace.sql``). This module
-generates SF-scaled columnar tables directly (dbgen row counts:
-lineitem ≈ 6M·SF, orders = 1.5M·SF, customer = 150k·SF, part = 200k·SF)
-and times the jitted columnar queries on the attached device.
-
-Timing protocol: each query's result pull is the sync; the round trip
-of a trivial jitted dispatch (``_rtt``) is subtracted from the wall.
-"""
+"""dbgen-shaped TPC-H tables at a scale factor, generated directly as
+columns (lineitem ≈ 6M·SF, orders = 1.5M·SF, customer = 150k·SF, part =
+200k·SF)."""
 
 from __future__ import annotations
 
-import time
-from typing import Dict
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from netsdb_tpu.relational.queries import COLUMNAR_QUERIES, Tables
+from netsdb_tpu.relational.queries import Tables
 from netsdb_tpu.relational.table import ColumnTable
-
-# reference-published wall times (seconds) — BASELINE.md §6
-PUBLISHED = {"q01": 13.4, "q02": 77.4, "q04": 188.5}
 
 
 def generate_columnar(sf: float = 0.1, seed: int = 0) -> Tables:
     """dbgen-shaped synthetic tables, built directly as columns (no row
-    dicts — row generation at SF≥0.1 would dominate the benchmark).
+    dicts — row generation at SF≥0.1 would dominate a run).
     Distributions follow dbgen's ranges; string domains are the real
     TPC-H enumerations, dictionary-encoded. Covers all eight tables so
     every columnar query (incl. Q02's five-way join and Q22's
-    anti-join) benches at dbgen scale: supplier 10k·SF, partsupp =
+    anti-join) runs at dbgen scale: supplier 10k·SF, partsupp =
     4 suppliers per part, nation 25, region 5."""
     rng = np.random.default_rng(seed)
     n_li = int(6_000_000 * sf)
@@ -154,88 +137,3 @@ def generate_columnar(sf: float = 0.1, seed: int = 0) -> Tables:
     for t in tables.values():
         t.cols = {k: jnp.asarray(v) for k, v in t.cols.items()}
     return tables
-
-
-def _rtt() -> float:
-    g = jax.jit(lambda v: v + 1)
-    float(g(jnp.float32(0)))
-    t0 = time.perf_counter()
-    for _ in range(5):
-        float(g(jnp.float32(0)))
-    return (time.perf_counter() - t0) / 5
-
-
-def bench_queries(tables: Tables,
-                  names=("q01", "q02", "q03", "q04", "q06", "q12", "q13",
-                         "q14", "q17", "q22"),
-                  iters: int = 10) -> Dict[str, Dict[str, float]]:
-    """Steady-state per-query seconds (compile excluded — the compiled-
-    plan cache is the reference's PreCompiledWorkload, so steady state
-    is the honest comparison; compile time is reported separately)."""
-    out: Dict[str, Dict[str, float]] = {}
-    rtt = _rtt()
-    n_li = tables["lineitem"].num_rows
-    for name in names:
-        fn = COLUMNAR_QUERIES[name]
-        t0 = time.perf_counter()
-        fn(tables)  # compile + first run (result pull syncs)
-        first = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            fn(tables)
-        wall = (time.perf_counter() - t0) / iters
-        dev = wall - rtt
-        entry = {"seconds_wall": wall, "first_run_seconds": first,
-                 "controller_rtt": rtt,
-                 "lineitem_rows_per_sec": n_li / wall}
-        if dev > 0.2 * rtt:
-            entry["seconds_device"] = dev
-        else:
-            # query finishes inside controller-RTT noise; wall time is
-            # an upper bound and the device time is unresolvable
-            entry["seconds_device_below_rtt"] = True
-        out[name] = entry
-    return out
-
-
-def bench_suite(tables: Tables, iters: int = 10) -> Dict[str, float]:
-    """The whole ten-query suite as ONE fused jitted program (see
-    queries.compile_suite): wall seconds for all ten queries per call,
-    one controller round-trip total."""
-    from netsdb_tpu.relational.queries import compile_suite
-
-    suite = compile_suite(tables)
-
-    def sync(out):
-        leaves = jax.tree_util.tree_leaves(out)
-        return float(jnp.sum(leaves[-1].astype(jnp.float32)))
-
-    t0 = time.perf_counter()
-    sync(suite())  # compile + first run
-    first = time.perf_counter() - t0
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        sync(suite())
-        times.append(time.perf_counter() - t0)
-    wall = sorted(times)[len(times) // 2]
-    return {"all_ten_queries_wall_seconds": wall,
-            "first_run_seconds": first}
-
-
-def main(sf: float = 0.1, iters: int = 10):
-    tables = generate_columnar(sf)
-    res = bench_queries(tables, iters=iters)
-    res["suite_fused"] = bench_suite(tables, iters=iters)
-    # published-baseline comparison only at SF 1: the reference's scale
-    # factor is unrecorded, and dividing its full-scale wall time by a
-    # smaller run's would inflate the ratio by the scale difference
-    if sf >= 1.0:
-        for name, secs in PUBLISHED.items():
-            if name in res:
-                res[name]["published_baseline_seconds"] = secs
-                res[name]["speedup_vs_published"] = \
-                    secs / res[name]["seconds_wall"]
-    return {"scale_factor": sf,
-            "lineitem_rows": tables["lineitem"].num_rows,
-            "queries": res}

@@ -79,8 +79,7 @@ class PagedColumns:
         self.stats = stats or {}
         # device-cache binding (storage/devcache.py), set by
         # ``SetStore._bind_cache`` for store-owned relations only —
-        # grace-hash spill partitions and bench temporaries stay
-        # uncached. ``_mutations`` is this handle's own append/drop
+        # grace-hash spill partitions stay uncached. ``_mutations`` is this handle's own append/drop
         # counter: it rides every cache key so even direct
         # ``pc.append`` callers (bypassing the store's version bump)
         # can never leave a stale cached run matchable.
@@ -722,7 +721,7 @@ def partition_by_key(pc: PagedColumns, key: str, nparts: int,
 def run_fold(fold, pc: PagedColumns, *resident, placement=None):
     """Thin standalone driver for a FoldSpec over one paged relation —
     delegates to the SAME loop the plan executor runs for paged
-    ScanSets, exposed for direct/bench use without a Client. One jit
+    ScanSets, exposed for direct use without a Client. One jit
     per pass per call; call-site loops should go through the executor,
     whose compiled-step cache amortizes across jobs."""
     from netsdb_tpu.plan.executor import _run_fold_once
@@ -837,7 +836,7 @@ def ooc_q03(pc: PagedColumns, store: PagedTensorStore,
     Thin wrapper: each LUT block becomes a build-side ColumnTable
     (non-qualifying keys → -1, dropped by the orphan-key rule) and the
     grace-hash loop runs the SAME fold + merge the set-API DAG uses for
-    a paged build side (``relational.dag.q03_probe_fold``). This bench
+    a paged build side (``relational.dag.q03_probe_fold``). This
     driver keeps the LEGACY per-block discipline (full probe re-stream
     per LUT block — its build lives in a raw block store, not a
     relation); the canonical ONE-PASS grace hash is the set-API path
@@ -877,186 +876,3 @@ Q06_COLUMNS = ["l_shipdate", "l_discount", "l_quantity",
                "l_extendedprice"]
 Q03_COLUMNS = ["l_orderkey", "l_shipdate", "l_extendedprice",
                "l_discount"]
-
-
-def bench_paged_set_api(rows: int = 60_000_000,
-                        pool_bytes: int = 1 << 30,
-                        page_bytes: int = 1 << 20,
-                        seed: int = 0) -> Dict[str, object]:
-    """The SET-API paged path at SF10 scale (60M-row lineitem ≈ SF10's
-    59.99M) on the real chip — round-5 item 5: the same
-    ``suite_sink_for``/grace-hash DAGs the tests verify at KB scale,
-    measured at larger-than-pool scale through ``create_set(storage=
-    "paged")`` + ``send_table``, never the thin ``ooc_*`` drivers.
-
-    Measures: q01 through ``suite_sink_for`` (fold streamed over the
-    arena), q03 through ``q03_build_sink`` (paged build set) +
-    ``q03_probe_sink`` (ONE-PASS grace hash, probe-pass ratio
-    asserted), with arena spills recorded."""
-    import shutil
-    import tempfile
-    import time
-
-    from netsdb_tpu.client import Client
-    from netsdb_tpu.config import Configuration
-    from netsdb_tpu.relational import dag as rdag
-    from netsdb_tpu.storage.store import SetIdentifier
-
-    rng = np.random.default_rng(seed)
-    n_orders = max(rows // 4, 1)
-    n_cust = max(n_orders // 10, 1)
-    li = {
-        "l_orderkey": rng.integers(0, n_orders, rows, dtype=np.int32),
-        "l_shipdate": rng.integers(19920101, 19981231, rows,
-                                   dtype=np.int32),
-        "l_returnflag": rng.integers(0, 3, rows, dtype=np.int32),
-        "l_linestatus": rng.integers(0, 2, rows, dtype=np.int32),
-        "l_quantity": rng.integers(1, 51, rows,
-                                   dtype=np.int32).astype(np.float32),
-        "l_extendedprice": rng.uniform(1000, 100000,
-                                       rows).astype(np.float32),
-        "l_discount": rng.uniform(0, 0.1, rows).astype(np.float32),
-        "l_tax": rng.uniform(0, 0.08, rows).astype(np.float32),
-    }
-    orders = {
-        "o_orderkey": np.arange(n_orders, dtype=np.int32),
-        "o_custkey": rng.integers(0, n_cust, n_orders, dtype=np.int32),
-        "o_orderdate": rng.integers(19920101, 19981231, n_orders,
-                                    dtype=np.int32),
-        "o_shippriority": np.zeros(n_orders, np.int32),
-    }
-    cust = {
-        "c_custkey": np.arange(n_cust, dtype=np.int32),
-        "c_mktsegment": rng.integers(0, 5, n_cust, dtype=np.int32),
-    }
-    table_bytes = sum(c.nbytes for c in li.values())
-    root = tempfile.mkdtemp(prefix="paged_api_bench_")
-    out: Dict[str, object] = {
-        "rows": rows, "table_bytes": table_bytes,
-        "pool_bytes": pool_bytes,
-        "pool_fraction": round(pool_bytes / table_bytes, 3)}
-    try:
-        c = Client(Configuration(root_dir=root,
-                                 page_size_bytes=page_bytes,
-                                 page_pool_bytes=pool_bytes))
-        c.create_database("d")
-        for name, cols, dicts in (
-                ("lineitem", li, {"l_returnflag": ["A", "N", "R"],
-                                  "l_linestatus": ["F", "O"]}),
-                ("orders", orders, None),
-                ("customer", cust,
-                 {"c_mktsegment": ["AUTOMOBILE", "BUILDING",
-                                   "FURNITURE", "HOUSEHOLD",
-                                   "MACHINERY"]})):
-            c.create_set("d", name, type_name="table",
-                         storage="paged" if name != "customer"
-                         else "memory")
-            t0 = time.perf_counter()
-            c.send_table("d", name, ColumnTable(cols, dicts or {}))
-            out[f"ingest_{name}_s"] = round(time.perf_counter() - t0, 2)
-        del li, orders  # free the host copies; the arena owns the data
-
-        t0 = time.perf_counter()
-        q01 = rdag.run_query(c, rdag.q01_sink("d"))
-        out["q01_s"] = round(time.perf_counter() - t0, 2)
-        out["q01_groups"] = int(np.asarray(q01.mask()).sum())
-
-        cinfo = c.analyze_set("d", "customer")
-        seg = cinfo["dicts"]["c_mktsegment"].index("BUILDING")
-        c.create_set("d", "q03_build", type_name="table",
-                     storage="paged")
-        t0 = time.perf_counter()
-        c.execute_computations(rdag.q03_build_sink(
-            "d", n_customers=n_cust, segment_code=seg))
-        out["q03_build_s"] = round(time.perf_counter() - t0, 2)
-        li_pc = c.store.get_items(SetIdentifier("d", "lineitem"))[0]
-        before = li_pc.pages_streamed
-        t0 = time.perf_counter()
-        q03 = rdag.run_query(c, rdag.q03_probe_sink(
-            "d", n_orders=n_orders))
-        out["q03_probe_s"] = round(time.perf_counter() - t0, 2)
-        out["q03_rows"] = len(rdag.q03_rows(q03))
-        out["probe_passes"] = round(
-            (li_pc.pages_streamed - before) / max(li_pc.num_pages(), 1),
-            2)
-        bpc = c.store.get_items(SetIdentifier("d", "q03_build"))[0]
-        out["build_pages"] = bpc.num_pages()
-        out["store_stats"] = c.store.page_store().stats()
-        out["native"] = c.store.page_store().native
-    finally:
-        shutil.rmtree(root, ignore_errors=True)
-    return out
-
-
-def bench_out_of_core(rows: int = 60_000_000,
-                      pool_bytes: int = 1 << 30,
-                      row_block: Optional[int] = None,
-                      seed: int = 0) -> Dict[str, object]:
-    """SF10-scale synthetic lineitem (60M rows ≈ SF10's 59.99M) through
-    q01+q06 under a pool cap far smaller than the table — the
-    PageScanner larger-than-memory proof, measured. Verifies against an
-    in-memory numpy oracle on the same data."""
-    import time
-
-    from netsdb_tpu.config import Configuration
-
-    rng = np.random.default_rng(seed)
-    cols = {
-        "l_shipdate": rng.integers(19920101, 19981231, rows,
-                                   dtype=np.int32),
-        "l_returnflag": rng.integers(0, 3, rows, dtype=np.int32),
-        "l_linestatus": rng.integers(0, 2, rows, dtype=np.int32),
-        "l_quantity": rng.integers(1, 51, rows,
-                                   dtype=np.int32).astype(np.float32),
-        "l_extendedprice": rng.uniform(1000, 100000,
-                                       rows).astype(np.float32),
-        "l_discount": rng.uniform(0, 0.1, rows).astype(np.float32),
-        "l_tax": rng.uniform(0, 0.08, rows).astype(np.float32),
-    }
-    table_bytes = sum(c.nbytes for c in cols.values())
-    import tempfile
-
-    cfg = Configuration(root_dir=tempfile.mkdtemp(prefix="ooc_bench_"))
-    store = PagedTensorStore(cfg, pool_bytes=pool_bytes)
-    if row_block is None:
-        # one page must be far smaller than the pool or ingest cannot
-        # even allocate (several pages stay pinned concurrently): cap a
-        # page at pool/8, floor at 4k rows
-        width = len(cols)
-        row_block = max(min(cfg.page_size_bytes // (4 * width),
-                            pool_bytes // (8 * 4 * width)), 4096)
-    t0 = time.perf_counter()
-    pc = PagedColumns.ingest(store, "lineitem", cols, row_block=row_block,
-                             dicts={"l_returnflag": ["A", "N", "R"],
-                                    "l_linestatus": ["F", "O"]})
-    ingest_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    r01 = ooc_q01(pc)
-    q01_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    r06 = ooc_q06(pc)
-    q06_s = time.perf_counter() - t0
-
-    # spot-verify q06 against a numpy oracle on the same host columns
-    a, b = date_to_int("1994-01-01"), date_to_int("1995-01-01")
-    m = ((cols["l_shipdate"] >= a) & (cols["l_shipdate"] < b)
-         & (cols["l_discount"] >= 0.06 - 0.011)
-         & (cols["l_discount"] <= 0.06 + 0.011)
-         & (cols["l_quantity"] < 24))
-    oracle = float((cols["l_extendedprice"][m]
-                    * cols["l_discount"][m]).sum(dtype=np.float64))
-    rel_err = abs(r06[0][1] - oracle) / max(abs(oracle), 1e-9)
-
-    out = {"rows": rows, "table_bytes": table_bytes,
-           "pool_bytes": pool_bytes,
-           "pool_fraction": round(pool_bytes / table_bytes, 3),
-           "ingest_s": round(ingest_s, 2),
-           "q01_s": round(q01_s, 2), "q06_s": round(q06_s, 2),
-           "q01_groups": len(r01), "q06_rel_err": rel_err,
-           "store_stats": store.stats(), "native": store.native}
-    store.close()
-    import shutil
-
-    shutil.rmtree(cfg.root_dir, ignore_errors=True)  # spilled pages
-    return out

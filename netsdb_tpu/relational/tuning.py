@@ -136,7 +136,7 @@ def _scan_time(step_fn, lo: int = 8, hi: int = 64) -> Optional[float]:
 
     # autotune sweeps dozens of (strategy, size) points and each
     # escalation recompiles two loop lengths — cap the retries and
-    # accept a coarser delta than the benches use. Per-dispatch walls
+    # accept a coarser delta than the default. Per-dispatch walls
     # are not used: sub-millisecond kernels drown in dispatch overhead.
     return device_seconds(lambda n: float(loop(n)), lo=lo, hi=hi,
                           repeats=2, max_escalations=2,

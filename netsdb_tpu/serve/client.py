@@ -1324,8 +1324,7 @@ class RemoteClient:
                   chunk_bytes: Optional[int] = None) -> None:
         """Object ingest. Large batches stream as bounded chunks under
         the depth-W windowed-ack pipeline (``pipeline=None`` decides by
-        item count; force ``True``/``False`` to pin a path — the bench
-        pins both to record the streamed-vs-monolithic win).
+        item count; force ``True``/``False`` to pin a path).
 
         A set the cached placement map shows as PARTITIONED routes
         instead: items split across the owning shards (hash or range,

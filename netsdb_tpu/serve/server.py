@@ -2307,8 +2307,7 @@ class ServeController:
         starts heartbeating it immediately; the rebalancer treats the
         growth as a forced trigger — when ``config.rebalance`` is on,
         a move round runs synchronously and the reply carries its
-        results, so callers (tests, the CLI, the bench's mid-run
-        registration) observe the pool absorb the member.
+        results, so callers observe the pool absorb the member.
         ``campaign=False`` registers only, leaving the move decision
         to a later pass (the advisor's measured commit-or-revert)."""
         addr = str(addr)

@@ -292,8 +292,7 @@ class DeviceBlockCache:
         return self._budget
 
     def resize(self, budget_bytes: int) -> None:
-        """Re-point the budget (the serve knob path / the bench's
-        cache-off baseline). Shrinking evicts immediately."""
+        """Re-point the budget. Shrinking evicts immediately."""
         with self._mu:
             self._budget = int(budget_bytes or 0)
             if self._budget < self._pinned_bytes:

@@ -609,8 +609,7 @@ class PagedTensorStore:
         the row-block's shape bucket (zero rows, output rows sliced
         back off — exact) so the whole stream runs ONE compiled
         program. ``stage_depth`` pins the staging depth (None = the
-        ``config.stage_depth`` knob; 0 = the synchronous baseline the
-        staging bench measures against).
+        ``config.stage_depth`` knob; 0 = synchronous).
 
         With ``config.distributed_matmul`` on and >1 device visible,
         the stream routes through the SUMMA engine instead

@@ -296,8 +296,8 @@ class SetStore:
     def _bind_cache(self, pc, ident: SetIdentifier) -> None:
         """Attach the device cache to a store-owned paged relation
         handle so its streams consult/install cached runs. Direct
-        ``PagedColumns.ingest`` callers (grace-hash spill partitions,
-        benches) never get a binding — temporaries stay uncached."""
+        ``PagedColumns.ingest`` callers (grace-hash spill partitions)
+        never get a binding — temporaries stay uncached."""
         pc.devcache = self.device_cache()
         pc.cache_scope = str(ident)
         pc.cache_version_fn = functools.partial(self.version_of, ident)

@@ -29,8 +29,7 @@ acquisition pays one module-global read and an ``is None`` check on
 top of the raw ``threading`` primitive — nothing allocates.  Enabled
 (``config.lock_witness``, or the test suite's conftest), each
 acquisition walks the thread's held stack (depth ≤ 3 in practice) and
-consults the edge set; ``micro_bench --lint-overhead`` pins the
-enabled cost < 2% on the staged fold stream.
+consults the edge set.
 
 Findings export through the obs registry: ``analysis.lock_edges``
 (gauge: distinct rank edges observed) and ``analysis.violations``
@@ -81,8 +80,7 @@ class LockWitness:
         #: new readers), counted but not violations: lockdep's
         #: recursive-read exemption
         self.read_cycles_suppressed = 0
-        #: total tracked acquisitions observed (unsynchronized tally —
-        #: the lint-overhead bench's deterministic-bound multiplier)
+        #: total tracked acquisitions observed (unsynchronized tally)
         self.acquisitions = 0
 
     # --- per-thread held stack ---------------------------------------

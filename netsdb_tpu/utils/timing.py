@@ -8,9 +8,9 @@ iteration's full output (so XLA can neither hoist the body nor
 slice-push it down to a single element), and the fixed dispatch+sync
 overhead cancels in the subtraction.
 
-Used by ``bench.py`` (flagship FF metric) and
-``netsdb_tpu/workloads/conv_bench.py`` — one implementation so the
-protocol cannot diverge between benchmarks.
+Used by the autotuner (``relational/tuning.py``) and
+``workloads/la_tasks.py`` — one implementation so the protocol cannot
+diverge between them.
 """
 
 from __future__ import annotations

@@ -173,8 +173,3 @@ def run_task(task: str, rows: int = 200000, cols: int = 1000,
         out["exec_s_device"] = round(dev_s, 6)
         out["speedup_vs_ref_best_device"] = round(ref["best"] / dev_s, 1)
     return out
-
-
-def run_all(rows: int = 200000, cols: int = 1000, block: int = 1000,
-            iters: int = 5) -> Dict[str, Dict[str, object]]:
-    return {t: run_task(t, rows, cols, block, iters) for t in TASKS}

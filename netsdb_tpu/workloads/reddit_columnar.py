@@ -324,49 +324,3 @@ def label_partition_counts(comments_t: ColumnTable,
     seg = (comments_t["label"] * num_parts
            + comments_t["index"] % num_parts)
     return K.segment_count(seg, 2 * num_parts).reshape(2, num_parts)
-
-
-# ----------------------------------------------------------- bench
-def bench_label_propagation(rows: int = 1_000_000,
-                            n_authors: int = 50_000,
-                            seed: int = 0) -> Dict[str, object]:
-    """≥1M comments through label propagation + the per-author
-    group-by + the 2×11 partition grid, device-timed (scan-slope)."""
-    from netsdb_tpu.utils.timing import scan_slope_seconds
-
-    rng = np.random.default_rng(seed)
-    t = ColumnTable({
-        "index": jnp.asarray(np.arange(rows, dtype=np.int32)),
-        "author_id": jnp.asarray(
-            rng.integers(0, n_authors, rows).astype(np.int32)),
-        "label": jnp.asarray(
-            (rng.random(rows) < 0.01).astype(np.int32)),
-    })
-
-    @functools.partial(jax.jit, static_argnums=(3, 4, 5))
-    def loop(author_id, label, index, n_auth, parts, n):
-        def step(carry, _):
-            aid = (author_id + carry) % n_auth  # carry-coupled: no hoist
-            prop = _propagate_core(n_auth, aid, label)
-            counts = K.segment_count(aid, n_auth)
-            seg = label * parts + index % parts
-            grid = K.segment_count(seg, 2 * parts)
-            # carry keeps a live (non-constant) data dependency so XLA
-            # can neither hoist the body nor dead-code-eliminate it
-            return (prop.sum() + counts.max() + grid.sum()) % 127, None
-
-        c, _ = jax.lax.scan(step, jnp.zeros((), jnp.int32), None,
-                            length=n)
-        return c
-
-    res = scan_slope_seconds(
-        lambda n: float(loop(t["author_id"], t["label"], t["index"],
-                             n_authors, 11, n)), lo=2, hi=8)
-    dt = res["seconds_per_iter"]
-    if dt is None:  # below device timing noise (tiny smoke shapes)
-        return {"rows": rows, "n_authors": n_authors,
-                "device_ms": 0.0, "rows_per_sec": float("inf"),
-                "below_noise": True}
-    return {"rows": rows, "n_authors": n_authors,
-            "device_ms": round(dt * 1e3, 3),
-            "rows_per_sec": round(rows / dt, 1)}

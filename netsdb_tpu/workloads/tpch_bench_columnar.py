@@ -172,48 +172,6 @@ def top_jaccard(tables: Dict[str, ColumnTable],
     return [(float(s), int(i)) for s, i in out]
 
 
-# ----------------------------------------------------------- bench
-def bench_tpch_bench(n_customers: int = 100_000, max_orders: int = 4,
-                     max_items: int = 5, n_parts: int = 2048,
-                     n_suppliers: int = 64, k: int = 10,
-                     seed: int = 0) -> Dict[str, object]:
-    """Device-timed columnar run of the family at a scale the
-    host-object path cannot touch (~1M triples)."""
-    from netsdb_tpu.utils.timing import scan_slope_seconds
-
-    rng = np.random.default_rng(seed)
-    n_rows = n_customers * ((max_orders + 1) // 2) * ((max_items + 1) // 2)
-    ck = np.repeat(np.arange(n_customers, dtype=np.int32),
-                   n_rows // n_customers)
-    triples = ColumnTable({
-        "custKey": jnp.asarray(ck),
-        "supplier": jnp.asarray(rng.integers(0, n_suppliers,
-                                             len(ck)).astype(np.int32)),
-        "partKey": jnp.asarray(rng.integers(0, n_parts,
-                                            len(ck)).astype(np.int32)),
-    }, dicts={"supplier": [f"Supplier{i}" for i in range(n_suppliers)]})
-    member = _membership_matrix(n_customers, n_parts,
-                                triples["custKey"], triples["partKey"])
-    q = jnp.asarray((rng.random(n_parts) < 0.05).astype(np.float32))
-
-    @functools.partial(jax.jit, static_argnums=(2,))
-    def loop(member, q, n):
-        def step(carry, _):
-            vals, idx = _jaccard_core(member + carry, q, k)
-            return vals.sum() * 1e-9, None
-
-        c, _ = jax.lax.scan(step, jnp.zeros(()), None, length=n)
-        return c
-
-    res = scan_slope_seconds(lambda n: float(loop(member, q, n)),
-                             lo=2, hi=8)
-    dt = res["seconds_per_iter"]
-    return {"triples": int(len(ck)), "customers": n_customers,
-            "parts": n_parts,
-            "jaccard_ms": None if dt is None else round(dt * 1e3, 3),
-            "below_noise": dt is None}
-
-
 def queries_on_sets(client, db: str = "tpchbench", threshold: int = 0,
                     segment: str = "BUILDING",
                     query_parts: Sequence[int] = (0,), k: int = 5):

@@ -131,19 +131,6 @@ def test_bucket_density_four_ladder():
     assert staging.pad_rows_target(129, True, density=2) == 192
 
 
-def test_bucket_sweep_reports_tradeoff():
-    from netsdb_tpu.workloads.micro_bench import bench_bucket_sweep
-
-    out = bench_bucket_sweep(base=400, spread=0.5, samples=10)
-    for d in (2, 4):
-        r = out[f"density{d}"]
-        assert r["traces"] == r["buckets"]  # one compile per bucket
-    # the denser ladder trades compiles for pad: never MORE pad waste
-    assert (out["density4"]["pad_waste_pct"]
-            <= out["density2"]["pad_waste_pct"])
-    assert out["density4"]["buckets"] >= out["density2"]["buckets"]
-
-
 # ------------------------------------------------- warm path, local client
 def test_warm_query_miss_counter_flat_and_exact(config):
     c = Client(config)
@@ -549,16 +536,3 @@ def test_paged_matrix_resyncs_page_by_page(tmp_path):
     finally:
         leader.shutdown()
         follower.shutdown()
-
-
-# --------------------------------------------------------- bench smoke
-def test_device_cache_bench_smoke():
-    from netsdb_tpu.workloads.serve_bench import run_device_cache_bench
-
-    out = run_device_cache_bench(rows=20_000, page_rows=2048, pool_mb=1,
-                                 repeats=1, cache_mb=64)
-    for key in ("cold_first_s", "uncached_steady_s", "warm_s",
-                "speedup_warm_vs_uncached", "warm_misses_flat"):
-        assert key in out
-    assert out["warm_misses_flat"] is True
-    assert out["cache_stats"]["hits"] > 0

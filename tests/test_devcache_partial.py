@@ -472,9 +472,9 @@ def test_handoff_drain_lands_as_tail_range_on_shard(tmp_path):
     batch applies as an APPEND — its pre-buffered cached blocks stay
     resident (dirty-range coherence across the pool)."""
     from tests.test_scaleout import _load_q01, pool
-    from netsdb_tpu.workloads.serve_bench import (_scale_rows,
-                                                  scaleout_q01_sink,
-                                                  scaleout_table)
+    from netsdb_tpu.workloads.scaleout import (scale_rows,
+                                               scaleout_q01_sink,
+                                               scaleout_table)
 
     with pool(tmp_path, n_workers=2,
               leader_kwargs={"heartbeat_interval_s": 60.0},
@@ -489,7 +489,7 @@ def test_handoff_drain_lands_as_tail_range_on_shard(tmp_path):
         sink = scaleout_q01_sink("d")
         c.execute_computations(sink, job_name="warm1",
                                fetch_results=False)
-        want = _scale_rows(c, "d", "scale_q01_out")
+        want = scale_rows(c, "d", "scale_q01_out")
         w0 = workers[0]
         w0_addr = f"127.0.0.1:{w0.port}"
         w0_cache = w0.library.store.device_cache()
@@ -517,7 +517,7 @@ def test_handoff_drain_lands_as_tail_range_on_shard(tmp_path):
         # post-drain scatter query equals a fresh full computation
         c.execute_computations(sink, job_name="warm2",
                                fetch_results=False)
-        got = _scale_rows(c, "d", "scale_q01_out")
+        got = scale_rows(c, "d", "scale_q01_out")
         assert got != want  # the append changed the answer
         assert w0_cache.stats()["partial_hits"] > 0
         c.close()
@@ -529,8 +529,8 @@ def test_scatter_4daemon_partial_cache_byte_equal(tmp_path):
     single-node run; every shard serves its second run from resident
     blocks."""
     from tests.test_scaleout import _load_q01, pool, solo
-    from netsdb_tpu.workloads.serve_bench import (_scale_rows,
-                                                  scaleout_q01_sink)
+    from netsdb_tpu.workloads.scaleout import (scale_rows,
+                                               scaleout_q01_sink)
 
     storage = {"page_size_bytes": 64 * 1024}
     with pool(tmp_path, n_workers=3, storage_kwargs=storage) \
@@ -540,10 +540,10 @@ def test_scatter_4daemon_partial_cache_byte_equal(tmp_path):
         sink = scaleout_q01_sink("d")
         c.execute_computations(sink, job_name="cold",
                                fetch_results=False)
-        cold = _scale_rows(c, "d", "scale_q01_out")
+        cold = scale_rows(c, "d", "scale_q01_out")
         c.execute_computations(sink, job_name="warm",
                                fetch_results=False)
-        warm = _scale_rows(c, "d", "scale_q01_out")
+        warm = scale_rows(c, "d", "scale_q01_out")
         hits = sum(d.library.store.device_cache().stats()["hits"]
                    for d in [leader] + workers)
         assert hits >= 4  # every daemon's slot re-served resident
@@ -553,7 +553,7 @@ def test_scatter_4daemon_partial_cache_byte_equal(tmp_path):
         _load_q01(sc, rows=12000, sharded=False)
         sc.execute_computations(scaleout_q01_sink("d"),
                                 job_name="solo", fetch_results=False)
-        want = _scale_rows(sc, "d", "scale_q01_out")
+        want = scale_rows(sc, "d", "scale_q01_out")
         sc.close()
     assert cold == want and warm == want
 
@@ -738,23 +738,6 @@ def test_slo_shed_pinned_formula_and_recovery():
     snap = qs.lanes.snapshot()
     assert snap["shed_lanes"] == []
     assert snap["lane_quotas"]["heavy"] == 6
-
-
-# --------------------------------------------------------- bench smoke
-def test_partial_cache_bench_smoke():
-    from netsdb_tpu.workloads.serve_bench import run_partial_cache_bench
-
-    out = run_partial_cache_bench(rows=20_000, page_rows=2048,
-                                  pool_mb=1, cache_mb=64,
-                                  append_frac=0.05, cycles=1)
-    for key in ("devcache_partial_speedup", "partial", "whole_run",
-                "partial_zero_evictions", "partial_hits_positive"):
-        assert key in out
-    # the structural proof holds at any scale (the speedup itself is
-    # only meaningful at bench scale — not asserted here)
-    assert out["partial_zero_evictions"] is True
-    assert out["partial_hits_positive"] is True
-    assert out["partial"]["blocks_before_appends"] > 1
 
 
 def test_shed_floor_and_unbounded_lanes():

@@ -35,8 +35,8 @@ from netsdb_tpu.relational.table import ColumnTable
 from netsdb_tpu.serve.client import RemoteClient
 from netsdb_tpu.serve.server import ServeController
 from netsdb_tpu.storage.store import SetIdentifier
-from netsdb_tpu.workloads.serve_bench import (
-    _scale_rows,
+from netsdb_tpu.workloads.scaleout import (
+    scale_rows,
     scaleout_q01_sink,
     scaleout_table,
 )
@@ -108,7 +108,7 @@ def test_scatter_q01_one_program_per_shard_plus_one_merge(tmp_path):
         # 4 shard anchor regions + the coordinator merge region
         assert _counter("fusion.distributed_regions") - dr0 == 5
         assert _counter("fusion.fallbacks") - fb0 == 0
-        rows = _scale_rows(c, "d", "scale_q01_out")
+        rows = scale_rows(c, "d", "scale_q01_out")
         assert len(rows) == 6
         c.close()
 
@@ -128,7 +128,7 @@ def test_rollback_off_and_greedy_byte_equal_and_same_keys(tmp_path):
                                    job_name=f"rb-{tag}",
                                    fetch_results=False)
             new = set(executor.compiled_cache_keys()) - keys0
-            rows = _scale_rows(c, "d", "scale_q01_out")
+            rows = scale_rows(c, "d", "scale_q01_out")
             c.close()
             return rows, new
 
@@ -165,14 +165,14 @@ def test_multi_sink_fan_one_subplan_per_shard_byte_equal(tmp_path):
                 and "multi::" in k}, sorted(new)
         assert {k for k in new if k.startswith("region::fan::scatter::")
                 and "::merge::k4::" in k}, sorted(new)
-        fan = [_scale_rows(c, "d", f"fan_out_{i}")
+        fan = [scale_rows(c, "d", f"fan_out_{i}")
                for i in range(len(_CUTS))]
         for i, ct in enumerate(_CUTS):
             c.execute_computations(
                 scaleout_q01_sink("d", cutoff=ct,
                                   output_set=f"solo_out_{i}"),
                 job_name=f"fan-solo{i}", fetch_results=False)
-            assert fan[i] == _scale_rows(c, "d", f"solo_out_{i}")
+            assert fan[i] == scale_rows(c, "d", f"solo_out_{i}")
         c.close()
 
 

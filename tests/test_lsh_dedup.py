@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 
 from netsdb_tpu.core.blocked import BlockedTensor
-from netsdb_tpu.dedup.lsh import (LSHIndex, bench_lsh_zoo,
-                                  block_signatures, dedup_model_zoo)
+from netsdb_tpu.dedup.lsh import (LSHIndex, block_signatures,
+                                  dedup_model_zoo)
 
 
 def _tensor(arr, block=64):
@@ -55,12 +55,3 @@ def test_candidates_are_subquadratic():
     res = dedup_model_zoo(models)
     assert res["groups"] == []  # all-distinct zoo: nothing groups
     assert res["pair_work_fraction"] < 0.2  # and few pairs verified
-
-
-def test_bench_zoo_smoke():
-    res = bench_lsh_zoo(n_models=20, blocks_per_model=2, block=64,
-                        n_families=4)
-    assert res["groups_family_pure"]
-    # each (family, block position) unites its 5 variants
-    assert res["groups"] == 4 * 2
-    assert res["verified_pairs"] < res["all_pairs"]

@@ -5,7 +5,7 @@ per-shard ONE-program proof, and sharded ANALYZE_SET fan-out.
 The acceptance oracle throughout is the SINGLE-DEVICE ENGINE — a solo
 daemon running the same model on the same bytes — never a hand-rolled
 numpy reimplementation (the FF tail is a softmax; byte-equality must
-pin the engine against itself, exactly like ``serve_bench --scale``).
+pin the engine against itself, as ``tests/test_scaleout.py`` does).
 """
 
 import contextlib

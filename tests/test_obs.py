@@ -1,6 +1,6 @@
 """Unit tests for the observability subsystem (netsdb_tpu/obs/):
-registry instruments, bounded histograms, query traces + ring, the
-bounded StageTimer, and the obs-overhead micro-bench smoke.
+registry instruments, bounded histograms, query traces + ring, and the
+bounded StageTimer.
 
 The serve-side integration (GET_TRACE over the wire, COLLECT_STATS
 "metrics", leader/follower merge) lives in tests/test_obs_serve.py.
@@ -232,16 +232,3 @@ def test_staged_stream_reports_into_active_trace(tmp_path):
         assert prof["counters"]["stage.bytes"] > 0
     finally:
         store.close()
-
-
-def test_obs_overhead_bench_smoke():
-    from netsdb_tpu.workloads.micro_bench import bench_obs_overhead
-
-    out = bench_obs_overhead(rows=30_000, page_rows=4096, repeats=2)
-    assert out["untraced_s"] > 0
-    assert "overhead_pct" in out and "noise_pct" in out
-    assert out["chunks"] >= 2
-    assert out["trace_counters"]["stage.chunks"] == out["chunks"]
-    # the deterministic per-chunk accounting bound is what the < 3%
-    # budget is pinned on (the end-to-end A/B is scheduler-noisy)
-    assert out["accounting_overhead_pct"] < 3.0

@@ -303,3 +303,18 @@ class TestEmbedding:
         out_sum = np.asarray(
             emb_ops.embedding_lookup_sparse(w, ids, segs, 2, "sum"))[:, :5]
         np.testing.assert_allclose(out_sum[1], table[[3, 4, 5]].sum(0), rtol=1e-5)
+
+
+def test_direct_matches_torch():
+    import torch
+    import jax.numpy as jnp
+
+    from netsdb_tpu.ops.conv import conv2d_direct
+
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((2, 3, 12, 12)).astype(np.float32)
+    w = rng.standard_normal((5, 3, 3, 3)).astype(np.float32)
+    ours = np.asarray(conv2d_direct(jnp.asarray(x), jnp.asarray(w)))
+    with torch.no_grad():
+        ref = torch.conv2d(torch.from_numpy(x), torch.from_numpy(w)).numpy()
+    np.testing.assert_allclose(ours, ref, rtol=1e-4, atol=1e-4)

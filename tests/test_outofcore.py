@@ -84,13 +84,6 @@ def test_ooc_under_tiny_pool_spills(tables):
     store.close()
 
 
-def test_bench_out_of_core_smoke():
-    res = O.bench_out_of_core(rows=200_000, pool_bytes=1 << 22,
-                              row_block=16_384)
-    assert res["q01_groups"] > 0
-    assert res["q06_rel_err"] < 1e-4
-
-
 # ------------------------------------------------ out-of-core JOIN (r3)
 def test_ooc_q03_join_matches_in_memory(tables):
     """Streamed probe (lineitem pages) against a partitioned resident

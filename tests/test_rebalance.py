@@ -25,7 +25,7 @@ from netsdb_tpu.serve.client import (
     ShardUnavailableError,
 )
 from netsdb_tpu.serve.server import ServeController
-from netsdb_tpu.workloads.serve_bench import scaleout_table
+from netsdb_tpu.workloads.scaleout import scaleout_table
 
 from test_scaleout import _local_rows, pool
 

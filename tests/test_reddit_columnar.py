@@ -93,8 +93,3 @@ def test_sharded_three_way_matches_local(data, tables, force,
                       np.asarray(local["karma"])[lv].tolist(),
                       np.asarray(local["subscribers"])[lv].tolist()))
     assert got == want
-
-
-def test_bench_smoke():
-    res = RC.bench_label_propagation(rows=20_000, n_authors=500)
-    assert res["rows_per_sec"] > 0

@@ -4,8 +4,7 @@ epoch/handoff fault story (PR 13).
 
 In-process pools (a leader ServeController + N worker controllers on
 loopback, like the follower-concurrency tests) — correctness, not
-throughput; the paired throughput claim lives in
-``serve_bench --scale``.
+throughput.
 """
 
 import contextlib
@@ -34,8 +33,8 @@ from netsdb_tpu.serve.protocol import (
 )
 from netsdb_tpu.serve.server import ServeController
 from netsdb_tpu.storage.store import SetIdentifier
-from netsdb_tpu.workloads.serve_bench import (
-    _scale_rows,
+from netsdb_tpu.workloads.scaleout import (
+    scale_rows,
     scaleout_join_sink,
     scaleout_q01_sink,
     scaleout_table,
@@ -96,9 +95,7 @@ def _local_rows(ctl, db, set_name) -> int:
     return total
 
 
-# ONE byte-equality probe, shared with the bench — the oracle the
-# acceptance gate runs must be the oracle the tests pin
-_result_rows = _scale_rows
+_result_rows = scale_rows
 
 
 # --- placement map / routing units -----------------------------------

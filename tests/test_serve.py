@@ -285,21 +285,6 @@ def test_weights_stay_resident_across_sessions(server):
     c2.close()
 
 
-def test_two_process_integration(tmp_path):
-    """The VERDICT 'done' criterion in miniature: a real daemon process
-    and two real client processes running inference against weights
-    loaded once."""
-    from netsdb_tpu.workloads import serve_bench
-
-    out = serve_bench.run_serve_bench(
-        clients=2, jobs_per_client=2, batch=128, platform="cpu")
-    assert out["server_jobs_done"] >= 4  # 2 clients x 2 jobs (+ warmups)
-    assert out["aggregate_rows_per_sec"] > 0
-    assert len(out["per_client"]) == 2
-    for r in out["per_client"]:
-        assert r["jobs"] == 2
-
-
 def test_execute_plan_with_shipped_udf_source(tmp_path):
     """Code shipping on registerType (round-3 item 7): the plan's UDF
     module does NOT exist on the server's import path — its source

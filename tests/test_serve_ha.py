@@ -33,7 +33,7 @@ from netsdb_tpu.serve.protocol import (
 )
 from netsdb_tpu.serve.server import ServeController, _FollowerLink
 from netsdb_tpu.storage.store import SetIdentifier
-from netsdb_tpu.workloads.serve_bench import scaleout_table
+from netsdb_tpu.workloads.scaleout import scaleout_table
 
 pytestmark = pytest.mark.chaos
 

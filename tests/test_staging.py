@@ -283,20 +283,6 @@ def test_fold_donate_argnums_gating(config):
     assert staging.fold_donate_argnums(config) == ()
 
 
-# ------------------------------------------------------- bench smoke
-def test_bench_staging_smoke():
-    from netsdb_tpu.workloads.micro_bench import bench_staging
-
-    out = bench_staging(rows=2048, cols=64, rhs_cols=16, page_rows=256,
-                        pool_mb=4, fold_rows=20_000, repeats=1)
-    for key in ("matmul_speedup", "fold_speedup", "fold_sync_traces",
-                "fold_staged_traces"):
-        assert key in out
-    # buckets absorb the per-size shape churn the baseline pays
-    assert out["fold_staged_traces"] < out["fold_sync_traces"]
-    assert out["fold_staged_traces"] == 1
-
-
 # ------------------------------------------------- stream lock semantics
 def test_staged_stream_holds_read_lock_until_closed(store):
     pc, _ = _ingest(store, n=2048, row_block=64)

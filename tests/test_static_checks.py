@@ -48,7 +48,7 @@ def test_obs_layer_registry_discipline():
     _clean("module-dict-counter")
 
 
-def test_no_prints_outside_cli_and_workloads():
+def test_no_prints_outside_cli():
     _clean("print-ban")
 
 

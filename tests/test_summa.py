@@ -8,7 +8,7 @@ host-platform CPU devices):
   single-device blocked engine (integer-valued f32 operands make
   every summation order exact, so this is a true bit-for-bit gate);
 * **panel staging** — each participant stages ~1/N of the operand
-  bytes (the panel-staging proof the bench measures at scale);
+  bytes;
 * **knob routing** — ``config.distributed_matmul`` routes
   ``matmul_streamed`` (and ``ops.matmul``) through the engine, off
   keeps the single-device path byte-for-byte;

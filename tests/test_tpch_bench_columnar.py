@@ -70,9 +70,3 @@ def test_top_jaccard_matches_host(customers, tables):
     assert [ck for _, ck in got] == [ck for _, ck in want]
     for (gs, _), (ws, _) in zip(got, want):
         assert gs == pytest.approx(ws, rel=1e-5)
-
-
-def test_bench_smoke():
-    res = TC.bench_tpch_bench(n_customers=2_000, n_parts=256,
-                              n_suppliers=8)
-    assert res["triples"] > 0

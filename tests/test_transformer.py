@@ -76,20 +76,6 @@ def test_graft_entry_dryrun_all_sizes():
         g.dryrun_multichip(n)
 
 
-def test_transformer_bench_smoke():
-    from netsdb_tpu.workloads.transformer_bench import (
-        bench_transformer_layer, layer_flops)
-
-    # flops model sanity: attention halves under causal, MLP dominates
-    # at short seq
-    assert layer_flops(1, 128, 256, 4) > 0
-    assert layer_flops(1, 128, 256, 4, causal=True) < \
-        layer_flops(1, 128, 256, 4, causal=False)
-    res = bench_transformer_layer(seq_lens=(256,), batch=1, embed=128,
-                                  heads=4)
-    assert "seq_256" in res
-
-
 def test_transformer_sp_through_set_api(tmp_path):
     """Long-context through the database API (round 3): weights in
     replicated placed sets, activations sharded on the SEQUENCE axis,
