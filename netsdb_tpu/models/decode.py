@@ -368,6 +368,11 @@ class _HybridKind:
 
         return hybrid_lm.plan_chunks(spec, n_tokens)
 
+    def cache_rows_read(self, spec, lengths):
+        from netsdb_tpu.models import hybrid_lm
+
+        return hybrid_lm.cache_rows_read(spec, lengths)
+
 
 _KINDS = {k.name: k for k in (_LstmKind(), _TransformerKind(),
                               _HybridKind())}
@@ -715,6 +720,12 @@ class DecodeRuntime:
         """[(chunk length, tokens that count)] covering ``n_tokens``."""
         reg = self._reg(db)
         return reg["kind"].plan_prefill(reg["spec"], n_tokens)
+
+    def cache_rows_read(self, db: str, lengths) -> Tuple[int, int]:
+        """(rows fetched, rows held) of a language model's key/value
+        caches by one step whose live slots see ``lengths`` keys."""
+        reg = self._reg(db)
+        return reg["kind"].cache_rows_read(reg["spec"], lengths)
 
     def prefill(self, db: str, arrays, slot: int, tokens: np.ndarray,
                 n_valid: int, next_tok: int):

@@ -39,10 +39,11 @@ the lanes (``dv`` = 192 alone would pad to 256), a cache's two minor
 axes are tokens and ``head_dim``, and the three rows of a convolution
 window are not a minor axis: no array is padded. Each full layer's
 caches and each linear layer's states are arrays of their own: a
-step's attention takes the caches whole as its products' operands, and
-its delta-rule kernel writes the donated states in place. Cut out of
-one array with a layer axis they were copied, a gigabyte a layer a
-step.
+step's attention takes the caches as they stand (its kernel fetches of
+each slot's cache the blocks the slot's own length reaches; a cache
+whose shape the kernel does not take is read whole), and its
+delta-rule kernel writes the donated states in place. Cut out of one
+array with a layer axis they were copied, a gigabyte a layer a step.
 
 Two programs over that slab, both donating it:
 
@@ -66,7 +67,9 @@ from typing import Any, Dict, List, Tuple
 import numpy as np
 
 from netsdb_tpu import obs
-from netsdb_tpu.ops.attention import cache_write_rows, cached_attention
+from netsdb_tpu.ops.attention import (DECODE_BLOCK, cache_write_rows,
+                                      cached_attention, decode_attention,
+                                      decode_attention_fits)
 from netsdb_tpu.ops.delta_rule import (gated_delta_chunked,
                                        gated_delta_step_flat, heads_first,
                                        heads_on_lanes, step_kernel_fits)
@@ -74,7 +77,6 @@ from netsdb_tpu.ops.delta_rule import (gated_delta_chunked,
 KIND = "hybrid_lm"
 SPEC_SET = "spec"
 LINEAR, FULL = "linear_attention", "full_attention"
-ATTN_BLOCK = 256
 
 
 # --- the spec ---------------------------------------------------------
@@ -110,9 +112,32 @@ def make_spec(*, layer_types, hidden, intermediate, vocab, heads, head_dim,
 def cache_rows(spec) -> int:
     """Rows of a slot's key/value cache as allocated: the tokens it may
     hold plus one largest prefill chunk (a padded chunk is written whole
-    at ``pos``), rounded up to whole attention blocks."""
+    at ``pos``), rounded up to whole blocks of the decode step's
+    attention kernel."""
     rows = spec["cache_tokens"] + max(spec["prefill_chunks"])
-    return -(-rows // ATTN_BLOCK) * ATTN_BLOCK
+    return -(-rows // DECODE_BLOCK) * DECODE_BLOCK
+
+
+def _ragged(spec) -> bool:
+    """Whether the step's attention is the kernel that reads each
+    slot's cache up to its own length: the cache's shape decides."""
+    return decode_attention_fits(cache_rows(spec), spec["head_dim"],
+                                 spec["dtype"])
+
+
+def cache_rows_read(spec, lengths) -> Tuple[int, int]:
+    """(rows fetched, rows held) of the full layers' caches by ONE
+    decode step whose live slots see ``lengths`` keys each, in rows (a
+    token's keys and values of one layer): the kernel fetches a live
+    slot's length in whole blocks and one block of an idle slot, the
+    whole pass everything the slab holds."""
+    held = spec["slots"] * cache_rows(spec)
+    fetched = held
+    if _ragged(spec):
+        fetched = DECODE_BLOCK * (spec["slots"] - len(lengths) + sum(
+            -(-int(n) // DECODE_BLOCK) for n in lengths))
+    full = sum(t == FULL for t in spec["layer_types"])
+    return full * fetched, full * held
 
 
 def _conv_width(spec) -> int:
@@ -307,11 +332,14 @@ def build_step(spec):
     keep = spec["conv_k"] - 1
     fits = step_kernel_fits(spec["lin_dk"],
                             spec["lin_heads"] * spec["lin_dv"])
+    ragged = _ragged(spec)
 
     def hybrid_lm_step(p, slab, active):
         # runs when the program is traced, once a compiled program
         obs.REGISTRY.gauge("decode.gdn_step.fused_layers").set(
             sum(t == LINEAR for t in types) if fits else 0)
+        obs.REGISTRY.gauge("decode.attn.ragged_layers").set(
+            sum(t == FULL for t in types) if ragged else 0)
         slab = dict(slab)
         conv = slab["conv"]
         pos, tok = slab["pos"], slab["tok"]
@@ -348,8 +376,15 @@ def build_step(spec):
                 vc = cache_write_rows(slab[f"v{fi}"],
                                       v.reshape(-1, heads, hd), pos)
                 slab[f"k{fi}"], slab[f"v{fi}"] = kc, vc
-                o = cached_attention(q.reshape(-1, 1, heads, hd), kc, vc,
-                                     pos[:, None])
+                q = q.reshape(-1, 1, heads, hd)
+                if ragged:
+                    # each slot's cache is read up to the slot's own
+                    # length; an idle slot sees nothing (nobody reads
+                    # its row of o)
+                    o = decode_attention(q, kc, vc,
+                                         jnp.where(active, pos, -1))
+                else:
+                    o = cached_attention(q, kc, vc, pos[:, None])
                 mix = _dense(o.reshape(-1, heads * hd), p[pre + "wo"])
                 fi += 1
             h = x + _rms(mix, p[pre + "norm_mix"], eps)

@@ -306,6 +306,21 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("session.decode_tokens", "tokens generated by decode steps of "
                                   "language-model sessions (one per "
                                   "live session per step)"),
+        ("decode.attn.rows_fetched", "cache rows (a token's keys and "
+                                     "values of one layer) the decode "
+                                     "steps' attention fetched over "
+                                     "the full-attention layers: with "
+                                     "the kernel each live slot's "
+                                     "length in whole blocks of "
+                                     "DECODE_BLOCK and one block of an "
+                                     "idle slot, with the whole pass "
+                                     "all the slab holds; counted a "
+                                     "step on the host from the "
+                                     "scheduler's own lengths"),
+        ("decode.attn.rows_held", "cache rows the slab held for the "
+                                  "same steps and layers (slots x "
+                                  "rows a slot): what a whole pass "
+                                  "over every slot's cache reads"),
         ("session.slab.spills", "slots of a model's session slab "
                                 "copied to the host arena (lease "
                                 "evicted under pressure or expired)"),
@@ -355,6 +370,13 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                          "in-place kernel (0: the "
                                          "state's shape took the XLA "
                                          "form)"),
+        ("decode.attn.ragged_layers", "full-attention layers of the "
+                                      "last traced hybrid_lm step "
+                                      "program whose attention is the "
+                                      "kernel that reads each slot's "
+                                      "cache up to its own length (0: "
+                                      "the cache's shape took the "
+                                      "whole pass)"),
         ("dedup.page_bytes", "unique model weight-page bytes resident "
                              "after cross-model deduplication "
                              "(compare against the per-model "

@@ -91,10 +91,11 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      q_pos: jax.Array, block_size: Optional[int] = None,
                      scale: Optional[float] = None, row0=None) -> jax.Array:
-    """Attention of new queries over a per-row cache — the decode path
-    (one query a row) and the chunked-prefill path (one row, a chunk of
-    queries) of a model that keeps keys and values in slot-indexed
-    caches.
+    """Attention of new queries over a per-row cache — the
+    chunked-prefill path (one row, a chunk of queries) of a model that
+    keeps keys and values in slot-indexed caches, and its decode path
+    (one query a row) where the caches' shape does not fit
+    :func:`decode_attention`, which is tested against this.
 
     ``q`` (B, Lq, H, D). ``k_cache`` and ``v_cache`` are (rows, H, T,
     D): tokens and head_dim are the two minor axes, so a bfloat16 cache
@@ -206,6 +207,132 @@ def cache_write_rows(cache: jax.Array, new: jax.Array,
         input_output_aliases={2: 0}, name="cache_write_rows",
         interpret=pallas_interpret())(
             pos.astype(jnp.int32), new.astype(cache.dtype), cache)
+
+
+# tokens of a cache block the decode kernel fetches at a time: thirty
+# heads of (256, 128) bfloat16 are 1.97 MB, keys and values each
+# double-buffered 7.9 MB of a core's 16 MiB of scoped VMEM. On the chip
+# 128 and 512 rows were no faster (PERF.md section 6, PR 30)
+DECODE_BLOCK = 256
+LANES = 128
+
+
+def decode_attention_fits(t: int, d: int, dtype) -> bool:
+    """Whether :func:`decode_attention` takes caches of ``t`` tokens and
+    ``head_dim`` ``d``: whole blocks of ``DECODE_BLOCK`` tokens (so
+    whole (16, 128) tiles of a bfloat16 cache) and whole lanes."""
+    return (t % DECODE_BLOCK == 0 and d % LANES == 0
+            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     pos: jax.Array,
+                     scale: Optional[float] = None) -> jax.Array:
+    """The decode case of :func:`cached_attention` (one query a row,
+    every row a slot) as one Pallas kernel, ``decode_attention`` in a
+    device trace, that fetches of each row's cache only the blocks its
+    own length reaches. ``q`` (B, 1, H, D); the caches (B, H, T, D) as
+    they stand in the slab; ``pos`` (B,) int32: key ``j`` of row ``b``
+    is visible when ``j <= pos[b]``, and a row with ``pos[b] < 0`` sees
+    nothing and yields zeros. Returns float32 (B, 1, H, D): the
+    arithmetic of ``cached_attention(..., block_size=DECODE_BLOCK)``,
+    operands in the cache's dtype, every sum and the softmax float32.
+
+    The grid is (rows, T // DECODE_BLOCK) with ``pos`` prefetched. A
+    row that needs ``n`` of its ``N`` blocks spends its first ``N - n``
+    grid steps on block 0 with the body skipped and then walks blocks 0
+    to ``n - 1``: consecutive steps on one block fetch nothing, so what
+    lies past a row's length never leaves HBM, and the row's first block
+    is copied under the row before's last product. (Idle steps at a
+    row's end would leave that copy exposed, a block a row.) A row
+    that sees nothing still has its block 0 fetched, once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from netsdb_tpu.ops.common import pallas_interpret
+
+    b, _, h, d = q.shape
+    t = k_cache.shape[2]
+    dt = k_cache.dtype
+    block = DECODE_BLOCK
+    if not decode_attention_fits(t, d, dt):
+        raise ValueError(f"a {dt} cache of {t} tokens by {d} is not whole "
+                         f"blocks of ({block}, {LANES})")
+    n_all = t // block
+    rows = 32 // jnp.dtype(dt).itemsize      # a head's query, one tile
+    scale = scale if scale is not None else d ** -0.5
+    qs = (q.astype(jnp.float32) * scale).astype(dt).reshape(b, h, d)
+
+    def block_at(j, last):
+        """The block of grid step ``j`` of a row whose last visible key
+        is ``last``; below 0 while the row idles."""
+        used = (jnp.maximum(last, -1) + block) // block
+        return j - (n_all - used)
+
+    def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, num_ref, den_ref,
+               max_ref):
+        row, j = pl.program_id(0), pl.program_id(1)
+        last = pos_ref[row]
+        at = block_at(j, last)
+
+        @pl.when(j == 0)
+        def _():
+            num_ref[...] = jnp.zeros(num_ref.shape, jnp.float32)
+            den_ref[...] = jnp.zeros(den_ref.shape, jnp.float32)
+            max_ref[...] = jnp.full(max_ref.shape, NEG_INF, jnp.float32)
+
+        @pl.when(at >= 0)
+        def _():
+            # every head's query as the rows of one tile: the products
+            # are batched over the heads, (rows, D) x (block, D)^T
+            qr = jnp.broadcast_to(q_ref[0][:, None, :], (h, rows, d))
+            logits = jax.lax.dot_general(
+                qr, k_ref[0], (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)    # (H, rows, block)
+            k_pos = at * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, block), 2)
+            # the block's first key is visible, so the new max is finite
+            # and a masked key's weight is exactly 0
+            logits = jnp.where(k_pos <= last, logits, NEG_INF)
+            mx = max_ref[...]
+            new_max = jnp.maximum(mx, logits.max(-1, keepdims=True))
+            corr = jnp.exp(mx - new_max)
+            p = jnp.exp(logits - new_max)
+            den_ref[...] = den_ref[...] * corr + p.sum(-1, keepdims=True)
+            num_ref[...] = num_ref[...] * corr + jax.lax.dot_general(
+                p.astype(dt), v_ref[0], (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)    # (H, rows, D)
+            max_ref[...] = new_max
+
+        @pl.when(j == n_all - 1)
+        def _():
+            out = num_ref[...] / jnp.maximum(den_ref[...], 1e-30)
+            o_ref[0] = out.max(1)        # a head's rows are all the same
+
+    def cache_block(row, j, pos_ref):
+        return row, 0, jnp.maximum(block_at(j, pos_ref[row]), 0), 0
+
+    def whole_row(row, j, pos_ref):
+        return row, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, n_all),
+        in_specs=[pl.BlockSpec((1, h, d), whole_row),
+                  pl.BlockSpec((1, h, block, d), cache_block),
+                  pl.BlockSpec((1, h, block, d), cache_block)],
+        out_specs=pl.BlockSpec((1, h, d), whole_row),
+        scratch_shapes=[pltpu.VMEM((h, rows, d), jnp.float32),
+                        pltpu.VMEM((h, rows, 1), jnp.float32),
+                        pltpu.VMEM((h, rows, 1), jnp.float32)])
+    # keys and values, each double-buffered, and room for the body
+    vmem = 4 * h * block * d * jnp.dtype(dt).itemsize + (8 << 20)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        name="decode_attention", interpret=pallas_interpret())(
+            pos.astype(jnp.int32), qs, k_cache, v_cache)
+    return out[:, None]
 
 
 def split_qkv_heads(qkv: jax.Array, num_heads: int):

@@ -824,6 +824,13 @@ class SessionManager:
         with slab.mu:
             slab.arrays, outs = self.runtime.step(db, slab.arrays,
                                                   active, xs)
+        if xs is None:
+            # a live slot sees its history at the frame's start and what
+            # the frame has consumed and generated since
+            fetched, held = self.runtime.cache_rows_read(
+                db, [t["step"] + t["advance"] - t["n"] for t in ready])
+            obs.REGISTRY.counter("decode.attn.rows_fetched").inc(fetched)
+            obs.REGISTRY.counter("decode.attn.rows_held").inc(held)
         for t in ready:
             t["n"] -= 1
             t["unread"] += 1

@@ -5,9 +5,14 @@ tier-1 model's, the published head shape, and one the kernel does not
 take (``H dv`` is no multiple of 128 lanes), which must fall back to
 the XLA form and still equal. The last test compiles the kernel at the
 published widths for a v5e that is described, not attached: what
-interpret mode cannot refuse (tiling, VMEM), the chip's compiler does.
+interpret mode cannot refuse (tiling, VMEM), the chip's compiler does;
+beside it the whole step program of the benchmark's model, with the
+attention kernel of ``tests/test_decode_attention.py`` (one file holds
+libtpu: a second file's fixture would skip on another worker).
 """
 
+import importlib.util
+import json
 import os
 import re
 
@@ -177,6 +182,74 @@ def test_the_kernel_compiles_in_place_for_the_v5e(one_chip, monkeypatch):
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 8
     assert not re.search(rf"= f32\[{b},{dk},{h * dv}\]\S* copy\(", text)
+
+
+def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
+                                                          monkeypatch):
+    """The decode step of ``benchmark/configs/olmo-hybrid-7b-16l.json``
+    as the daemon compiles it: one ``decode_attention`` call a full
+    layer and no whole pass over a cache left, one ``gdn_step`` a
+    linear layer, the slab written in place."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from netsdb_tpu.ops import common
+
+    # the spec as the benchmark's deployment makes it from its file
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    loader = importlib.util.spec_from_file_location(
+        "bench_lm_sessions", os.path.join(bench, "deployments",
+                                          "lm_sessions.py"))
+    deployment = importlib.util.module_from_spec(loader)
+    loader.loader.exec_module(deployment)
+    with open(os.path.join(bench, "configs",
+                           "olmo-hybrid-7b-16l.json")) as f:
+        spec = deployment.spec_of(json.load(f))
+    heads = spec["heads"]
+    slots, rows = spec["slots"], hybrid_lm.cache_rows(spec)
+    full = spec["layer_types"].count(hybrid_lm.FULL)
+    linear = spec["layer_types"].count(hybrid_lm.LINEAR)
+    assert (slots, heads, rows, full, linear) == (16, 30, 4608, 4, 12)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {n: arg(shape, spec["dtype"] if is_matrix else "float32")
+              for n, (shape, is_matrix)
+              in hybrid_lm.weight_shapes(spec).items()}
+    layout = hybrid_lm.state_layout(spec)
+    slab = {n: arg(e["shape"], e["dtype"]) for n, e in layout.items()}
+    slab_bytes = sum(int(np.prod(e["shape"])) * jnp.dtype(e["dtype"]).itemsize
+                     for e in layout.values())
+
+    monkeypatch.setattr(common, "pallas_interpret", lambda: False)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            hybrid_lm.build_step(spec), donate_argnums=(1,)).lower(
+                params, slab, arg((slots,), "bool")).compile(
+                    compiler_options=spec["xla_options"])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == full
+    assert obs.REGISTRY.gauge("decode.gdn_step.fused_layers").value == linear
+    text = compiled.as_text()
+    calls = re.findall(r'^\s*(\S+) = .*custom_call_target="tpu_custom_call"',
+                       text, re.M)
+    assert len([c for c in calls if "decode_attention" in c]) == full
+    assert len([c for c in calls if "gdn_step" in c]) == linear
+    assert len([c for c in calls if "cache_write_rows" in c]) == 2 * full
+    assert len(calls) == 3 * full + linear
+    # no array of a logit a cached token: the whole pass is gone
+    assert f"f32[{slots},{heads},{rows}]" not in text
+    # the donated slab (caches and states) is written in place
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
+    assert memory.temp_size_in_bytes < slab_bytes // 100
 
 
 def test_the_three_bfloat16_pieces_sum_to_the_value_exactly():

@@ -51,6 +51,12 @@ CFG = {"hidden_size": 128, "intermediate_size": 256, "vocab_size": VOCAB,
        "layer_types": TYPES, "linear_num_key_heads": 4,
        "linear_key_head_dim": 16, "linear_value_head_dim": 32,
        "linear_conv_kernel_dim": 4, "rms_norm_eps": 1e-6}
+# the same model with ONE attention head of 128: whole lanes, so the
+# step's attention is the kernel ``decode_attention`` (the heads of 32
+# above take ``cached_attention``); the session tests run on both
+SPECS = {"heads-of-32": (SPEC, CFG),
+         "one-head-of-128": (dict(SPEC, heads=1, head_dim=128),
+                             dict(CFG, num_attention_heads=1))}
 # bfloat16 operands on both sides, but a rounding that falls the other
 # way on one side moves a logit by about a bfloat16 step of the
 # activations; float32 weights (below) agree to 1e-4
@@ -72,9 +78,34 @@ def _daemon(tmp_path, name="d0", **cfg_kw):
         ctl.shutdown()
 
 
-def _deploy(ctl, spec=SPEC, seed=5, db="lm"):
+@pytest.fixture(params=list(SPECS))
+def both_specs(request, monkeypatch):
+    """Runs a test once a spec of ``SPECS``, as the module's ``SPEC``
+    and ``CFG``; with the head of 128 the kernel must have been on the
+    step's path and its counters must say that it read less than the
+    slab holds."""
+    spec, cfg = SPECS[request.param]
+    monkeypatch.setitem(globals(), "SPEC", spec)
+    monkeypatch.setitem(globals(), "CFG", cfg)
+    # a program compiled for an earlier test sets no gauge again
+    decode_mod.clear_decode_programs()
+    rows = [_counter("decode.attn.rows_fetched"),
+            _counter("decode.attn.rows_held")]
+    yield request.param
+    kernel = request.param == "one-head-of-128"
+    assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == (
+        TYPES.count("full_attention") if kernel else 0)
+    fetched, held = (_counter("decode.attn.rows_fetched") - rows[0],
+                     _counter("decode.attn.rows_held") - rows[1])
+    # four slots of 768 rows: a session of under 256 tokens is a block
+    assert held > 0 and held % (2 * 4 * 768) == 0
+    assert (fetched <= held / 2) if kernel else (fetched == held)
+
+
+def _deploy(ctl, spec=None, seed=5, db="lm"):
     # through the daemon's own library, as a deployment fills it: the
     # wire's array codec carries no bfloat16
+    spec = spec or SPEC
     hybrid_lm.deploy(ctl.library, db, spec,
                      hybrid_lm.random_weights(spec, seed))
     return RemoteClient(ctl.advertise_addr)
@@ -228,7 +259,7 @@ def _turns(handles, plans, rng):
 @pytest.mark.parametrize("dtype,tol", [("bfloat16", LOGIT_TOL),
                                        ("float32", 2e-4)])
 def test_sessions_equal_the_reference_forward(tmp_path, dtype, tol,
-                                              monkeypatch):
+                                              monkeypatch, both_specs):
     """3 sessions batched, prefill then decode over several turns
     (prompts of 150 and 70 tokens cross the 64- and 128-token chunks;
     a later turn appends nothing and only generates): the logits of
@@ -282,7 +313,7 @@ def _one_session(ctl, plan, seed=7, sid=None):
 PLAN = [(100, 4), (64, 3)]
 
 
-def test_alone_equals_batched_bit_for_bit(tmp_path):
+def test_alone_equals_batched_bit_for_bit(tmp_path, both_specs):
     with _daemon(tmp_path) as ctl:
         _deploy(ctl).close()
         c0, h0, alone = _one_session(ctl, PLAN)
@@ -307,7 +338,7 @@ def test_alone_equals_batched_bit_for_bit(tmp_path):
             cc.close()
 
 
-def test_a_reused_slot_equals_a_fresh_daemons(tmp_path):
+def test_a_reused_slot_equals_a_fresh_daemons(tmp_path, both_specs):
     with _daemon(tmp_path, "fresh") as ctl:
         _deploy(ctl).close()
         c, h, fresh = _one_session(ctl, PLAN)
@@ -327,7 +358,7 @@ def test_a_reused_slot_equals_a_fresh_daemons(tmp_path):
         c.close()
 
 
-def test_spill_and_revive_of_both_kinds_of_state(tmp_path):
+def test_spill_and_revive_of_both_kinds_of_state(tmp_path, both_specs):
     """Between two turns the lease expires: the slot's recurrent state,
     convolution window and key/value cache all go to the arena and come
     back; the next turn equals an uninterrupted run's."""
