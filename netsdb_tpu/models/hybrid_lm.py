@@ -70,7 +70,8 @@ from netsdb_tpu import obs
 from netsdb_tpu.ops.attention import (DECODE_BLOCK, cache_write_rows,
                                       cached_attention, decode_attention,
                                       decode_attention_fits)
-from netsdb_tpu.ops.delta_rule import (gated_delta_chunked,
+from netsdb_tpu.ops.delta_rule import (chunk_kernel_fits,
+                                       gated_delta_chunked,
                                        gated_delta_step_flat, heads_first,
                                        heads_on_lanes, step_kernel_fits)
 
@@ -411,8 +412,13 @@ def build_prefill(spec, chunk: int):
     types = spec["layer_types"]
     heads, hd = spec["heads"], spec["head_dim"]
     keep = spec["conv_k"] - 1
+    fits = chunk_kernel_fits(spec["delta_chunk"], spec["lin_dk"],
+                             spec["lin_dv"])
 
     def hybrid_lm_prefill(p, slab, slot, tokens, n_valid, next_tok):
+        # runs when the program is traced, once a compiled program
+        obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers").set(
+            sum(t == LINEAR for t in types) if fits else 0)
         slab = dict(slab)
         conv = slab["conv"]
         pos, tok = slab["pos"], slab["tok"]

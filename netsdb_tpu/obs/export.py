@@ -370,6 +370,13 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                          "in-place kernel (0: the "
                                          "state's shape took the XLA "
                                          "form)"),
+        ("prefill.gdn_chunk.fused_layers", "linear-attention layers of "
+                                           "the last traced hybrid_lm "
+                                           "prefill program whose "
+                                           "chunked delta rule is the "
+                                           "one kernel that keeps the "
+                                           "state in VMEM (0: the "
+                                           "shape took the XLA form)"),
         ("decode.attn.ragged_layers", "full-attention layers of the "
                                       "last traced hybrid_lm step "
                                       "program whose attention is the "

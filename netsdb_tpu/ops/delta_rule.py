@@ -20,8 +20,19 @@ Three formulations, all float32 at ``Precision.HIGHEST``:
   what the kernel is tested against. Nothing but the shape chooses.
 * :func:`gated_delta_chunked` — a whole sequence in chunks of ``chunk``
   tokens (prefill): inside a chunk the rule is solved in its WY form
-  (one unit-lower-triangular solve a chunk, all chunks at once), and
-  only the chunk-to-chunk state passes through a scan.
+  (one unit-lower-triangular system a chunk and head), and only the
+  state passes from chunk to chunk. Where a chunk is 128 tokens and
+  ``dk`` and ``dv`` are multiples of 8 (:func:`chunk_kernel_fits`; the
+  published 128, 96, 192) the call is one Pallas kernel, ``gdn_chunk``: grid
+  (groups of heads, chunks in order), a head's state in VMEM from the
+  first chunk to the last, read from ``S0`` once and written once, and
+  ``(I + L)^-1`` built by blocks (the 16-row diagonal blocks by their
+  finite Neumann product, then three merges of pairs of blocks:
+  twelve products of (128, 128) matrices and no loop over rows). Any
+  other shape takes :func:`gated_delta_chunked_xla`, the same form in
+  plain XLA (one ``triangular_solve`` for all chunks, then a scan),
+  which stays as the fallback and as what the kernel is tested
+  against. Nothing but the shape chooses.
 * :func:`gated_delta_recurrent` — the token-by-token scan the other two
   are tested against.
 
@@ -40,7 +51,8 @@ and, ``S_0`` entering linearly, ``U = U~ - W S_0`` with ``U~`` and ``W``
 the solutions for the right-hand sides ``diag(beta) V`` and
 ``diag(beta G) K``. Then ``O = diag(G) Q S_0 + ((Q K^T) * D) U`` with
 ``D_ij = G_i / G_j`` for ``j <= i``, and ``S_C = G_C S_0 + (diag(G_C /
-G) K)^T U``.
+G) K)^T U``. The kernel has the chunk's ``S_0`` at hand when it solves,
+so it solves the first equation as it stands, for ``U`` alone.
 """
 
 from __future__ import annotations
@@ -237,9 +249,31 @@ def gated_delta_recurrent(S0, q, k, v, log_alpha, beta):
     return jax.lax.scan(body, S0, (q, k, v, log_alpha, beta))
 
 
+def chunk_kernel_fits(chunk: int, dk: int, dv: int) -> bool:
+    """Whether :func:`gated_delta_chunked` runs its kernel: a delta-chunk
+    whose ``(chunk, chunk)`` matrices are one lane tile wide, and states
+    ``(dk, dv)`` of whole float32 sublane tiles."""
+    return chunk == LANES and dk % 8 == 0 and dv % 8 == 0
+
+
 def gated_delta_chunked(S0, q, k, v, log_alpha, beta, chunk: int = 64):
     """One sequence in chunks (shapes as :func:`gated_delta_recurrent`;
-    ``L`` a multiple of ``chunk``). Returns ``(S_L, O)``."""
+    ``L`` a multiple of ``chunk``). Returns ``(S_L, O)``.
+
+    Where the shape fits (:func:`chunk_kernel_fits`) the whole call is
+    one kernel that keeps a head's state in VMEM from the first chunk
+    to the last; any other shape takes
+    :func:`gated_delta_chunked_xla`."""
+    if chunk_kernel_fits(chunk, q.shape[-1], v.shape[-1]):
+        return _gated_delta_chunk_kernel(S0, q, k, v, log_alpha, beta, chunk)
+    return gated_delta_chunked_xla(S0, q, k, v, log_alpha, beta, chunk)
+
+
+def gated_delta_chunked_xla(S0, q, k, v, log_alpha, beta, chunk: int = 64):
+    """:func:`gated_delta_chunked` in plain XLA: the form for shapes the
+    kernel does not take, and what the kernel is tested against. One
+    unit-lower-triangular solve for all chunks at once, then a scan
+    that passes the state from chunk to chunk."""
     L, H, dk = q.shape
     dv = v.shape[-1]
     if L % chunk:
@@ -283,5 +317,157 @@ def gated_delta_chunked(S0, q, k, v, log_alpha, beta, chunk: int = 64):
         return S2, O
 
     S_L, O = jax.lax.scan(body, S0, (qc, kc, W, Ut, qk, g, to_end))
+    # (nc, H, C, dv) -> (L, H, dv)
+    return S_L, jnp.moveaxis(O, 1, 2).reshape(L, H, dv)
+
+
+# heads a grid step of the chunked form's kernel, so that a step's 0.35
+# microseconds are shared: on the v5e groups of 2 to 10 take the same
+# time (the products bound it), one head a step 40% more, and the
+# compile time grows with the group
+MAX_CHUNK_HEADS = 3
+# rows of the diagonal blocks of ``I + L`` that are inverted by their
+# finite Neumann product before the blocks are merged upward
+INVERSE_BLOCK = 16
+
+
+def _mm(a, b):
+    """``a @ b`` a head, (G, m, k) x (G, k, n), in float32 at six
+    bfloat16 passes."""
+    return jax.lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse(L):
+    """``(I + L)^-1`` for strictly lower triangular ``L`` (G, n, n), by
+    blocks: the diagonal blocks of ``INVERSE_BLOCK`` rows by the Neumann
+    product ``(I + N)(I + N^2)(I + N^4)...`` with ``N = -L`` there
+    (finite, ``N`` being nilpotent), then pairs of blocks merged,
+    ``[[T11, 0], [-T22 A21 T11, T22]]``, until one block is left. Every
+    step is a product of whole (n, n) matrices under a mask: no loop
+    over rows."""
+    n = L.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 2)
+    apart = rows ^ cols                 # < b: in the same block of b
+    width = INVERSE_BLOCK
+    N = jnp.where(apart < width, -L, 0.0)
+    T = jnp.where(rows == cols, 1.0, 0.0) + N
+    power = N
+    for _ in range(width.bit_length() - 2):
+        power = _mm(power, power)
+        T = T + _mm(T, power)
+    while width < n:
+        below = jnp.where((apart >= width) & (apart < 2 * width), L, 0.0)
+        T = T - _mm(T, _mm(below, T))
+        width *= 2
+    return T
+
+
+def _chunk_heads(heads: int) -> int:
+    return max(g for g in range(1, MAX_CHUNK_HEADS + 1) if heads % g == 0)
+
+
+def _gated_delta_chunk_kernel(S0, q, k, v, log_alpha, beta, chunk):
+    """The chunked form as one Pallas kernel (``gdn_chunk`` in a device
+    trace). Grid: groups of heads, then the sequence's chunks in order;
+    a group's states live in the output block, which stays in VMEM
+    while the chunks pass, is filled from ``S0`` before the first and
+    written back after the last.
+
+    A grid step solves one chunk of each of its heads from the state as
+    it stands, ``(I + L) U = diag(beta) (V - diag(G) K S)`` (the module
+    docstring's system with ``U~ - W S`` taken together, the state being
+    at hand), with the inverse of ``I + L`` built by blocks
+    (:func:`_unit_lower_inverse`). ``k`` comes in both layouts, rows of
+    tokens and rows of ``dk``, so that no product transposes an operand;
+    the gates come as a row a head (``g_i`` along the lanes) and as
+    columns (a group's ``g`` and ``beta`` side by side, tokens along the
+    sublanes, one 128-lane tile a group), prepared outside: 3 MB a
+    512-token chunk of the published shape beside the 41 MB of q, k,
+    ``k^T``, v and o."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from netsdb_tpu.ops.common import pallas_interpret
+
+    L, H, dk = q.shape
+    dv = v.shape[-1]
+    nc, G = L // chunk, _chunk_heads(H)
+
+    def chunks(a):  # (L, H, ...) -> (nc, H, chunk, ...)
+        a = a.reshape((nc, chunk) + a.shape[1:])
+        return jnp.moveaxis(a, 2, 1)
+
+    qc, kc, vc = chunks(q), chunks(k), chunks(v)
+    kt = jnp.swapaxes(kc, 2, 3)                        # (nc, H, dk, C)
+    g = jnp.cumsum(chunks(log_alpha), axis=-1)         # log G_i, (nc, H, C)
+    g_rows = g[:, :, None, :]
+
+    def columns(a):  # (nc, H, C) -> (nc, H / G, C, G)
+        return jnp.moveaxis(a.reshape(nc, H // G, G, chunk), 2, 3)
+
+    # (nc, H / G, C, LANES): lane j is g of the group's head j, lane
+    # G + j its beta
+    gates = jnp.concatenate([columns(g), columns(chunks(beta))], axis=-1)
+    gates = jnp.pad(gates, ((0, 0),) * 3 + ((0, LANES - 2 * G),))
+
+    def kernel(s0_ref, q_ref, k_ref, kt_ref, v_ref, g_ref, gates_ref,
+               s_ref, o_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            s_ref[...] = s0_ref[...]
+
+        rows = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 2)
+        S = s_ref[...]                                   # (G, dk, dv)
+        kt_g, g_row = kt_ref[0], g_ref[0]                # g_row (G, 1, C)
+        cols_of = gates_ref[0, 0]
+        g_col = jnp.stack([cols_of[:, h:h + 1] for h in range(G)])
+        b_col = jnp.stack([cols_of[:, G + h:G + h + 1] for h in range(G)])
+        # D_ij = G_i / G_j on and below the diagonal, 0 above: the
+        # exponent is masked before exp
+        D = jnp.exp(jnp.where(rows >= cols, g_col - g_row, -jnp.inf))
+        kq = jnp.concatenate([k_ref[0], q_ref[0]], axis=1)   # (G, 2C, dk)
+        kq_kt = _mm(kq, kt_g)                            # k k^T over q k^T
+        kq_S = _mm(kq, S)                                # K S over Q S
+        T = _unit_lower_inverse(
+            jnp.where(rows > cols, b_col * D * kq_kt[:, :chunk], 0.0))
+        decay = jnp.exp(g_col)
+        U = _mm(T, b_col * (v_ref[0] - decay * kq_S[:, :chunk]))
+        o_ref[0] = decay * kq_S[:, chunk:] + _mm(kq_kt[:, chunk:] * D, U)
+        g_end = g_row[:, :, chunk - 1:]                  # (G, 1, 1)
+        s_ref[...] = jnp.exp(g_end) * S + _mm(
+            kt_g * jnp.exp(g_end - g_row), U)
+
+    def per_chunk(*block):
+        return pl.BlockSpec((1, G) + block, lambda i, c: (c, i, 0, 0))
+
+    state = pl.BlockSpec((G, dk, dv), lambda i, c: (i, 0, 0))
+
+    def lanes(n):
+        return -(-n // LANES) * LANES
+
+    # a group's blocks (q, k, k^T, v, o, the state in and out), each
+    # double-buffered, and room for the body: some forty (chunk, chunk)
+    # matrices a head live at once
+    vmem = 8 * G * (chunk * (2 * lanes(dk) + 2 * lanes(dv)) + dk * chunk
+                    + 2 * dk * lanes(dv)) + G * (3 << 20) + (4 << 20)
+    S_L, O = pl.pallas_call(
+        kernel, grid=(H // G, nc),
+        in_specs=[state, per_chunk(chunk, dk), per_chunk(chunk, dk),
+                  per_chunk(dk, chunk), per_chunk(chunk, dv),
+                  per_chunk(1, chunk),
+                  pl.BlockSpec((1, 1, chunk, LANES),
+                               lambda i, c: (c, i, 0, 0))],
+        out_specs=[state, per_chunk(chunk, dv)],
+        out_shape=[jax.ShapeDtypeStruct((H, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((nc, H, chunk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        name="gdn_chunk", interpret=pallas_interpret())(
+            S0, qc, kc, kt, vc, g_rows, gates)
     # (nc, H, C, dv) -> (L, H, dv)
     return S_L, jnp.moveaxis(O, 1, 2).reshape(L, H, dv)

@@ -9,6 +9,11 @@ interpret mode cannot refuse (tiling, VMEM), the chip's compiler does;
 beside it the whole step program of the benchmark's model, with the
 attention kernel of ``tests/test_decode_attention.py`` (one file holds
 libtpu: a second file's fixture would skip on another worker).
+
+Since PR 34 the chunked form of prefill has a kernel too
+(``gated_delta_chunked`` -> ``gdn_chunk``): held here against its XLA
+body and the token recurrence, and the benchmark's two prefill
+programs are compiled for the described v5e like the step program.
 """
 
 import importlib.util
@@ -132,6 +137,148 @@ def test_the_kernel_path_equals_the_rules_other_forms(shape):
     assert np.abs(np.asarray(S_b)[1] - np.asarray(S1)[1]).max() > 0.1
 
 
+# --- the chunked form's kernel (prefill) --------------------------------
+
+CHUNK = 128
+# (heads, L, dk, dv): the published head shape at two group sizes and
+# both prefill lengths, and one small shape that fits
+CHUNKED = [(2, 128, 96, 192), (5, 128, 96, 192), (2, 512, 96, 192),
+           (5, 512, 96, 192), (3, 256, 16, 32)]
+
+
+def _sequence(rng, heads, length, dk, dv, state=True):
+    s0, q, k, v, la, beta = _inputs(rng, (1, heads, dk, dv), length)
+    s0 = s0[0] if state else np.zeros_like(s0[0])
+    return (s0,) + tuple(a[:, 0] for a in (q, k, v, la, beta))
+
+
+_chunked = jax.jit(delta_rule.gated_delta_chunked, static_argnums=(6,))
+_chunked_xla = jax.jit(delta_rule.gated_delta_chunked_xla,
+                       static_argnums=(6,))
+_recurrent = jax.jit(delta_rule.gated_delta_recurrent)
+
+
+def _close(got, want, tol):
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               atol=tol)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                               atol=tol)
+
+
+@pytest.mark.parametrize("state", [False, True], ids=["from-zero", "onto"])
+@pytest.mark.parametrize("shape", CHUNKED, ids=lambda s: "x".join(map(str, s)))
+def test_the_chunked_kernel_equals_its_xla_body_and_the_recurrence(shape,
+                                                                   state):
+    heads, length, dk, dv = shape
+    assert delta_rule.chunk_kernel_fits(CHUNK, dk, dv)
+    args = _sequence(np.random.default_rng(length + heads), heads, length,
+                     dk, dv, state)
+    got = _chunked(*args, CHUNK)
+    assert got[0].shape == (heads, dk, dv)
+    assert got[1].shape == (length, heads, dv)
+    _close(got, _chunked_xla(*args, CHUNK), 5e-6)
+    _close(got, _recurrent(*args), 2e-5)
+
+
+def test_a_padded_tail_leaves_the_chunked_kernels_state_bit_for_bit():
+    heads, dk, dv = 5, 96, 192
+    s0, q, k, v, la, beta = _sequence(np.random.default_rng(8), heads, 512,
+                                      dk, dv)
+    # nothing but padding: the state comes back as it went in
+    zero = np.zeros_like(la)
+    S, _ = _chunked(s0, q, k, v, zero, zero, CHUNK)
+    np.testing.assert_array_equal(np.asarray(S), s0)
+    # two chunks of tokens, two of padding: the state of the two alone
+    la[256:], beta[256:] = 0.0, 0.0
+    S, O = _chunked(s0, q, k, v, la, beta, CHUNK)
+    S2, O2 = _chunked(s0, q[:256], k[:256], v[:256], la[:256], beta[:256],
+                      CHUNK)
+    np.testing.assert_array_equal(np.asarray(S), np.asarray(S2))
+    np.testing.assert_array_equal(np.asarray(O)[:256], np.asarray(O2))
+    # a chunk that ends in padding: the recurrence over what counts
+    la[200:], beta[200:] = 0.0, 0.0
+    S, O = _chunked(s0, q, k, v, la, beta, CHUNK)
+    S_r, O_r = _recurrent(s0, q[:200], k[:200], v[:200], la[:200],
+                          beta[:200])
+    _close((S, O[:200]), (S_r, O_r), 2e-5)
+
+
+@pytest.mark.parametrize("beta_at,decay_at", [
+    (1e-4, None), (2.0 - 1e-4, None), (None, -30.0), (None, -1e-6),
+    (2.0 - 1e-4, -1e-6)],
+    ids=["beta-near-0", "beta-near-2", "decay-near-0", "decay-near-1",
+         "beta-near-2-undamped"])
+def test_the_chunked_kernel_at_the_gates_edges(beta_at, decay_at):
+    s0, q, k, v, la, beta = _sequence(np.random.default_rng(21), 2, 256,
+                                      96, 192)
+    if beta_at is not None:
+        beta[:] = beta_at
+    if decay_at is not None:
+        la[:] = decay_at
+    got = _chunked(s0, q, k, v, la, beta, CHUNK)
+    assert np.isfinite(np.asarray(got[0])).all()
+    assert np.isfinite(np.asarray(got[1])).all()
+    want = _recurrent(s0, q, k, v, la, beta)
+    scale = max(1.0, float(np.abs(np.asarray(want[0])).max()))
+    _close(got, want, 3e-5 * scale)
+    _close(got, _chunked_xla(s0, q, k, v, la, beta, CHUNK), 3e-5 * scale)
+
+
+def test_two_calls_that_pass_the_state_equal_one_recurrent_pass():
+    s0, q, k, v, la, beta = _sequence(np.random.default_rng(4), 5, 1024,
+                                      96, 192)
+    S, O1 = _chunked(s0, q[:512], k[:512], v[:512], la[:512], beta[:512],
+                     CHUNK)
+    S, O2 = _chunked(S, q[512:], k[512:], v[512:], la[512:], beta[512:],
+                     CHUNK)
+    S_r, O_r = _recurrent(s0, q, k, v, la, beta)
+    _close((S, np.concatenate([O1, O2])), (S_r, O_r), 3e-5)
+
+
+def _prefill_fused_layers(chunk, dk, dv):
+    """What a prefill program built for a model of twelve linear layers
+    of this head shape reports (traced, never run)."""
+    spec = hybrid_lm.make_spec(
+        layer_types=[hybrid_lm.LINEAR] * 12, hidden=32, intermediate=64,
+        vocab=64, heads=2, head_dim=16, lin_heads=2, lin_dk=dk, lin_dv=dv,
+        slots=2, cache_tokens=256, prefill_chunks=(2 * chunk,),
+        delta_chunk=chunk, dtype="float32")
+    params = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+              for n, (s, _) in hybrid_lm.weight_shapes(spec).items()}
+    slab = {n: jax.ShapeDtypeStruct(e["shape"], jnp.dtype(e["dtype"]))
+            for n, e in hybrid_lm.state_layout(spec).items()}
+    gauge = obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers")
+    gauge.set(-1)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    jaxpr = jax.make_jaxpr(hybrid_lm.build_prefill(spec, 2 * chunk))(
+        params, slab, scalar,
+        jax.ShapeDtypeStruct((2 * chunk,), jnp.int32), scalar, scalar)
+    assert obs.REGISTRY.snapshot()["gauges"][
+        "prefill.gdn_chunk.fused_layers"] == gauge.value
+    return gauge.value, str(jaxpr)
+
+
+@pytest.mark.parametrize("shape,fits", [
+    ((128, 96, 192), True), ((128, 16, 32), True), ((64, 96, 192), False),
+    ((256, 96, 192), False), ((128, 12, 32), False)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else None)
+def test_nothing_but_the_shape_chooses_the_chunked_kernel(shape, fits):
+    chunk, dk, dv = shape
+    assert delta_rule.chunk_kernel_fits(chunk, dk, dv) == fits
+    layers, jaxpr = _prefill_fused_layers(chunk, dk, dv)
+    assert layers == (12 if fits else 0)
+    assert jaxpr.count("gdn_chunk") == (12 if fits else 0)
+    assert ("triangular_solve" in jaxpr) != fits
+    if not fits:
+        # the dispatcher's result is the XLA body's, bit for bit
+        args = _sequence(np.random.default_rng(2), 2, 2 * chunk, dk, dv)
+        got, want = _chunked(*args, chunk), _chunked_xla(*args, chunk)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """One described v5e chip; the topology is asked for only once a
@@ -184,32 +331,28 @@ def test_the_kernel_compiles_in_place_for_the_v5e(one_chip, monkeypatch):
     assert not re.search(rf"= f32\[{b},{dk},{h * dv}\]\S* copy\(", text)
 
 
-def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
-                                                          monkeypatch):
-    """The decode step of ``benchmark/configs/olmo-hybrid-7b-16l.json``
-    as the daemon compiles it: one ``decode_attention`` call a full
-    layer and no whole pass over a cache left, one ``gdn_step`` a
-    linear layer, the slab written in place."""
-    from jax.experimental.compilation_cache import compilation_cache
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
 
-    from netsdb_tpu.ops import common
 
-    # the spec as the benchmark's deployment makes it from its file
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    loader = importlib.util.spec_from_file_location(
-        "bench_lm_sessions", os.path.join(bench, "deployments",
-                                          "lm_sessions.py"))
-    deployment = importlib.util.module_from_spec(loader)
-    loader.loader.exec_module(deployment)
-    with open(os.path.join(bench, "configs",
+def _load(name, path):
+    loader = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(loader)
+    loader.loader.exec_module(module)
+    return module
+
+
+def _benchmarks_model(one_chip):
+    """(cfg, spec, params, slab, slab bytes) of
+    ``benchmark/configs/olmo-hybrid-7b-16l.json``, the spec as the
+    benchmark's deployment makes it from its file, the arrays as shapes
+    on the described chip."""
+    deployment = _load("bench_lm_sessions",
+                       os.path.join(BENCH, "deployments", "lm_sessions.py"))
+    with open(os.path.join(BENCH, "configs",
                            "olmo-hybrid-7b-16l.json")) as f:
-        spec = deployment.spec_of(json.load(f))
-    heads = spec["heads"]
-    slots, rows = spec["slots"], hybrid_lm.cache_rows(spec)
-    full = spec["layer_types"].count(hybrid_lm.FULL)
-    linear = spec["layer_types"].count(hybrid_lm.LINEAR)
-    assert (slots, heads, rows, full, linear) == (16, 30, 4608, 4, 12)
+        cfg = json.load(f)
+    spec = deployment.spec_of(cfg)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
@@ -222,24 +365,52 @@ def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
     slab = {n: arg(e["shape"], e["dtype"]) for n, e in layout.items()}
     slab_bytes = sum(int(np.prod(e["shape"])) * jnp.dtype(e["dtype"]).itemsize
                      for e in layout.values())
+    return cfg, spec, params, slab, slab_bytes, arg
+
+
+def _compile_for_the_chip(monkeypatch, program, args, options):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from netsdb_tpu.ops import common
 
     monkeypatch.setattr(common, "pallas_interpret", lambda: False)
+    # such a compile can be written to the persistent cache but not
+    # read back without a chip: keep it out
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jax.jit(
-            hybrid_lm.build_step(spec), donate_argnums=(1,)).lower(
-                params, slab, arg((slots,), "bool")).compile(
-                    compiler_options=spec["xla_options"])
+        return jax.jit(program, donate_argnums=(1,)).lower(*args).compile(
+            compiler_options=options)
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
+
+
+def _custom_calls(text):
+    return re.findall(r'^\s*(\S+) = .*custom_call_target="tpu_custom_call"',
+                      text, re.M)
+
+
+def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
+                                                          monkeypatch):
+    """The decode step of ``benchmark/configs/olmo-hybrid-7b-16l.json``
+    as the daemon compiles it: one ``decode_attention`` call a full
+    layer and no whole pass over a cache left, one ``gdn_step`` a
+    linear layer, the slab written in place."""
+    _, spec, params, slab, slab_bytes, arg = _benchmarks_model(one_chip)
+    heads = spec["heads"]
+    slots, rows = spec["slots"], hybrid_lm.cache_rows(spec)
+    full = spec["layer_types"].count(hybrid_lm.FULL)
+    linear = spec["layer_types"].count(hybrid_lm.LINEAR)
+    assert (slots, heads, rows, full, linear) == (16, 30, 4608, 4, 12)
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_step(spec),
+        (params, slab, arg((slots,), "bool")), spec["xla_options"])
     assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == full
     assert obs.REGISTRY.gauge("decode.gdn_step.fused_layers").value == linear
     text = compiled.as_text()
-    calls = re.findall(r'^\s*(\S+) = .*custom_call_target="tpu_custom_call"',
-                       text, re.M)
+    calls = _custom_calls(text)
     assert len([c for c in calls if "decode_attention" in c]) == full
     assert len([c for c in calls if "gdn_step" in c]) == linear
     assert len([c for c in calls if "cache_write_rows" in c]) == 2 * full
@@ -250,6 +421,40 @@ def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= slab_bytes
     assert memory.temp_size_in_bytes < slab_bytes // 100
+
+
+@pytest.mark.parametrize("chunk", [128, 512])
+def test_the_benchmarks_prefill_programs_compile_for_the_v5e(
+        one_chip, monkeypatch, chunk):
+    """The two prefill programs of the same model: the chunked rule is
+    one ``gdn_chunk`` call a linear layer, whose text carries a shape
+    the benchmark's roofline looks for
+    (``benchmark/lm_work.py::touches_chunk_solve``), and neither a
+    triangular solve nor a loop over delta-chunks is left."""
+    lm_work = _load("bench_lm_work", os.path.join(BENCH, "lm_work.py"))
+    cfg, spec, params, slab, slab_bytes, arg = _benchmarks_model(one_chip)
+    assert chunk in spec["prefill_chunks"]
+    linear = spec["layer_types"].count(hybrid_lm.LINEAR)
+    scalar = arg((), "int32")
+    gauge = obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers")
+    gauge.set(-1)
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_prefill(spec, chunk),
+        (params, slab, scalar, arg((chunk,), "int32"), scalar, scalar),
+        spec["xla_options"])
+    assert gauge.value == linear == 12
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == linear
+    for line in calls:
+        assert "gdn_chunk" in line
+        assert lm_work.touches_chunk_solve(line, cfg)
+    assert "triangular" not in text.lower()
+    assert 'custom_call_target="Invert' not in text
+    assert not re.search(r"\bwhile\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
 
 
 def test_the_three_bfloat16_pieces_sum_to_the_value_exactly():

@@ -54,9 +54,15 @@ CFG = {"hidden_size": 128, "intermediate_size": 256, "vocab_size": VOCAB,
 # the same model with ONE attention head of 128: whole lanes, so the
 # step's attention is the kernel ``decode_attention`` (the heads of 32
 # above take ``cached_attention``); the session tests run on both
+# and the same model with delta-chunks of 128 tokens: the shape whose
+# chunked rule in prefill is the kernel ``gdn_chunk`` (chunks of 64
+# take the XLA body); prompts of 150 and 70 tokens then run a 256- and
+# a 128-token prefill chunk that end in padding
 SPECS = {"heads-of-32": (SPEC, CFG),
          "one-head-of-128": (dict(SPEC, heads=1, head_dim=128),
-                             dict(CFG, num_attention_heads=1))}
+                             dict(CFG, num_attention_heads=1)),
+         "delta-chunks-of-128": (dict(SPEC, delta_chunk=128,
+                                      prefill_chunks=[128, 256]), CFG)}
 # bfloat16 operands on both sides, but a rounding that falls the other
 # way on one side moves a logit by about a bfloat16 step of the
 # activations; float32 weights (below) agree to 1e-4
@@ -83,7 +89,8 @@ def both_specs(request, monkeypatch):
     """Runs a test once a spec of ``SPECS``, as the module's ``SPEC``
     and ``CFG``; with the head of 128 the kernel must have been on the
     step's path and its counters must say that it read less than the
-    slab holds."""
+    slab holds; with delta-chunks of 128 the chunked rule's kernel must
+    have been on prefill's path, and with chunks of 64 its XLA body."""
     spec, cfg = SPECS[request.param]
     monkeypatch.setitem(globals(), "SPEC", spec)
     monkeypatch.setitem(globals(), "CFG", cfg)
@@ -100,6 +107,9 @@ def both_specs(request, monkeypatch):
     # four slots of 768 rows: a session of under 256 tokens is a block
     assert held > 0 and held % (2 * 4 * 768) == 0
     assert (fetched <= held / 2) if kernel else (fetched == held)
+    assert obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers").value == (
+        TYPES.count("linear_attention")
+        if request.param == "delta-chunks-of-128" else 0)
 
 
 def _deploy(ctl, spec=None, seed=5, db="lm"):
