@@ -373,6 +373,11 @@ class _HybridKind:
 
         return hybrid_lm.cache_rows_read(spec, lengths)
 
+    def step_counts(self, spec):
+        from netsdb_tpu.models import hybrid_lm
+
+        return hybrid_lm.step_counts(spec)
+
 
 _KINDS = {k.name: k for k in (_LstmKind(), _TransformerKind(),
                               _HybridKind())}
@@ -726,6 +731,14 @@ class DecodeRuntime:
         caches by one step whose live slots see ``lengths`` keys."""
         reg = self._reg(db)
         return reg["kind"].cache_rows_read(reg["spec"], lengths)
+
+    def step_counts(self, db: str) -> Dict[str, int]:
+        """What a step of ``db`` returns after its slots' ids, by name,
+        and ``experts_held``, the held experts its expert layers walk a
+        step ({} for a model without expert layers)."""
+        reg = self._reg(db)
+        counts = getattr(reg["kind"], "step_counts", None)
+        return counts(reg["spec"]) if counts else {}
 
     def prefill(self, db: str, arrays, slot: int, tokens: np.ndarray,
                 n_valid: int, next_tok: int):

@@ -309,18 +309,33 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("decode.attn.rows_fetched", "cache rows (a token's keys and "
                                      "values of one layer) the decode "
                                      "steps' attention fetched over "
-                                     "the full-attention layers: with "
-                                     "the kernel each live slot's "
-                                     "length in whole blocks of "
-                                     "DECODE_BLOCK and one block of an "
-                                     "idle slot, with the whole pass "
-                                     "all the slab holds; counted a "
-                                     "step on the host from the "
-                                     "scheduler's own lengths"),
+                                     "the attention layers, by layer "
+                                     "type: with the kernel what each "
+                                     "live slot sees in whole blocks "
+                                     "of DECODE_BLOCK (of a full layer "
+                                     "its length, of a sliding layer "
+                                     "its window at most) and one "
+                                     "block of an idle slot, with the "
+                                     "whole pass all the slab holds; "
+                                     "counted a step on the host from "
+                                     "the scheduler's own lengths"),
         ("decode.attn.rows_held", "cache rows the slab held for the "
                                   "same steps and layers (slots x "
                                   "rows a slot): what a whole pass "
                                   "over every slot's cache reads"),
+        ("decode.moe.pairs", "(token, expert) pairs the decode steps "
+                             "routed to experts held here, summed "
+                             "over the expert layers; returned by the "
+                             "step program after its ids"),
+        ("decode.moe.experts_touched", "distinct held experts that a "
+                                       "decode step's pairs touched, "
+                                       "summed over its expert layers "
+                                       "and over steps: the experts "
+                                       "whose matrices a step read"),
+        ("decode.moe.experts_held", "held experts the same steps' "
+                                    "expert layers could have read "
+                                    "(expert layers x experts held, a "
+                                    "step)"),
         ("session.slab.spills", "slots of a model's session slab "
                                 "copied to the host arena (lease "
                                 "evicted under pressure or expired)"),
@@ -377,7 +392,10 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                            "one kernel that keeps the "
                                            "state in VMEM (0: the "
                                            "shape took the XLA form)"),
-        ("decode.attn.ragged_layers", "full-attention layers of the "
+        ("decode.moe.max_load", "pairs of the busiest held expert in "
+                                "the last decode step read (the "
+                                "largest over its expert layers)"),
+        ("decode.attn.ragged_layers", "attention layers of the "
                                       "last traced hybrid_lm step "
                                       "program whose attention is the "
                                       "kernel that reads each slot's "

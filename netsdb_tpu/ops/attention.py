@@ -90,22 +90,31 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      q_pos: jax.Array, block_size: Optional[int] = None,
-                     scale: Optional[float] = None, row0=None) -> jax.Array:
+                     scale: Optional[float] = None, row0=None,
+                     k_pos: Optional[jax.Array] = None,
+                     window: Optional[int] = None) -> jax.Array:
     """Attention of new queries over a per-row cache — the
     chunked-prefill path (one row, a chunk of queries) of a model that
     keeps keys and values in slot-indexed caches, and its decode path
     (one query a row) where the caches' shape does not fit
     :func:`decode_attention`, which is tested against this.
 
-    ``q`` (B, Lq, H, D). ``k_cache`` and ``v_cache`` are (rows, H, T,
+    ``q`` (B, Lq, H, D). ``k_cache`` and ``v_cache`` are (rows, Hkv, T,
     D): tokens and head_dim are the two minor axes, so a bfloat16 cache
-    tiles without padding. With ``row0=None`` every row is attended
+    tiles without padding. ``H`` is a multiple of ``Hkv`` (grouped
+    queries: ``H / Hkv`` consecutive query heads share a key/value head,
+    and are folded into the query axis, so the products are the same
+    ones). With ``row0=None`` every row is attended
     (``B`` = rows) and the caches are the products' operands as they
     stand, never sliced or copied; with a (possibly traced) ``row0``
     the ``B`` rows from there are. ``q_pos`` (B, Lq) int32 is the
     position of each query: key ``j`` of row ``b`` is visible to query
     ``i`` when ``j <= q_pos[b, i]``. A query with ``q_pos < 0`` sees
-    nothing and yields zeros.
+    nothing and yields zeros. A cache that is a ring gives ``k_pos``
+    (T,) or (B, T) int32, the position of the token each cache row holds
+    (below 0: none yet), in place of the row's own index, and every
+    block is walked; with ``window`` key ``j`` is visible only while
+    ``j > q_pos - window``.
 
     ``block_size=None`` reads a row's whole cache in one pass (masked
     beyond each query's position): the fewest operations, and what a
@@ -116,7 +125,7 @@ def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     length is never read. Products take the cache's dtype as operands
     and accumulate in float32; returns float32 (B, Lq, H, D)."""
     b, lq, h, d = q.shape
-    t = k_cache.shape[2]
+    hkv, t = k_cache.shape[1], k_cache.shape[2]
     block_size = block_size or t
     if t % block_size:
         raise ValueError(f"cache length {t} is not a multiple of the "
@@ -124,6 +133,11 @@ def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     scale = scale if scale is not None else d ** -0.5
     qs = jnp.moveaxis((q.astype(jnp.float32) * scale).astype(k_cache.dtype),
                       1, 2)                                # (B, H, Lq, D)
+    group = h // hkv
+    if group > 1:
+        qs = qs.reshape(b, hkv, group * lq, d)
+        q_pos = jnp.tile(q_pos, (1, group))
+    lf = group * lq
     whole = row0 is None and block_size == t
 
     def block(cache, i):
@@ -132,14 +146,24 @@ def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         start = (jnp.asarray(0 if row0 is None else row0, jnp.int32),
                  jnp.int32(0), jnp.asarray(i * block_size, jnp.int32),
                  jnp.int32(0))
-        return jax.lax.dynamic_slice(cache, start, (b, h, block_size, d))
+        return jax.lax.dynamic_slice(cache, start, (b, hkv, block_size, d))
 
     def body(i, carry):
         num, den, mx = carry
         logits = jnp.einsum("bhqd,bhkd->bhqk", qs, block(k_cache, i),
                             preferred_element_type=jnp.float32)
-        k_pos = i * block_size + jnp.arange(block_size)
-        mask = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
+        if k_pos is None:
+            at = i * block_size + jnp.arange(block_size)
+        else:
+            at = jax.lax.dynamic_slice_in_dim(k_pos, i * block_size,
+                                              block_size, axis=-1)
+        at = at.reshape((-1, 1, 1, block_size))     # one ring, or one a row
+        qp = q_pos[:, None, :, None]
+        mask = at <= qp
+        if k_pos is not None:
+            mask &= at >= 0
+        if window is not None:
+            mask &= at > qp - window
         logits = jnp.where(mask, logits, NEG_INF)
         new_max = jnp.maximum(mx, logits.max(-1, keepdims=True))
         corr = jnp.exp(mx - new_max)
@@ -150,17 +174,19 @@ def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             preferred_element_type=jnp.float32)
         return num, den, new_max
 
-    carry = (jnp.zeros((b, h, lq, d), jnp.float32),
-             jnp.zeros((b, h, lq, 1), jnp.float32),
-             jnp.full((b, h, lq, 1), NEG_INF, jnp.float32))
+    carry = (jnp.zeros((b, hkv, lf, d), jnp.float32),
+             jnp.zeros((b, hkv, lf, 1), jnp.float32),
+             jnp.full((b, hkv, lf, 1), NEG_INF, jnp.float32))
     if block_size == t:
         num, den, _ = body(0, carry)
+    elif k_pos is not None:
+        num, den, _ = jax.lax.fori_loop(0, t // block_size, body, carry)
     else:
         n_blocks = jnp.minimum(
             (jnp.max(q_pos) + block_size) // block_size, t // block_size)
         num, den, _ = jax.lax.fori_loop(0, n_blocks, body, carry)
     out = num / jnp.maximum(den, 1e-30)
-    return jnp.moveaxis(out, 1, 2)
+    return jnp.moveaxis(out.reshape(b, h, lq, d), 1, 2)
 
 
 def cache_write_rows(cache: jax.Array, new: jax.Array,
@@ -226,54 +252,86 @@ def decode_attention_fits(t: int, d: int, dtype) -> bool:
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     pos: jax.Array,
-                     scale: Optional[float] = None) -> jax.Array:
+                     pos: jax.Array, scale: Optional[float] = None,
+                     window: Optional[int] = None) -> jax.Array:
     """The decode case of :func:`cached_attention` (one query a row,
     every row a slot) as one Pallas kernel, ``decode_attention`` in a
     device trace, that fetches of each row's cache only the blocks its
-    own length reaches. ``q`` (B, 1, H, D); the caches (B, H, T, D) as
-    they stand in the slab; ``pos`` (B,) int32: key ``j`` of row ``b``
+    own length reaches. ``q`` (B, 1, H, D); the caches (B, Hkv, T, D)
+    as they stand in the slab, ``H`` a multiple of ``Hkv`` (grouped
+    queries: the ``H / Hkv`` query heads of a key/value head are the
+    rows of one query tile); ``pos`` (B,) int32: key ``j`` of row ``b``
     is visible when ``j <= pos[b]``, and a row with ``pos[b] < 0`` sees
     nothing and yields zeros. Returns float32 (B, 1, H, D): the
     arithmetic of ``cached_attention(..., block_size=DECODE_BLOCK)``,
     operands in the cache's dtype, every sum and the softmax float32.
 
-    The grid is (rows, T // DECODE_BLOCK) with ``pos`` prefetched. A
-    row that needs ``n`` of its ``N`` blocks spends its first ``N - n``
-    grid steps on block 0 with the body skipped and then walks blocks 0
-    to ``n - 1``: consecutive steps on one block fetch nothing, so what
-    lies past a row's length never leaves HBM, and the row's first block
-    is copied under the row before's last product. (Idle steps at a
-    row's end would leave that copy exposed, a block a row.) A row
-    that sees nothing still has its block 0 fetched, once."""
+    With ``window`` the cache is a ring of ``T`` rows (``T >= window +
+    DECODE_BLOCK``): the token at position ``j`` lies in row ``j % T``
+    and is visible while ``pos[b] - window < j <= pos[b]``.
+
+    The grid is (rows, blocks a row can need) with ``pos`` prefetched.
+    A row that needs ``n`` blocks spends its first grid steps on its
+    first block with the body skipped and then walks its ``n`` blocks
+    (0 to ``n - 1``; in a ring from the block that holds its oldest
+    visible key, around the ring's end): consecutive steps on one block
+    fetch nothing, so what a row does not see never leaves HBM, and the
+    row's first block is copied under the row before's last product.
+    (Idle steps at a row's end would leave that copy exposed, a block a
+    row.) A row that sees nothing still has one block fetched, once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from netsdb_tpu.ops.common import pallas_interpret
 
     b, _, h, d = q.shape
-    t = k_cache.shape[2]
+    hkv, t = k_cache.shape[1], k_cache.shape[2]
     dt = k_cache.dtype
     block = DECODE_BLOCK
     if not decode_attention_fits(t, d, dt):
         raise ValueError(f"a {dt} cache of {t} tokens by {d} is not whole "
                          f"blocks of ({block}, {LANES})")
+    if window is not None and t < window + block:
+        raise ValueError(f"a ring of {t} rows cannot hold a window of "
+                         f"{window} and a block of {block}")
     n_all = t // block
-    rows = 32 // jnp.dtype(dt).itemsize      # a head's query, one tile
+    n_walk = n_all if window is None else -(-window // block) + 1
+    group = h // hkv
+    tile = 32 // jnp.dtype(dt).itemsize      # rows of one query tile
+    rows = -(-group // tile) * tile
     scale = scale if scale is not None else d ** -0.5
-    qs = (q.astype(jnp.float32) * scale).astype(dt).reshape(b, h, d)
+    qs = (q.astype(jnp.float32) * scale).astype(dt)
+    if group == 1:
+        qs = qs.reshape(b, h, d)     # broadcast to a tile in the kernel
+    else:
+        qs = jnp.pad(qs.reshape(b, hkv, group, d),
+                     ((0, 0), (0, 0), (0, rows - group), (0, 0)))
 
-    def block_at(j, last):
-        """The block of grid step ``j`` of a row whose last visible key
-        is ``last``; below 0 while the row idles."""
-        used = (jnp.maximum(last, -1) + block) // block
-        return j - (n_all - used)
+    def walk(last):
+        """(first block, blocks) that a row whose last visible key is
+        ``last`` reads."""
+        if window is None:
+            return 0, (jnp.maximum(last, -1) + block) // block
+        seen = jnp.clip(last + 1, 0, window)
+        oldest = jnp.maximum(last - window + 1, 0) % t
+        used = jnp.where(seen > 0,
+                         (oldest % block + seen + block - 1) // block, 0)
+        return oldest // block, used
+
+    def step_at(j, last):
+        """(which of its blocks a row reads at grid step ``j``, below 0
+        while it idles; that block's number in the cache)."""
+        first, used = walk(last)
+        at = j - (n_walk - used)
+        if window is None:
+            return at, jnp.maximum(at, 0)
+        return at, (first + jnp.maximum(at, 0)) % n_all
 
     def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, num_ref, den_ref,
                max_ref):
         row, j = pl.program_id(0), pl.program_id(1)
         last = pos_ref[row]
-        at = block_at(j, last)
+        at, blk = step_at(j, last)
 
         @pl.when(j == 0)
         def _():
@@ -283,17 +341,28 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
         @pl.when(at >= 0)
         def _():
-            # every head's query as the rows of one tile: the products
-            # are batched over the heads, (rows, D) x (block, D)^T
-            qr = jnp.broadcast_to(q_ref[0][:, None, :], (h, rows, d))
+            # the query heads of a key/value head as the rows of one
+            # tile (one head: its query in every row): the products are
+            # batched over the key/value heads, (rows, D) x (block, D)^T
+            if group == 1:
+                qr = jnp.broadcast_to(q_ref[0][:, None, :], (hkv, rows, d))
+            else:
+                qr = q_ref[0]
             logits = jax.lax.dot_general(
                 qr, k_ref[0], (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)    # (H, rows, block)
-            k_pos = at * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, block), 2)
-            # the block's first key is visible, so the new max is finite
-            # and a masked key's weight is exactly 0
-            logits = jnp.where(k_pos <= last, logits, NEG_INF)
+                preferred_element_type=jnp.float32)    # (Hkv, rows, block)
+            # (not a ring: a row's block ``at`` is the cache's, as it is)
+            k_row = (at if window is None else blk) * block \
+                + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block), 2)
+            # one key of the block at least is visible, so the new max
+            # is finite and a masked key's weight is exactly 0
+            if window is None:
+                seen = k_row <= last
+            else:
+                age = last % t - k_row          # back from the newest key
+                age = jnp.where(age < 0, age + t, age)
+                seen = age < jnp.minimum(last + 1, window)
+            logits = jnp.where(seen, logits, NEG_INF)
             mx = max_ref[...]
             new_max = jnp.maximum(mx, logits.max(-1, keepdims=True))
             corr = jnp.exp(mx - new_max)
@@ -301,37 +370,43 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             den_ref[...] = den_ref[...] * corr + p.sum(-1, keepdims=True)
             num_ref[...] = num_ref[...] * corr + jax.lax.dot_general(
                 p.astype(dt), v_ref[0], (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)    # (H, rows, D)
+                preferred_element_type=jnp.float32)    # (Hkv, rows, D)
             max_ref[...] = new_max
 
-        @pl.when(j == n_all - 1)
+        @pl.when(j == n_walk - 1)
         def _():
             out = num_ref[...] / jnp.maximum(den_ref[...], 1e-30)
-            o_ref[0] = out.max(1)        # a head's rows are all the same
+            if group == 1:
+                o_ref[0] = out.max(1)    # a head's rows are all the same
+            else:
+                o_ref[0] = out
 
     def cache_block(row, j, pos_ref):
-        return row, 0, jnp.maximum(block_at(j, pos_ref[row]), 0), 0
+        return row, 0, step_at(j, pos_ref[row])[1], 0
 
     def whole_row(row, j, pos_ref):
-        return row, 0, 0
+        return (row, 0, 0) if group == 1 else (row, 0, 0, 0)
 
+    q_block = (1, h, d) if group == 1 else (1, hkv, rows, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(b, n_all),
-        in_specs=[pl.BlockSpec((1, h, d), whole_row),
-                  pl.BlockSpec((1, h, block, d), cache_block),
-                  pl.BlockSpec((1, h, block, d), cache_block)],
-        out_specs=pl.BlockSpec((1, h, d), whole_row),
-        scratch_shapes=[pltpu.VMEM((h, rows, d), jnp.float32),
-                        pltpu.VMEM((h, rows, 1), jnp.float32),
-                        pltpu.VMEM((h, rows, 1), jnp.float32)])
+        num_scalar_prefetch=1, grid=(b, n_walk),
+        in_specs=[pl.BlockSpec(q_block, whole_row),
+                  pl.BlockSpec((1, hkv, block, d), cache_block),
+                  pl.BlockSpec((1, hkv, block, d), cache_block)],
+        out_specs=pl.BlockSpec(q_block, whole_row),
+        scratch_shapes=[pltpu.VMEM((hkv, rows, d), jnp.float32),
+                        pltpu.VMEM((hkv, rows, 1), jnp.float32),
+                        pltpu.VMEM((hkv, rows, 1), jnp.float32)])
     # keys and values, each double-buffered, and room for the body
-    vmem = 4 * h * block * d * jnp.dtype(dt).itemsize + (8 << 20)
+    vmem = 4 * hkv * block * d * jnp.dtype(dt).itemsize + (8 << 20)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b,) + q_block[1:], jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         name="decode_attention", interpret=pallas_interpret())(
             pos.astype(jnp.int32), qs, k_cache, v_cache)
+    if group > 1:
+        out = out[:, :, :group].reshape(b, h, d)
     return out[:, None]
 
 

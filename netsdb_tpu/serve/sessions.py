@@ -835,7 +835,8 @@ class SessionManager:
             t["n"] -= 1
             t["unread"] += 1
         lane["steps"].append({"outs": outs, "turns": list(ready),
-                              "t0": t0, "lane": lane})
+                              "t0": t0, "lane": lane,
+                              "counts": self.runtime.step_counts(db)})
         obs.record_into(ready[0]["trace"], "session.device",
                         time.perf_counter() - t0, "serve")
         obs.REGISTRY.counter("session.decode_steps").inc(len(ready))
@@ -861,6 +862,17 @@ class SessionManager:
         while not outs[key].is_ready():
             time.sleep(0.0005)
         host = np.asarray(outs[key])
+        counts = step["counts"]
+        if counts:
+            # an expert model's routing counts ride after its ids
+            moe = dict(zip(counts["names"], host[-len(counts["names"]):]))
+            obs.REGISTRY.counter("decode.moe.pairs").inc(int(moe["pairs"]))
+            obs.REGISTRY.counter("decode.moe.experts_touched").inc(
+                int(moe["experts_touched"]))
+            obs.REGISTRY.counter("decode.moe.experts_held").inc(
+                counts["experts_held"])
+            obs.REGISTRY.gauge("decode.moe.max_load").set(
+                int(moe["max_load"]))
         for t in turns:
             t["outs"].append(host[t["slot"]])
             t["unread"] -= 1

@@ -66,10 +66,20 @@ _BENCH_TESTS = os.path.join(os.path.dirname(_TESTS_DIR), "benchmark", "tests")
 # PR 27 gave plan_s_per_req and executor_s_per_req a `workloads` list that
 # leaves the waiting cell out; this case still expects both there (PERF.md
 # §7 item 23). The fix is an edit under benchmark/, a `benchmark` PR's
-# (ROADMAP D8); until then tier-1 runs the other 33 cases.
+# (ROADMAP D8); until then tier-1 runs the other cases.
 _BENCH_DESELECTED = (
     "test_harness.py::test_traced_rehearsal_reports_per_layer_metrics"
-    "[tpch30-fold-outofcore]")
+    "[tpch30-fold-outofcore]",
+    # PR 30's case holds that its metric is the LAST entry of `per_layer`,
+    # which the next PR to append a metric (PR 35, as the contract makes
+    # it: new entries at the end) makes untrue. What else it holds (listed
+    # for the older sessions cell alone; its rehearsal reads the whole
+    # pass, 1.0) is held by `benchmark/tests/test_trinity_cell.py` since;
+    # dropping the line is an edit under benchmark/ (PERF.md §7, 27): the
+    # FIRST item of the next `benchmark` PR (ROADMAP S0), which deletes
+    # this entry. Nothing more is to be deselected this way.
+    "test_attn_cache_fetch_share.py::test_it_is_listed_for_the_sessions_"
+    "cell_alone_and_the_rehearsal_reads_the_whole_pass")
 
 
 def _collect_benchmark_tests(config):
@@ -85,7 +95,7 @@ def _collect_benchmark_tests(config):
     config.args.append(_BENCH_TESTS)
     rel = os.path.relpath(_BENCH_TESTS, str(config.rootpath))
     config.option.deselect = list(config.option.deselect or ()) + [
-        rel.replace(os.sep, "/") + "/" + _BENCH_DESELECTED]
+        rel.replace(os.sep, "/") + "/" + case for case in _BENCH_DESELECTED]
 
 
 def pytest_configure(config):

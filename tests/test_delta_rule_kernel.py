@@ -14,6 +14,14 @@ Since PR 34 the chunked form of prefill has a kernel too
 (``gated_delta_chunked`` -> ``gdn_chunk``): held here against its XLA
 body and the token recurrence, and the benchmark's two prefill
 programs are compiled for the described v5e like the step program.
+
+Since PR 35 the benchmark has a second model behind the same block
+module (sparse experts, sliding and full attention: ``tests/
+test_afmoe_lm.py``); its step program and its 1,024-token prefill
+program are compiled here for the same described chip, with the
+grouped-query ring form of ``decode_attention`` and the experts' kernel
+``grouped_ffn``, and the benchmark's predicate for "an operation that
+reads expert matrices" is held to the kernel and to its XLA form.
 """
 
 import importlib.util
@@ -342,15 +350,19 @@ def _load(name, path):
     return module
 
 
-def _benchmarks_model(one_chip):
-    """(cfg, spec, params, slab, slab bytes) of
-    ``benchmark/configs/olmo-hybrid-7b-16l.json``, the spec as the
-    benchmark's deployment makes it from its file, the arrays as shapes
-    on the described chip."""
-    deployment = _load("bench_lm_sessions",
-                       os.path.join(BENCH, "deployments", "lm_sessions.py"))
-    with open(os.path.join(BENCH, "configs",
-                           "olmo-hybrid-7b-16l.json")) as f:
+def _benchmarks_model(one_chip, deployment="lm_sessions",
+                      config="olmo-hybrid-7b-16l"):
+    """(cfg, spec, params, slab, slab bytes) of a configuration of the
+    benchmark (``benchmark/configs/olmo-hybrid-7b-16l.json``), the spec
+    as the benchmark's deployment makes it from its file, the arrays as
+    shapes on the described chip."""
+    import sys
+
+    sys.path.insert(0, BENCH)     # a deployment imports the harness's own
+    deployment = _load("bench_" + deployment, os.path.join(
+        BENCH, "deployments", deployment + ".py"))
+    sys.path.remove(BENCH)
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
         cfg = json.load(f)
     spec = deployment.spec_of(cfg)
 
@@ -455,6 +467,93 @@ def test_the_benchmarks_prefill_programs_compile_for_the_v5e(
     assert not re.search(r"\bwhile\(", text)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= slab_bytes
+
+
+def _sparse_model(one_chip):
+    return _benchmarks_model(one_chip, "lm_sessions_moe",
+                             "trinity-large-ep8-5l")
+
+
+def test_the_sparse_models_step_program_compiles_for_the_v5e(one_chip,
+                                                            monkeypatch):
+    """The decode step of ``benchmark/configs/trinity-large-ep8-5l.json``:
+    one ``decode_attention`` call an attention layer (four over rings,
+    one over the full cache), one ``grouped_ffn`` an expert layer, which
+    the benchmark's predicate finds and no other call, the slab written
+    in place, 8.64 GB of weights."""
+    moe_work = _load("bench_moe_work", os.path.join(BENCH, "moe_work.py"))
+    cfg, spec, params, slab, slab_bytes, arg = _sparse_model(one_chip)
+    assert (hybrid_lm.cache_rows(spec), hybrid_lm.cache_rows(
+        spec, hybrid_lm.SLIDING), spec["slots"]) == (17408, 5120, 32)
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert round(weights / 1e9, 2) == 8.64 and round(slab_bytes / 1e9,
+                                                     2) == 4.97
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_step(spec),
+        (params, slab, arg((32,), "bool")), spec.get("xla_options"))
+    assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == 5
+    text = compiled.as_text()
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = _custom_calls(text)
+    assert len([c for c in names if "decode_attention" in c]) == 5
+    assert len([c for c in names if "cache_write_rows" in c]) == 10
+    assert len([c for c in names if "grouped_ffn" in c]) == 4
+    assert len(names) == 19
+    for line in lines:
+        assert moe_work.touches_experts(line, cfg) == ("grouped_ffn" in line)
+    # no array of a logit a cached token: no whole pass over a cache
+    assert "f32[32,8,5120]" not in text and "f32[32,8,17408]" not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
+    assert memory.temp_size_in_bytes < slab_bytes // 100
+
+
+def test_the_sparse_models_prefill_program_compiles_for_the_v5e(
+        one_chip, monkeypatch):
+    """Its 1,024-token prefill program: the experts' kernel on the three
+    expert layers whose feed-forward a prompt needs (the last layer's
+    feeds only the head), the slab written in place, and what it needs
+    beside weights and slab fits the chip."""
+    cfg, spec, params, slab, slab_bytes, arg = _sparse_model(one_chip)
+    scalar = arg((), "int32")
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_prefill(spec, 1024),
+        (params, slab, scalar, arg((1024,), "int32"), scalar, scalar),
+        spec.get("xla_options"))
+    names = _custom_calls(compiled.as_text())
+    assert len(names) == 3 and all("grouped_ffn" in c for c in names)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
+def test_the_experts_xla_form_is_found_by_the_benchmarks_predicate(
+        one_chip, monkeypatch):
+    """The other side of the fallback: the grouped product in plain XLA
+    at the published widths (8 tiles of 16 rows), compiled for the chip;
+    the operations that read the gathered expert matrices match."""
+    from netsdb_tpu.ops import experts
+
+    moe_work = _load("bench_moe_work", os.path.join(BENCH, "moe_work.py"))
+    cfg, spec, _, _, _, arg = _sparse_model(one_chip)
+    d, f, held = 3072, 3072, 32
+
+    def product(xs, slab, tile_expert, used, w_gate_up, w_down):
+        del slab
+        return experts.grouped_ffn_xla(xs, tile_expert, used, w_gate_up,
+                                       w_down, 16)
+
+    compiled = _compile_for_the_chip(
+        monkeypatch, product,
+        (arg((128, d), "float32"), arg((1,), "int32"), arg((8,), "int32"),
+         arg((), "int32"), arg((held, 2, f, d), "bfloat16"),
+         arg((held, d, f), "bfloat16")), None)
+    found = [line for line in compiled.as_text().splitlines()
+             if moe_work.touches_experts(line, cfg)]
+    assert any(re.search(r"\b(fusion|convolution|dot)\(", line)
+               for line in found)
 
 
 def test_the_three_bfloat16_pieces_sum_to_the_value_exactly():
