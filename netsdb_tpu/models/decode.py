@@ -373,6 +373,11 @@ class _HybridKind:
 
         return hybrid_lm.cache_rows_read(spec, lengths)
 
+    def prefill_blocks_read(self, spec, chunk, pos0, n_valid):
+        from netsdb_tpu.models import hybrid_lm
+
+        return hybrid_lm.prefill_blocks_read(spec, chunk, pos0, n_valid)
+
     def step_counts(self, spec):
         from netsdb_tpu.models import hybrid_lm
 
@@ -731,6 +736,15 @@ class DecodeRuntime:
         caches by one step whose live slots see ``lengths`` keys."""
         reg = self._reg(db)
         return reg["kind"].cache_rows_read(reg["spec"], lengths)
+
+    def prefill_blocks_read(self, db: str, chunk: int, pos0: int,
+                            n_valid: int) -> Tuple[int, int]:
+        """(key blocks read, key blocks held) by the attention of one
+        prefill chunk of a language model onto a slot at ``pos0``
+        tokens."""
+        reg = self._reg(db)
+        return reg["kind"].prefill_blocks_read(reg["spec"], chunk, pos0,
+                                               n_valid)
 
     def step_counts(self, db: str) -> Dict[str, int]:
         """What a step of ``db`` returns after its slots' ids, by name,

@@ -89,7 +89,10 @@ import numpy as np
 from netsdb_tpu import obs
 from netsdb_tpu.ops.attention import (DECODE_BLOCK, cache_write_rows,
                                       cached_attention, decode_attention,
-                                      decode_attention_fits)
+                                      decode_attention_fits,
+                                      prefill_attention,
+                                      prefill_attention_fits, prefill_tiles,
+                                      prefill_walk)
 from netsdb_tpu.ops import experts
 from netsdb_tpu.ops.delta_rule import (chunk_kernel_fits,
                                        gated_delta_chunked,
@@ -105,9 +108,9 @@ STEP_COUNTS = ("pairs", "experts_touched", "max_load")
 #: rows of a tile of the grouped expert product: a decode step's few
 #: pairs an expert, a prefill chunk's many
 STEP_TILE, PREFILL_TILE = 16, 64
-#: a prefill chunk's attention reads a slot's cache in one pass while its
-#: float32 scores stay under this many bytes, else in blocks of
-#: ``PREFILL_ATTN_BLOCK`` keys
+#: where a prefill chunk's attention is not the kernel (``_fused``): it
+#: reads a slot's cache in one pass while its float32 scores stay under
+#: this many bytes, else in blocks of ``PREFILL_ATTN_BLOCK`` keys
 PREFILL_ATTN_WHOLE = 1 << 29
 PREFILL_ATTN_BLOCK = 1024
 
@@ -226,6 +229,42 @@ def cache_rows_read(spec, lengths) -> Tuple[int, int]:
         fetched_all += layers * fetched
         held_all += layers * held
     return fetched_all, held_all
+
+
+def _fused(spec, chunk: int, kind: str = FULL) -> bool:
+    """Whether a prefill chunk's attention over a layer of this type is
+    the kernel that keeps the scores on the chip: the shapes decide."""
+    return prefill_attention_fits(chunk, cache_rows(spec, kind),
+                                  spec["head_dim"], spec["dtype"])
+
+
+def prefill_blocks_read(spec, chunk: int, pos0: int,
+                        n_valid: int) -> Tuple[int, int]:
+    """(key blocks read, key blocks held) by the attention of ONE
+    prefill chunk of ``chunk`` queries, ``n_valid`` of which count, onto
+    a slot at ``pos0`` tokens: a block is the kernel's (``prefill_tiles``)
+    of one key/value head of one layer, counted once a query tile, by
+    layer type, over the layers whose attention a prefill program
+    computes (the last layer's feeds nothing). The kernel reads what
+    ``prefill_walk`` gives a tile; held is every block of the slot's
+    cache a tile, and what the block walk in plain XLA reads."""
+    read_all = held_all = 0
+    group = spec["heads"] // _kv_heads(spec)
+    for kind in (FULL, SLIDING):
+        layers = sum(t == kind for t in spec["layer_types"][:-1])
+        if not layers:
+            continue
+        rows = cache_rows(spec, kind)
+        tq, bk = prefill_tiles(chunk, rows, group, spec["dtype"])
+        held = read = chunk // tq * (rows // bk)
+        if _fused(spec, chunk, kind):
+            window = spec["window"] if kind == SLIDING else None
+            read = sum(int(prefill_walk(np, qi, pos0, n_valid, tq, bk, rows,
+                                        window)[1])
+                       for qi in range(chunk // tq))
+        read_all += layers * _kv_heads(spec) * read
+        held_all += layers * _kv_heads(spec) * held
+    return read_all, held_all
 
 
 def step_counts(spec) -> Dict[str, int]:
@@ -677,6 +716,8 @@ def build_prefill(spec, chunk: int):
         # runs when the program is traced, once a compiled program
         obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers").set(
             sum(t == LINEAR for t in types) if fits else 0)
+        obs.REGISTRY.gauge("prefill.attn.fused_layers").set(
+            sum(t != LINEAR and _fused(spec, chunk, t) for t in types))
         slab = dict(slab)
         conv = slab.get("conv")          # None: no linear layer
         pos, tok = slab["pos"], slab["tok"]
@@ -738,12 +779,21 @@ def build_prefill(spec, chunk: int):
 
                 kc, vc = put(slab[f"k{fi}"], k), put(slab[f"v{fi}"], v)
                 slab[f"k{fi}"], slab[f"v{fi}"] = kc, vc
-                o = cached_attention(
-                    q[None], kc, vc, jnp.where(valid, at, -1)[None],
-                    block_size=_prefill_attn_block(spec, chunk, rows),
-                    row0=slot, window=window,
-                    k_pos=(_ring_pos(pos0 + chunk - 1, rows) if window
-                           else None))
+                if i == last:
+                    break     # the last layer's output feeds only the head
+                if _fused(spec, chunk, kind):
+                    # the scores stay on the chip, and of the slot's
+                    # cache only the blocks some query of the chunk sees
+                    # are read
+                    o = prefill_attention(q, kc, vc, slot, pos0, n_valid,
+                                          window=window)
+                else:
+                    o = cached_attention(
+                        q[None], kc, vc, jnp.where(valid, at, -1)[None],
+                        block_size=_prefill_attn_block(spec, chunk, rows),
+                        row0=slot, window=window,
+                        k_pos=(_ring_pos(pos0 + chunk - 1, rows) if window
+                               else None))
                 mix = _attn_out(p, pre, o.reshape(chunk, heads * hd), gate)
                 fi += 1
             h = x_in + _rms(mix, p[pre + "norm_mix"], eps)

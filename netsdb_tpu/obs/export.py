@@ -323,6 +323,23 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                   "same steps and layers (slots x "
                                   "rows a slot): what a whole pass "
                                   "over every slot's cache reads"),
+        ("prefill.attn.key_blocks_read", "key blocks (the prefill "
+                                         "attention kernel's, of one "
+                                         "key/value head of one layer, "
+                                         "once a query tile) that the "
+                                         "prefill chunks' attention "
+                                         "read, by layer type: with the "
+                                         "kernel what some query of a "
+                                         "tile sees, with the walk in "
+                                         "plain XLA all a slot's cache "
+                                         "holds; counted a chunk on the "
+                                         "host from the scheduler's own "
+                                         "position and count"),
+        ("prefill.attn.key_blocks_held", "key blocks a slot's caches "
+                                         "held for the same chunks, "
+                                         "layers and query tiles: what "
+                                         "a walk over the whole cache "
+                                         "reads"),
         ("decode.moe.pairs", "(token, expert) pairs the decode steps "
                              "routed to experts held here, summed "
                              "over the expert layers; returned by the "
@@ -395,6 +412,17 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("decode.moe.max_load", "pairs of the busiest held expert in "
                                 "the last decode step read (the "
                                 "largest over its expert layers)"),
+        ("prefill.attn.fused_layers", "attention layers of the last "
+                                      "traced hybrid_lm prefill "
+                                      "program whose shapes take the "
+                                      "kernel that keeps a chunk's "
+                                      "scores in VMEM and reads of the "
+                                      "slot's cache only what some "
+                                      "query sees (0: the chunk's or "
+                                      "the cache's shape took the walk "
+                                      "in plain XLA); the last layer "
+                                      "counts though a prefill program "
+                                      "computes no attention there"),
         ("decode.attn.ragged_layers", "attention layers of the "
                                       "last traced hybrid_lm step "
                                       "program whose attention is the "

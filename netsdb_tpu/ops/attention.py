@@ -410,6 +410,259 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out[:, None]
 
 
+# a prompt chunk's attention kernel: the most rows (a key/value head's
+# query heads x tokens) of one query tile, and the key blocks it tries,
+# first to last. On the chip what a step costs beside its two products
+# grows with the tile's rows, so long blocks pay: 512 keys took twice
+# 1,024's time, 1,536 two thirds of 512's; past that a tile computes
+# more keys than its queries see (PERF.md section 6, PR 36)
+PREFILL_TILE_ROWS = 1536
+PREFILL_BLOCKS = (1536, 1024, 512, DECODE_BLOCK)
+
+
+def _query_tile(dtype) -> int:
+    """Rows of one (sublanes, 128) tile of a query in ``dtype``."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def prefill_attention_fits(chunk: int, t: int, d: int, dtype) -> bool:
+    """Whether :func:`prefill_attention` takes a chunk of ``chunk``
+    queries over caches of ``t`` tokens and ``head_dim`` ``d``: what
+    :func:`decode_attention` takes (whole lanes, whole key blocks), and
+    query tiles of whole (16, 128) tiles of a bfloat16 query ((8, 128)
+    of a float32 one)."""
+    return (decode_attention_fits(t, d, dtype)
+            and chunk % _query_tile(dtype) == 0)
+
+
+def prefill_tiles(chunk: int, t: int, group: int, dtype) -> tuple:
+    """(tokens of a query tile, keys of a block) that
+    :func:`prefill_attention` takes for such a chunk and cache: the
+    whole chunk a tile, halved while its ``group`` query heads make
+    more than ``PREFILL_TILE_ROWS`` rows; the first of
+    ``PREFILL_BLOCKS`` that divides the cache."""
+    tq, least = chunk, 2 * _query_tile(dtype)
+    while group * tq > PREFILL_TILE_ROWS and tq % least == 0:
+        tq //= 2
+    return tq, next(b for b in PREFILL_BLOCKS if t % b == 0)
+
+
+def prefill_walk(xp, qi, pos0, n_valid, tq: int, bk: int, t: int,
+                 window: Optional[int]):
+    """(first block, blocks) of the walk of query tile ``qi`` (``tq``
+    tokens) of :func:`prefill_attention` around a cache of ``t`` rows in
+    blocks of ``bk``: a full cache from its first block to the block of
+    the tile's last query that counts, a ring from the block of the
+    oldest key the tile's first query sees, and no block where none of
+    its queries counts. ``xp`` is ``jax.numpy`` in the kernel and its
+    block map, and ``numpy`` where the host counts what a chunk reads
+    (``models/hybrid_lm.py::prefill_blocks_read``): one arithmetic."""
+    i0 = qi * tq
+    live = xp.clip(n_valid - i0, 0, tq)          # its queries that count
+    newest = pos0 + i0 + live - 1
+    if window is None:
+        first, used = 0, newest // bk + 1
+    else:
+        oldest = xp.maximum(pos0 + i0 - window + 1, 0)
+        first = oldest % t // bk
+        used = (oldest % t % bk + newest - oldest + bk) // bk
+    return first, xp.where(live > 0, xp.minimum(used, t // bk), 0)
+
+
+def prefill_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                      slot, pos0, n_valid, scale: Optional[float] = None,
+                      window: Optional[int] = None) -> jax.Array:
+    """The chunked-prefill case of :func:`cached_attention` (a chunk of
+    queries over ONE slot's cache) as one Pallas kernel,
+    ``prefill_attention`` in a device trace, that keeps the online
+    softmax in VMEM: no score of a (query, key) pair reaches HBM.
+
+    ``q`` (C, H, D) float32, the chunk's queries at positions ``pos0``
+    to ``pos0 + C - 1``, of which the first ``n_valid`` count; the
+    caches (slots, Hkv, T, D) as they stand in the slab with the chunk's
+    keys and values already written, ``H`` a multiple of ``Hkv``; ``slot``,
+    ``pos0`` and ``n_valid`` traced scalars. Key ``j`` is visible to
+    query ``i < n_valid`` while ``j <= pos0 + i``; a query past
+    ``n_valid`` yields zeros. With ``window`` the cache is a ring of
+    ``T`` rows (``T >= window + C``) written up to position ``pos0 + C -
+    1``: the token at position ``j`` lies in row ``j % T`` and is
+    visible while ``pos0 + i - window < j <= pos0 + i``. Returns float32
+    (C, H, D): the arithmetic of ``cached_attention(..., block_size=bk)``
+    block for block in the order of the cache's rows (``q`` scaled in
+    float32 and cast to the cache's dtype, operands in that dtype, every
+    sum and the softmax float32), ``bk`` by :func:`prefill_tiles`.
+
+    The grid is (key/value heads, query tiles, blocks a tile can need)
+    with ``slot``, ``pos0`` and ``n_valid`` prefetched. The ``H / Hkv``
+    query heads of a key/value head are the rows of one query tile
+    (``tq`` tokens each), held against one ``(bk, D)`` block of that
+    head's keys at a time. Queries and result keep a token's heads side
+    by side, ``(C, H x D)``: a key/value head's query heads are whole
+    lanes of a ``(tq, H / Hkv x D)`` block, stacked to the tile's rows
+    in VMEM, so nothing is re-laid in HBM on either side of the call.
+    A tile that needs ``n`` blocks (a full cache: up to the block of its
+    last query that counts; a ring: from the block of the oldest key its
+    first query sees) spends its first grid steps on its first block
+    with the body skipped and then walks its ``n`` blocks; a tile wholly
+    past ``n_valid`` stays on the block before it and does no product.
+    Consecutive steps on one block fetch nothing, so what no query of a
+    tile sees never leaves HBM. A block that every query of the tile
+    sees whole takes no mask."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from netsdb_tpu.ops.common import pallas_interpret
+
+    chunk, h, d = q.shape
+    hkv, t = k_cache.shape[1], k_cache.shape[2]
+    dt = k_cache.dtype
+    if not prefill_attention_fits(chunk, t, d, dt):
+        raise ValueError(f"a chunk of {chunk} queries over a {dt} cache of "
+                         f"{t} tokens by {d} is not whole tiles and blocks "
+                         f"of ({DECODE_BLOCK}, {LANES})")
+    if window is not None and t < window + chunk:
+        raise ValueError(f"a ring of {t} rows cannot hold a window of "
+                         f"{window} and a chunk of {chunk}")
+    group = h // hkv
+    tq, bk = prefill_tiles(chunk, t, group, dt)
+    rows, n_q, n_all = group * tq, chunk // tq, t // bk
+    n_walk = n_all if window is None else min(
+        n_all, -(-(window + tq - 1) // bk) + 1)
+    scale = scale if scale is not None else d ** -0.5
+    # a token's heads side by side: a key/value head's query heads are
+    # whole lanes of a (tokens, group x D) block, as the result's are
+    qs = (q.astype(jnp.float32) * scale).astype(dt).reshape(chunk, h * d)
+
+    def step_at(qi, j, pos0, n_valid):
+        """(which of its blocks the tile reads at grid step ``j``, below
+        0 while it idles or where no query of it counts; that block's
+        number in the cache). The blocks are taken in the order of the
+        cache's rows: a ring's walk that passes the ring's end starts
+        at row 0."""
+        last_live = jnp.maximum(n_valid - 1, 0) // tq
+        first, used = prefill_walk(jnp, jnp.minimum(qi, last_live), pos0,
+                                   n_valid, tq, bk, t, window)
+        at = j - (n_walk - used)
+        # a tile past n_valid stays where the tile before it ended
+        held = jnp.maximum(jnp.where(qi > last_live, used - 1, at), 0)
+        low = jnp.maximum(first + used - n_all, 0)
+        return (jnp.where(qi > last_live, -1, at),
+                jnp.where(held < low, held, first + held - low))
+
+    def kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, q_rows, num_ref,
+               den_ref, max_ref):
+        qi, j = pl.program_id(1), pl.program_id(2)
+        pos0, n_valid = meta_ref[1], meta_ref[2]
+        at, blk = step_at(qi, j, pos0, n_valid)
+        i0, row0 = qi * tq, blk * bk
+        newest = pos0 + chunk - 1          # the ring is written up to it
+        ring_at = newest % t
+
+        def query_rows():
+            i = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            return i0 + (i % tq if group > 1 else i)
+
+        def fold(seen):
+            logits = jax.lax.dot_general(
+                q_rows[...], k_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (rows, bk)
+            if seen is not None:
+                logits = jnp.where(seen, logits, NEG_INF)
+            mx = max_ref[...]
+            new_max = jnp.maximum(mx, logits.max(-1, keepdims=True))
+            corr = jnp.exp(mx - new_max)
+            p = jnp.exp(logits - new_max)
+            if seen is not None:
+                p = jnp.where(seen, p, 0.0)
+            den_ref[...] = den_ref[...] * corr + p.sum(-1, keepdims=True)
+            num_ref[...] = num_ref[...] * corr + jax.lax.dot_general(
+                p.astype(dt), v_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (rows, D)
+            max_ref[...] = new_max
+
+        # whether every query of the tile sees every key of the block
+        # (queries past n_valid see what they like: zeroed at the end)
+        if window is None:
+            clear = row0 + bk - 1 <= pos0 + i0
+        else:
+            # a ring row's age: how far its token lies behind the newest
+            age0 = jnp.where(ring_at < row0, ring_at - row0 + t,
+                             ring_at - row0)
+            ages_fall = (ring_at < row0) | (ring_at >= row0 + bk - 1)
+            clear = (ages_fall & (age0 - (bk - 1) >= chunk - 1 - i0)
+                     & (age0 < chunk - i0 - tq + window) & (age0 <= newest))
+
+        @pl.when(j == 0)
+        def _():
+            # the tile's rows: query head after query head
+            for g in range(group):
+                q_rows[g * tq:(g + 1) * tq] = q_ref[:, g * d:(g + 1) * d]
+            num_ref[...] = jnp.zeros(num_ref.shape, jnp.float32)
+            den_ref[...] = jnp.zeros(den_ref.shape, jnp.float32)
+            max_ref[...] = jnp.full(max_ref.shape, NEG_INF, jnp.float32)
+
+        @pl.when((at >= 0) & clear)
+        def _():
+            fold(None)
+
+        @pl.when((at >= 0) & jnp.logical_not(clear))
+        def _():
+            key_row = row0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            if window is None:
+                seen = key_row <= pos0 + query_rows()
+            else:
+                age = ring_at - key_row
+                age = jnp.where(age < 0, age + t, age)
+                # a row that holds no token yet is older than any window
+                age = jnp.where(age <= newest, age, 1 << 30)
+                behind = chunk - 1 - query_rows()    # the query's own age
+                seen = (age >= behind) & (age < behind + window)
+            fold(seen)
+
+        @pl.when(j == n_walk - 1)
+        def _():
+            out = num_ref[...] / jnp.maximum(den_ref[...], 1e-30)
+            out = jnp.where(query_rows() < n_valid, out, 0.0)
+            for g in range(group):
+                o_ref[:, g * d:(g + 1) * d] = out[g * tq:(g + 1) * tq]
+
+    def cache_block(hd, qi, j, meta_ref):
+        return (meta_ref[0], hd,
+                step_at(qi, j, meta_ref[1], meta_ref[2])[1], 0)
+
+    def tile(hd, qi, j, meta_ref):
+        return qi, hd
+
+    item = jnp.dtype(dt).itemsize
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(hkv, n_q, n_walk),
+        in_specs=[pl.BlockSpec((tq, group * d), tile),
+                  pl.BlockSpec((None, None, bk, d), cache_block),
+                  pl.BlockSpec((None, None, bk, d), cache_block)],
+        out_specs=pl.BlockSpec((tq, group * d), tile),
+        scratch_shapes=[pltpu.VMEM((rows, d), dt),
+                        pltpu.VMEM((rows, d), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32)])
+    # keys and values, queries and the result, each double-buffered, the
+    # sums, and the body: a tile's scores, their exponent, its cast and
+    # the mask
+    vmem = (4 * bk * d * item + 2 * rows * d * (item + 4) + rows * d * item
+            + 4 * rows * (d + 2 * LANES) + 16 * rows * bk + (4 << 20))
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((chunk, h * d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem,
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="prefill_attention", interpret=pallas_interpret())(
+            jnp.stack([jnp.asarray(slot, jnp.int32),
+                       jnp.asarray(pos0, jnp.int32),
+                       jnp.asarray(n_valid, jnp.int32)]),
+            qs, k_cache, v_cache)
+    return out.reshape(chunk, h, d)
+
+
 def split_qkv_heads(qkv: jax.Array, num_heads: int):
     """Packed (B,S,3E) projection → q/k/v (B,H,S,D) — THE layout
     convention (split into thirds, then head reshape/transpose); every

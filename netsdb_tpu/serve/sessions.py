@@ -748,7 +748,8 @@ class SessionManager:
                  n: int) -> List[Tuple[np.ndarray, int, int]]:
         """The prefill chunks of a language-model turn: ``[(ids of one
         chunk length, how many count, the id that becomes the slot's
-        next input or -1)]``. The sequence to consume is the id the
+        next input or -1, the tokens the slot holds before the
+        chunk)]``. The sequence to consume is the id the
         last frame left unconsumed (-1 stands for it: the program reads
         it from the slab) and then ``tokens``; all of it but its last id
         goes through prefill, the last is the first decode step's
@@ -771,12 +772,14 @@ class SessionManager:
         plan = self.runtime.plan_prefill(db, len(body))
         if not plan and last >= 0:
             plan = [(spec["prefill_chunks"][0], 0)]   # only sets tok
-        chunks, at = [], 0
+        # the slot holds the history but the id left unconsumed
+        chunks, at, held = [], 0, max(step - 1, 0)
         for j, (size, count) in enumerate(plan):
             ids = np.zeros(size, np.int32)
             ids[:count] = body[at:at + count]
+            chunks.append((ids, count, last if j == len(plan) - 1 else -1,
+                           held + at))
             at += count
-            chunks.append((ids, count, last if j == len(plan) - 1 else -1))
         return chunks
 
     def _reseat(self, db: str, turn: Dict[str, Any]) -> None:
@@ -797,12 +800,16 @@ class SessionManager:
 
     def _prefill_chunk(self, db: str, slab: SessionSlab,
                        turn: Dict[str, Any]) -> None:
-        ids, count, next_tok = turn["chunks"].pop(0)
+        ids, count, next_tok, pos0 = turn["chunks"].pop(0)
         t0 = time.perf_counter()
         with slab.mu:
             slab.arrays = self.runtime.prefill(
                 db, slab.arrays, turn["slot"], ids, count, next_tok)
         obs.REGISTRY.counter("session.prefill_tokens").inc(count)
+        read, held = self.runtime.prefill_blocks_read(db, len(ids), pos0,
+                                                      count)
+        obs.REGISTRY.counter("prefill.attn.key_blocks_read").inc(read)
+        obs.REGISTRY.counter("prefill.attn.key_blocks_held").inc(held)
         # the host's dispatch only: the program runs behind it, and its
         # device seconds are the device trace's to give
         obs.record_into(turn["trace"], "session.prefill",

@@ -435,36 +435,56 @@ def test_the_benchmarks_step_program_compiles_for_the_v5e(one_chip,
     assert memory.temp_size_in_bytes < slab_bytes // 100
 
 
+def _no_scores_reach_hbm(text, heads, chunk, caches):
+    """No loop over key blocks, and no array of a score a (query, key)
+    pair: queries (or ``chunk`` times a key/value head's query heads)
+    by a cache's rows or by a block of them."""
+    assert not re.search(r"\bwhile\(", text)
+    keys = "|".join(str(k) for t in caches
+                    for k in (t, 1024, 512, 256) if t % k == 0)
+    assert not re.search(
+        rf"f32\[(\d+,)+({chunk}|{heads * chunk}),({keys})\]", text)
+
+
 @pytest.mark.parametrize("chunk", [128, 512])
 def test_the_benchmarks_prefill_programs_compile_for_the_v5e(
         one_chip, monkeypatch, chunk):
     """The two prefill programs of the same model: the chunked rule is
     one ``gdn_chunk`` call a linear layer, whose text carries a shape
     the benchmark's roofline looks for
-    (``benchmark/lm_work.py::touches_chunk_solve``), and neither a
-    triangular solve nor a loop over delta-chunks is left."""
+    (``benchmark/lm_work.py::touches_chunk_solve``), a chunk's attention
+    one ``prefill_attention`` call a full layer but the last (whose
+    output feeds only the head), and neither a triangular solve, a loop
+    nor an array of scores is left."""
     lm_work = _load("bench_lm_work", os.path.join(BENCH, "lm_work.py"))
     cfg, spec, params, slab, slab_bytes, arg = _benchmarks_model(one_chip)
     assert chunk in spec["prefill_chunks"]
     linear = spec["layer_types"].count(hybrid_lm.LINEAR)
+    full = spec["layer_types"].count(hybrid_lm.FULL)
     scalar = arg((), "int32")
     gauge = obs.REGISTRY.gauge("prefill.gdn_chunk.fused_layers")
+    fused = obs.REGISTRY.gauge("prefill.attn.fused_layers")
     gauge.set(-1)
+    fused.set(-1)
     compiled = _compile_for_the_chip(
         monkeypatch, hybrid_lm.build_prefill(spec, chunk),
         (params, slab, scalar, arg((chunk,), "int32"), scalar, scalar),
         spec["xla_options"])
     assert gauge.value == linear == 12
+    assert fused.value == full == 4
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == linear
-    for line in calls:
-        assert "gdn_chunk" in line
+    rule = [line for line in calls if "gdn_chunk" in line]
+    assert len(rule) == linear
+    assert len([c for c in calls if "prefill_attention" in c]) == full - 1
+    assert len(calls) == linear + full - 1
+    for line in rule:
         assert lm_work.touches_chunk_solve(line, cfg)
     assert "triangular" not in text.lower()
     assert 'custom_call_target="Invert' not in text
-    assert not re.search(r"\bwhile\(", text)
+    _no_scores_reach_hbm(text, spec["heads"], chunk,
+                         [hybrid_lm.cache_rows(spec)])
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= slab_bytes
 
@@ -510,20 +530,40 @@ def test_the_sparse_models_step_program_compiles_for_the_v5e(one_chip,
     assert memory.temp_size_in_bytes < slab_bytes // 100
 
 
+@pytest.mark.parametrize("chunk", [256, 1024])
 def test_the_sparse_models_prefill_program_compiles_for_the_v5e(
-        one_chip, monkeypatch):
-    """Its 1,024-token prefill program: the experts' kernel on the three
-    expert layers whose feed-forward a prompt needs (the last layer's
-    feeds only the head), the slab written in place, and what it needs
-    beside weights and slab fits the chip."""
+        one_chip, monkeypatch, chunk):
+    """Its two prefill programs: the experts' kernel on the three expert
+    layers whose feed-forward a prompt needs (the last layer's feeds
+    only the head), which the benchmark's predicate finds and no other
+    call; a chunk's attention one ``prefill_attention`` call on the
+    full layer and on three rings (the last ring's output feeds only
+    the head), no loop over key blocks and no array of scores left; the
+    slab written in place, and what it needs beside weights and slab
+    fits the chip."""
+    moe_work = _load("bench_moe_work", os.path.join(BENCH, "moe_work.py"))
     cfg, spec, params, slab, slab_bytes, arg = _sparse_model(one_chip)
+    assert chunk in spec["prefill_chunks"]
     scalar = arg((), "int32")
+    fused = obs.REGISTRY.gauge("prefill.attn.fused_layers")
+    fused.set(-1)
     compiled = _compile_for_the_chip(
-        monkeypatch, hybrid_lm.build_prefill(spec, 1024),
-        (params, slab, scalar, arg((1024,), "int32"), scalar, scalar),
+        monkeypatch, hybrid_lm.build_prefill(spec, chunk),
+        (params, slab, scalar, arg((chunk,), "int32"), scalar, scalar),
         spec.get("xla_options"))
-    names = _custom_calls(compiled.as_text())
-    assert len(names) == 3 and all("grouped_ffn" in c for c in names)
+    assert fused.value == len(spec["layer_types"]) == 5
+    text = compiled.as_text()
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = _custom_calls(text)
+    assert len([c for c in names if "grouped_ffn" in c]) == 3
+    assert len([c for c in names if "prefill_attention" in c]) == 4
+    assert len(names) == 7
+    for line in lines:
+        assert moe_work.touches_experts(line, cfg) == ("grouped_ffn" in line)
+    _no_scores_reach_hbm(text, spec["heads"] // spec["kv_heads"], chunk,
+                         [hybrid_lm.cache_rows(spec),
+                          hybrid_lm.cache_rows(spec, hybrid_lm.SLIDING)])
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= slab_bytes
     assert memory.temp_size_in_bytes < 1 << 30

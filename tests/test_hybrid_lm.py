@@ -88,8 +88,8 @@ def _daemon(tmp_path, name="d0", **cfg_kw):
 def both_specs(request, monkeypatch):
     """Runs a test once a spec of ``SPECS``, as the module's ``SPEC``
     and ``CFG``; with the head of 128 the kernel must have been on the
-    step's path and its counters must say that it read less than the
-    slab holds; with delta-chunks of 128 the chunked rule's kernel must
+    step's path and on prefill's and their counters must say that they
+    read less than the slab holds; with delta-chunks of 128 the chunked rule's kernel must
     have been on prefill's path, and with chunks of 64 its XLA body."""
     spec, cfg = SPECS[request.param]
     monkeypatch.setitem(globals(), "SPEC", spec)
@@ -98,8 +98,18 @@ def both_specs(request, monkeypatch):
     decode_mod.clear_decode_programs()
     rows = [_counter("decode.attn.rows_fetched"),
             _counter("decode.attn.rows_held")]
+    blocks = [_counter("prefill.attn.key_blocks_read"),
+              _counter("prefill.attn.key_blocks_held")]
     yield request.param
     kernel = request.param == "one-head-of-128"
+    # prefill's attention likewise: the kernel on the head of 128, and
+    # of a slot's three blocks of 256 keys only those a chunk sees
+    assert obs.REGISTRY.gauge("prefill.attn.fused_layers").value == (
+        TYPES.count("full_attention") if kernel else 0)
+    read, kept = (_counter("prefill.attn.key_blocks_read") - blocks[0],
+                  _counter("prefill.attn.key_blocks_held") - blocks[1])
+    assert kept > 0
+    assert (read < kept) if kernel else (read == kept)
     assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == (
         TYPES.count("full_attention") if kernel else 0)
     fetched, held = (_counter("decode.attn.rows_fetched") - rows[0],
