@@ -285,9 +285,6 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("session.decode_steps", "decode steps applied to session "
                                  "state (one per session per batch "
                                  "dispatch)"),
-        ("session.batch_occupancy", "summed batch occupancy across "
-                                    "decode dispatches (divide by "
-                                    "batches for mean coalescing)"),
         ("session.budget_spills", "advanced state layers larger than "
                                   "the whole device-cache budget, "
                                   "written straight to the arena "

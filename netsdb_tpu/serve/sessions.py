@@ -50,6 +50,22 @@ turn that is past its prompt, and retires the turns whose last step's
 outputs have reached the host — one step behind the dispatch, so that
 the device has the next program queued while the host reads.
 
+A turn's own timeline is recorded into the frame's trace (the one
+captured under ``session.coalesce``) by the leader, as consecutive
+spans: ``server.sched.session_wait`` (submit to the iteration that
+seats it), ``session.admit``, ``session.turn.prefill`` (seated to its
+last chunk dispatched; counters ``tokens``, ``chunks`` and
+``chunks_ahead``: other turns' chunks dispatched while it had chunks
+pending), ``session.turn.first_token`` (to its first output row on the
+host), ``session.turn.decode`` (to its last; counters ``steps``, its
+later steps, ``chunk_steps``, those whose ``session.step`` span held
+another turn's chunk, and ``chunk_step_s``, their seconds),
+``session.retire`` and ``session.turn.reply`` (the answer's way back
+to the frame's handler thread). A step's ``session.step`` span (one a
+step, in the trace of its first turn) counts ``prefill_tokens``: the
+tokens of the chunks dispatched after the step before it and up to it,
+which the device runs between the two.
+
 Ownership and stickiness: the pool leader places each session
 deterministically (itself, or one live worker by sid hash), pushing
 ``SESSION_OPEN op=adopt`` — with the model's dense weights on the
@@ -84,6 +100,15 @@ LEASE = "slot"
 
 
 output_set = session_output_set
+
+
+def _span(trace, name: str, start: float, end: float,
+          **counters) -> None:
+    """Record the region between two ``perf_counter`` readings into a
+    captured trace (``obs.record_into``): placed where it was, however
+    late the leader gets to record it."""
+    obs.record_into(trace, name, end - start, "serve",
+                    ended_ago_s=time.perf_counter() - end, **counters)
 
 
 def _host(value: Any) -> np.ndarray:
@@ -640,9 +665,9 @@ class SessionManager:
         """ONE iteration over the live frames of ``db`` (module
         docstring): admit, one prefill chunk, one decode step, harvest
         and retire."""
-        t_batch = time.perf_counter()
         lane = self._lanes.setdefault(
-            db, {"turns": {}, "steps": collections.deque()})
+            db, {"turns": {}, "steps": collections.deque(),
+                 "chunk_tokens": 0})
         results: List[Any] = [CONTINUE] * len(reqs)
         for i, r in enumerate(reqs):
             try:
@@ -660,8 +685,7 @@ class SessionManager:
             slab = self._slab(db)
             joining = [t for t in turns if t["chunks"]]
             if joining:
-                self._prefill_chunk(db, slab, min(
-                    joining, key=lambda t: t["admitted"]))
+                self._prefill_chunk(db, slab, lane, joining)
             ready = [t for t in turns if not t["chunks"] and t["n"] > 0]
             if ready:
                 self._decode_step(db, slab, lane, ready)
@@ -676,9 +700,6 @@ class SessionManager:
                     and not t["chunks"] and t["n"] == 0 \
                     and t["unread"] == 0:
                 results[i] = self._retire(db, lane, r)
-        if turns:
-            obs.record_into(turns[0]["trace"], "session.batch",
-                            time.perf_counter() - t_batch, "serve")
         return results
 
     def _admit(self, db: str, lane: Dict[str, Any],
@@ -718,7 +739,12 @@ class SessionManager:
         turn = {"sid": sid, "slot": slot, "leased": leased, "ttl": ttl,
                 "step": step, "tok": tok, "trace": r.get("trace"),
                 "admitted": time.perf_counter(), "chunks": [], "n": 1,
-                "x": None, "unread": 0, "outs": [], "logits": None}
+                "x": None, "unread": 0, "outs": [], "logits": None,
+                # the turn's timeline (module docstring): when its
+                # current phase began, and what its phases count
+                "at": 0.0, "prompt_tokens": 0, "prompt_chunks": 0,
+                "chunks_ahead": 0, "later_steps": 0, "chunk_steps": 0,
+                "chunk_step_s": 0.0}
         if self.runtime.takes_x(db):
             turn["x"] = np.asarray(r["x"], np.float32)
             turn["advance"] = 1
@@ -737,11 +763,10 @@ class SessionManager:
         # the frame's wait for the decode scheduler: from its handler's
         # submit to the iteration that seated it (a step boundary, and
         # a free slot); a ``server.sched.*`` span like the lanes' own
-        now = time.perf_counter()
-        obs.record_into(turn["trace"], "server.sched.session_wait",
-                        max(0.0, t0 - r.get("queued", t0)), "serve",
-                        ended_ago_s=now - t0)
-        obs.record_into(turn["trace"], "session.admit", now - t0, "serve")
+        now = turn["at"] = time.perf_counter()
+        _span(turn["trace"], "server.sched.session_wait",
+              min(r.get("queued", t0), t0), t0)
+        _span(turn["trace"], "session.admit", t0, now)
         return CONTINUE
 
     def _lay_out(self, db: str, step: int, tokens: np.ndarray,
@@ -799,7 +824,11 @@ class SessionManager:
         turn["slot"], _, turn["leased"] = seat
 
     def _prefill_chunk(self, db: str, slab: SessionSlab,
-                       turn: Dict[str, Any]) -> None:
+                       lane: Dict[str, Any],
+                       joining: List[Dict[str, Any]]) -> None:
+        """One chunk of the oldest joining turn's prompt; the others
+        count it as a chunk ahead of theirs."""
+        turn = min(joining, key=lambda t: t["admitted"])
         ids, count, next_tok, pos0 = turn["chunks"].pop(0)
         t0 = time.perf_counter()
         with slab.mu:
@@ -812,8 +841,19 @@ class SessionManager:
         obs.REGISTRY.counter("prefill.attn.key_blocks_held").inc(held)
         # the host's dispatch only: the program runs behind it, and its
         # device seconds are the device trace's to give
-        obs.record_into(turn["trace"], "session.prefill",
-                        time.perf_counter() - t0, "serve", tokens=count)
+        now = time.perf_counter()
+        _span(turn["trace"], "session.prefill", t0, now, tokens=count)
+        lane["chunk_tokens"] += count
+        for t in joining:
+            if t is not turn:
+                t["chunks_ahead"] += 1
+        turn["prompt_tokens"] += count
+        turn["prompt_chunks"] += 1
+        if not turn["chunks"]:
+            _span(turn["trace"], "session.turn.prefill", turn["at"], now,
+                  tokens=turn["prompt_tokens"], chunks=turn["prompt_chunks"],
+                  chunks_ahead=turn["chunks_ahead"])
+            turn["at"] = now
 
     def _decode_step(self, db: str, slab: SessionSlab,
                      lane: Dict[str, Any],
@@ -841,13 +881,14 @@ class SessionManager:
         for t in ready:
             t["n"] -= 1
             t["unread"] += 1
+        # the chunks dispatched since the step before this one run on
+        # the device between the two
         lane["steps"].append({"outs": outs, "turns": list(ready),
                               "t0": t0, "lane": lane,
-                              "counts": self.runtime.step_counts(db)})
-        obs.record_into(ready[0]["trace"], "session.device",
-                        time.perf_counter() - t0, "serve")
+                              "counts": self.runtime.step_counts(db),
+                              "prefill_tokens": lane["chunk_tokens"]})
+        lane["chunk_tokens"] = 0
         obs.REGISTRY.counter("session.decode_steps").inc(len(ready))
-        obs.REGISTRY.counter("session.batch_occupancy").inc(len(ready))
         if xs is None:
             obs.REGISTRY.counter("session.decode_tokens").inc(len(ready))
 
@@ -880,11 +921,6 @@ class SessionManager:
                 counts["experts_held"])
             obs.REGISTRY.gauge("decode.moe.max_load").set(
                 int(moe["max_load"]))
-        for t in turns:
-            t["outs"].append(host[t["slot"]])
-            t["unread"] -= 1
-            if "logits" in outs and t["n"] == 0 and t["unread"] == 0:
-                t["logits"] = outs["logits"]
         # a step's span runs from when the device was free for it (the
         # step before it was read, or its own dispatch if later) to its
         # outputs on the host: consecutive steps tile the timeline, and
@@ -893,8 +929,35 @@ class SessionManager:
         now = time.perf_counter()
         start = max(step["t0"], lane.get("read_at", 0.0))
         lane["read_at"] = now
-        obs.record_into(turns[0]["trace"], "session.step", now - start,
-                        "serve", rows=len(turns))
+        chunk = step["prefill_tokens"]
+        for t in turns:
+            t["outs"].append(host[t["slot"]])
+            t["unread"] -= 1
+            if "logits" in outs and t["n"] == 0 and t["unread"] == 0:
+                t["logits"] = outs["logits"]
+            self._turn_row(t, now, now - start, chunk)
+        _span(turns[0]["trace"], "session.step", start, now,
+              rows=len(turns), prefill_tokens=chunk)
+
+    @staticmethod
+    def _turn_row(t: Dict[str, Any], now: float, took: float,
+                  chunk: int) -> None:
+        """A turn's row of a step reached the host at ``now``: its first
+        ends the wait for the first token, a later one is a decode step
+        (one ``took`` seconds long that held ``chunk`` tokens of another
+        turn's prompt), its last ends the decode."""
+        if len(t["outs"]) == 1:
+            _span(t["trace"], "session.turn.first_token", t["at"], now)
+            t["at"] = now
+        else:
+            t["later_steps"] += 1
+            if chunk:
+                t["chunk_steps"] += 1
+                t["chunk_step_s"] += took
+        if t["n"] == 0 and t["unread"] == 0:
+            _span(t["trace"], "session.turn.decode", t["at"], now,
+                  steps=t["later_steps"], chunk_steps=t["chunk_steps"],
+                  chunk_step_s=t["chunk_step_s"])
 
     def _drop_turn(self, db: str, lane: Dict[str, Any],
                    r: Dict[str, Any]) -> None:
@@ -930,8 +993,8 @@ class SessionManager:
                 self._applied[sid] = {"token": turn["tok"], "steps": step,
                                       "out": out}
         self._drop_turn(db, lane, r)
-        obs.record_into(turn["trace"], "session.retire",
-                        time.perf_counter() - t0, "serve")
+        now = r["retired"] = time.perf_counter()
+        _span(turn["trace"], "session.retire", t0, now)
         return dict(out, steps=step)
 
     def _retag(self, sid: str, db: str, step: int) -> None:
@@ -1199,6 +1262,12 @@ class SessionManager:
             req["trace"] = obs.capture()
             req["queued"] = time.perf_counter()
             out = self.batcher.submit(db, sid, req)
+            if "retired" in req:
+                # the leader answers at its iteration's end, after the
+                # turns it retires after this one; then this thread
+                # wakes (milliseconds on a busy host)
+                _span(req["trace"], "session.turn.reply", req["retired"],
+                      time.perf_counter())
         return MsgType.OK, dict(out, sid=sid,
                                 owner=self._me()), CODEC_PICKLE
 

@@ -358,6 +358,106 @@ def test_alone_equals_batched_bit_for_bit(tmp_path, both_specs):
             cc.close()
 
 
+PHASES = ["server.sched.session_wait", "session.admit",
+          "session.turn.prefill", "session.turn.first_token",
+          "session.turn.decode", "session.retire", "session.turn.reply"]
+
+
+def _turn_profiles(ctl, skip=()):
+    """{qid: spans} of the daemon's GENERATE frames, each a turn, but
+    those whose query id is in ``skip``."""
+    c = RemoteClient(ctl.advertise_addr)
+    profiles = c.get_trace(last=256)["profiles"]
+    c.close()
+    return {p["qid"]: p["spans"] for p in profiles
+            if p.get("origin") == "server" and p["qid"] not in skip
+            and any(s["name"] == "session.coalesce" for s in p["spans"])}
+
+
+def _phases(spans):
+    """A turn's phases by name, held to tile its ``session.coalesce``:
+    its children, in order, inside it, summing to it within 2 ms."""
+    by_name = {s["name"]: s for s in spans}
+    co = by_name["session.coalesce"]
+    phases = [by_name[n] for n in PHASES]
+    assert all(s["parent"] == co["id"] for s in phases)
+    assert co["start_s"] <= phases[0]["start_s"] + 1e-6
+    for a, b in zip(phases, phases[1:]):
+        assert a["start_s"] + a["duration_s"] <= b["start_s"] + 1e-4
+    end = phases[-1]["start_s"] + phases[-1]["duration_s"]
+    assert end <= co["start_s"] + co["duration_s"] + 1e-6
+    assert abs(sum(s["duration_s"] for s in phases)
+               - co["duration_s"]) <= 2e-3
+    first, decode = (by_name["session.turn.first_token"],
+                     by_name["session.turn.decode"])
+    assert abs(first["start_s"] + first["duration_s"]
+               - decode["start_s"]) <= 1e-5
+    return {n: by_name[n].get("counters", {}) for n in PHASES}
+
+
+def test_a_turns_phases_tile_it_and_count_the_prompts_ahead(tmp_path,
+                                                            both_specs):
+    """A session alone, then two whose prompts take several chunks each,
+    admitted in one iteration: the later one waits out the earlier
+    one's chunks, and the earlier one's decode steps hold the later
+    one's; the steps' spans count every prompt token dispatched."""
+    with _daemon(tmp_path) as ctl:
+        _deploy(ctl).close()
+        # an idle model's leader lingers for peers: long enough here
+        # that both turns of the pair join its first iteration
+        ctl.sessions.batcher.window_s = 1.0
+        c0, h0, _ = _one_session(ctl, [(100, 4)])
+        h0.close()
+        c0.close()
+        alone = _turn_profiles(ctl)
+        (spans,) = alone.values()
+        assert _phases(spans)["session.turn.decode"] == {
+            "steps": 3, "chunk_steps": 0, "chunk_step_s": 0.0}
+
+        tokens0 = _counter("session.prefill_tokens")
+        clients = [RemoteClient(ctl.advertise_addr) for _ in range(2)]
+        handles = [cc.open_session("lm", kind="hybrid_lm")
+                   for cc in clients]
+        gate = threading.Barrier(2)
+
+        def turn(h, seed):
+            gate.wait()
+            h.generate(tokens=_prompt(np.random.default_rng(seed), 300),
+                       new_tokens=5, deadline_s=120.0)
+
+        ts = [threading.Thread(target=turn, args=(h, i))
+              for i, h in enumerate(handles)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=300)
+        dispatched = _counter("session.prefill_tokens") - tokens0
+        pair = list(_turn_profiles(ctl, skip=alone).values())
+        assert len(pair) == 2
+        early, late = sorted(
+            (_phases(spans) for spans in pair),
+            key=lambda p: p["session.turn.prefill"]["chunks_ahead"])
+        chunks = early["session.turn.prefill"]["chunks"]
+        assert chunks >= 2
+        assert early["session.turn.prefill"]["chunks_ahead"] == 0
+        assert late["session.turn.prefill"] == {
+            "tokens": 299, "chunks": chunks, "chunks_ahead": chunks}
+        # the earlier turn's later steps ran the later one's chunks
+        assert early["session.turn.decode"]["steps"] == 4
+        assert early["session.turn.decode"]["chunk_steps"] == min(chunks, 4)
+        assert early["session.turn.decode"]["chunk_step_s"] > 0
+        assert late["session.turn.decode"] == {
+            "steps": 4, "chunk_steps": 0, "chunk_step_s": 0.0}
+        # nothing was dispatched after the last step
+        assert sum(s["counters"]["prefill_tokens"]
+                   for spans in pair for s in spans
+                   if s["name"] == "session.step") == dispatched
+        for h in handles:
+            h.close()
+        for cc in clients:
+            cc.close()
+
+
 def test_a_reused_slot_equals_a_fresh_daemons(tmp_path, both_specs):
     with _daemon(tmp_path, "fresh") as ctl:
         _deploy(ctl).close()

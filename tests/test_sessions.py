@@ -332,9 +332,13 @@ def test_warm_decode_steps_never_read_the_arena(tmp_path):
 
 
 def test_get_trace_decomposes_decode_spans(tmp_path):
-    """GET_TRACE on a decode step shows the coalesce -> batch ->
-    device decomposition (single-session case: the submitter IS the
-    batch leader, so all three spans land in one server profile)."""
+    """GET_TRACE on a decode turn shows the turn's phases, recorded by
+    the batch's leader into the frame's own trace: children of
+    ``session.coalesce``, in order, inside it and tiling it
+    (``session.turn.prefill`` is absent: a toy kind has no prompt)."""
+    phases = ["server.sched.session_wait", "session.admit",
+              "session.turn.first_token", "session.turn.decode",
+              "session.retire", "session.turn.reply"]
     with _daemon(tmp_path) as ctl:
         c = RemoteClient(ctl.advertise_addr)
         deploy_decode_model(c, "m1", kind="lstm", hidden=HID, seed=9)
@@ -344,20 +348,30 @@ def test_get_trace_decomposes_decode_spans(tmp_path):
         server = [p for p in reply["profiles"]
                   if p.get("origin") == "server"]
         names = {s["name"] for p in server for s in p["spans"]}
-        assert {"session.coalesce", "session.batch",
-                "session.device"} <= names, names
-        # the frame's wait for the decode scheduler is a scheduler
-        # span like a lane's, a child of the coalesce span it lies in
+        assert not {"session.batch", "session.device"} & names, names
         for p in server:
             by_name = {s["name"]: s for s in p["spans"]}
-            if "server.sched.session_wait" not in by_name:
+            if "session.coalesce" not in by_name:
                 continue
-            wait, co = (by_name["server.sched.session_wait"],
-                        by_name["session.coalesce"])
-            assert wait["parent"] == co["id"]
-            assert co["start_s"] <= wait["start_s"] + 1e-6
-            assert wait["start_s"] + wait["duration_s"] \
-                <= by_name["session.admit"]["start_s"] + 1e-3
+            co = by_name["session.coalesce"]
+            assert set(phases) <= set(by_name), by_name
+            assert "session.turn.prefill" not in by_name
+            spans = [by_name[n] for n in phases]
+            assert all(s["parent"] == co["id"] for s in spans)
+            assert co["start_s"] <= spans[0]["start_s"] + 1e-6
+            for a, b in zip(spans, spans[1:]):
+                assert a["start_s"] + a["duration_s"] \
+                    <= b["start_s"] + 1e-3
+            last = spans[-1]
+            assert last["start_s"] + last["duration_s"] \
+                <= co["start_s"] + co["duration_s"] + 1e-6
+            assert abs(sum(s["duration_s"] for s in spans)
+                       - co["duration_s"]) <= 2e-3
+            decode = by_name["session.turn.decode"]["counters"]
+            assert decode == {"steps": 0, "chunk_steps": 0,
+                              "chunk_step_s": 0.0}
+            assert by_name["session.step"]["counters"] == {
+                "rows": 1, "prefill_tokens": 0}
             break
         else:
             raise AssertionError(names)
