@@ -67,6 +67,19 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
         ("serve.wire.bytes_out", "bytes of the reply and stream frames "
                                  "that answered workload requests (OBS "
                                  "frames' replies excluded)"),
+        ("serve.wire.checksum.bytes", "bytes of out-of-band segments "
+                                      "checksummed, sent and received "
+                                      "(serve/protocol.py::"
+                                      "segment_checksum; spans "
+                                      "client.encode.checksum and "
+                                      "server.decode.checksum time "
+                                      "them)"),
+        ("serve.wire.checksum.split_bytes", "of those, the bytes of "
+                                            "segments of "
+                                            "CHECKSUM_SPLIT_BYTES or "
+                                            "more, checksummed in "
+                                            "CHECKSUM_PARTS parts side "
+                                            "by side"),
         ("serve.wire.recv_pool.hits", "out-of-band segments of "
                                       "RECV_POOL_MIN_BYTES or more "
                                       "received into a recycled, "

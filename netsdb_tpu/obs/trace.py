@@ -220,18 +220,25 @@ class QueryTrace:
                 self._spans.append(sp)
 
     def record(self, name: str, duration_s: float, category: str = "",
-               start_s: Optional[float] = None, **counters) -> None:
+               start_s: Optional[float] = None,
+               parent: Optional[Span] = None, **counters) -> Span:
         """Record an already-measured region (e.g. the frame decode
-        that finished before the trace could open)."""
+        that finished before the trace could open), as a child of
+        ``parent`` where given (a span recorded so before it), else of
+        the span open on this thread. Returns the span."""
         if start_s is None:
             start_s = (time.perf_counter() - self._t0) - duration_s
-        parent, depth = self.here()
-        sp = Span(name, category, start_s, depth, next(self._ids), parent)
+        if parent is None:
+            up, depth = self.here()
+        else:
+            up, depth = parent.id, parent.depth + 1
+        sp = Span(name, category, start_s, depth, next(self._ids), up)
         sp.duration_s = duration_s
         if counters:
             sp.counters.update(counters)
         with self._mu:
             self._spans.append(sp)
+        return sp
 
     def backdate(self, seconds: float) -> None:
         """Shift the trace start ``seconds`` earlier — for work that

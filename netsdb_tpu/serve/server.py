@@ -31,7 +31,7 @@ import threading
 import time
 import traceback
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -1300,9 +1300,10 @@ class ServeController:
                 if workload:
                     obs.REGISTRY.counter("serve.wire.bytes_in").inc(nbytes)
                 t_dec = time.perf_counter()
+                checked: List[Tuple[float, int]] = []
                 try:
                     payload = decode_body(raw, codec_in, self.allow_pickle,
-                                          segments=segs)
+                                          segments=segs, checked=checked)
                 except ProtocolError as e:
                     # refused codec — deterministic, fatal to retry
                     if not self._send_err(conn, e, retryable=False,
@@ -1331,7 +1332,8 @@ class ServeController:
                     continue
                 if not self._dispatch_frame(conn, typ, codec_in, payload,
                                             decode_s=decode_s,
-                                            recv_s=recv_s):
+                                            recv_s=recv_s,
+                                            checked=checked):
                     return
 
     def _send_reply(self, conn, typ, payload, codec=CODEC_MSGPACK,
@@ -1380,7 +1382,8 @@ class ServeController:
 
     def _dispatch_frame(self, conn, typ, codec_in, payload,
                         decode_s: float = 0.0,
-                        recv_s: float = 0.0) -> bool:
+                        recv_s: float = 0.0,
+                        checked: Sequence[Tuple[float, int]] = ()) -> bool:
         """Execute one decoded request frame and send its reply. A
         frame carrying a client-minted query id opens a query-scoped
         trace first (``obs.trace``): the handler, the executor below
@@ -1432,8 +1435,12 @@ class ServeController:
                 # overlapping it
                 tr.backdate(recv_s + decode_s)
                 tr.record("server.recv", recv_s, "serve", start_s=0.0)
-                tr.record("server.decode", decode_s, "serve",
-                          start_s=recv_s)
+                decode = tr.record("server.decode", decode_s, "serve",
+                                   start_s=recv_s)
+                for seconds, nbytes in checked:
+                    # the segments' checksums open the decode
+                    tr.record("server.decode.checksum", seconds, "wire",
+                              start_s=recv_s, parent=decode, bytes=nbytes)
                 if client is not None:
                     tr.annotate("client", str(client))
             with self._maybe_device_profile(tr):
