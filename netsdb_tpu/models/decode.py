@@ -144,6 +144,21 @@ def _program(key: Tuple, build: Callable, donate: Tuple[int, ...] = (),
         compiler_options=xla_options)
 
 
+def _put(*values) -> list:
+    """Host values on the device, one ``device_put`` each, ahead of the
+    program's dispatch; the same avals, so the same program. This is for
+    a profiler's sake, as ``serve/sessions.py::_harvest``'s poll is: a
+    dispatch copies its host arguments inside its own host event, and a
+    copy takes 0.5 to 0.8 ms on a v5e while the chip is busy, so a
+    prefill chunk's dispatch (four of them) was one event of 1.2 to
+    4 ms. The benchmark's reader of idle gaps walks every host event over
+    1 ms for every gap, and a traced window of 64 sessions took 731 s to
+    reduce (chip runs; PERF.md section 7)."""
+    import jax
+
+    return [jax.device_put(v) for v in values]
+
+
 # --- slot programs: one slot of a slab, by the layout's slot axis -----
 
 def _slot_index(layout_entry, slot):
@@ -718,7 +733,7 @@ class DecodeRuntime:
         key = ("step", spec["kind"], _spec_sig(spec))
         fn = _program(key, reg["kind"].build_step(spec), donate=(1,),
                       xla_options=spec.get("xla_options"))
-        new, outs = fn(reg["params"], arrays, active, xs)
+        new, outs = fn(reg["params"], arrays, *_put(active, xs))
         live = int(active.sum())
         with _mu:
             _stats["batches"] += 1
@@ -765,9 +780,9 @@ class DecodeRuntime:
         fn = _program(key, reg["kind"].build_prefill(spec, chunk),
                       donate=(1,),
                       xla_options=spec.get("xla_options"))
-        return fn(reg["params"], arrays, np.int32(slot),
-                  np.asarray(tokens, np.int32), np.int32(n_valid),
-                  np.int32(next_tok))
+        return fn(reg["params"], arrays,
+                  *_put(np.int32(slot), np.asarray(tokens, np.int32),
+                        np.int32(n_valid), np.int32(next_tok)))
 
     def row_of(self, outputs, slot: int):
         """Row ``slot`` of a step's output, (1, width), on the device."""

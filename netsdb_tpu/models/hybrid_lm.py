@@ -62,17 +62,20 @@ rows plus the margin whatever the session's length, rotary positions on
 those layers (``rope_theta``), RMSNorm per head on ``q`` and ``k``
 (``qk_norm`` "head"), a sigmoid output gate on attention
 (``attn_gate``), a norm before each branch as well as after it
-(``pre_norms``: four a layer), the embedding scaled (``embed_scale``),
-and a feed-forward that is dense on the first ``dense_layers`` layers
-and sparse experts (``moe``) on the rest: sigmoid routing over all
-``experts``, the ``top_k`` chosen by score plus a selection bias, a
-shared expert, and of the routed experts those HELD here
-(``ops/experts.py``; the others' part of the result is left out, as on
-a chip that shares the layer with others). The step then returns, after
-its ids, how many (token, expert) pairs it routed to held experts, how
-many distinct held experts they touched and the largest load of one
-(``STEP_COUNTS``), summed (the load: the largest) over its expert
-layers.
+(``pre_norms``: four a layer) or before it alone (``norm_place`` "pre":
+``h = x + Attn(N1 x); y = h + F(N2 h)``), a rotary table a layer type
+(``rope``: HF's ``rope_parameters``, default or YaRN-scaled), the
+embedding scaled (``embed_scale``), and a feed-forward that is dense on
+the first ``dense_layers`` layers and sparse experts (``moe``) on the
+rest: routing over all ``experts`` (sigmoid scores, the ``top_k``
+chosen by score plus a selection bias; or a softmax, the ``top_k``
+renormalised), a shared expert or none, and of the routed experts those
+HELD here (``ops/experts.py``; the others' part of the result is left
+out, as on a chip that shares the layer with others). The step then
+returns, after its ids, how many (token, expert) pairs it routed to
+held experts, how many distinct held experts they touched and the
+largest load of one (``STEP_COUNTS``), summed (the load: the largest)
+over its expert layers.
 
 dtypes: weights and matrix operands in the weights' dtype (bfloat16 as
 deployed), products accumulate in float32, the residual stream, norms,
@@ -123,7 +126,8 @@ def make_spec(*, layer_types, hidden, intermediate, vocab, heads, head_dim,
               delta_chunk=64, dtype="bfloat16",
               xla_options=None, kv_heads=None, window=None,
               rope_theta=None, qk_norm=None, attn_gate=False,
-              pre_norms=False, embed_scale=None, dense_layers=None,
+              pre_norms=False, norm_place=None, rope=None,
+              embed_scale=None, dense_layers=None,
               moe=None) -> Dict[str, Any]:
     """The record the database holds for a model. ``prefill_chunks``
     are multiples of ``delta_chunk``, the tokens a chunk of the delta
@@ -140,12 +144,22 @@ def make_spec(*, layer_types, hidden, intermediate, vocab, heads, head_dim,
     ``q_norm`` and ``k_norm`` are one head wide), ``attn_gate`` (``W_o
     (o * sigmoid(W_g u))``, the gate's rows stacked under ``w_qkv``'s),
     ``pre_norms`` (``a = x + N2(Attn(N1 x)); y = a + N4(F(N3 a))``),
-    ``embed_scale`` (the embedding's factor), ``dense_layers`` with
-    ``moe`` (``{"experts", "top_k", "intermediate", "route_scale",
-    "first", "held"}``: layers from ``dense_layers`` on route each token
-    to ``top_k`` of ``experts`` experts of width ``intermediate``, of
-    which this deployment holds ``held`` from number ``first`` on, and
-    add a shared expert of the same width)."""
+    ``norm_place`` ("pre": ``a = x + Attn(N1 x); y = a + F(N2 a)``, the
+    gains ``norm_pre_mix`` and ``norm_pre_ffn``), ``rope`` ({layer type:
+    HF's rope parameters}: ``rope_type`` "default" or "yarn",
+    ``rope_theta``, and for YaRN ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``; rotary positions on the attention layers of
+    each type named, in place of ``rope_theta``), ``embed_scale`` (the
+    embedding's factor), ``dense_layers`` with ``moe`` (``{"experts",
+    "top_k", "intermediate", "first", "held"}`` and the scoring: layers
+    from ``dense_layers`` on route each token to ``top_k`` of
+    ``experts`` experts of width ``intermediate``, of which this
+    deployment holds ``held`` from number ``first`` on; by sigmoid
+    scores with a selection bias and ``route_scale``, and a shared
+    expert of the same width, unless ``"scoring": "softmax"`` (weights
+    renormalised over the chosen, no bias, no scale) and ``"shared":
+    0``)."""
     chunks = sorted(int(c) for c in prefill_chunks)
     if any(c % int(delta_chunk) for c in chunks):
         raise ValueError(f"prefill chunks {chunks} must be multiples of "
@@ -164,15 +178,23 @@ def make_spec(*, layer_types, hidden, intermediate, vocab, heads, head_dim,
             "window": window and int(window),
             "rope_theta": rope_theta and float(rope_theta),
             "qk_norm": qk_norm, "attn_gate": bool(attn_gate),
-            "pre_norms": bool(pre_norms),
+            "pre_norms": bool(pre_norms), "norm_place": norm_place,
+            "rope": rope and {kind: dict(r) for kind, r in rope.items()},
             "embed_scale": embed_scale and float(embed_scale),
             "dense_layers": None if moe is None else int(dense_layers or 0),
             "moe": moe and dict(moe)}
-    spec.update({k: v for k, v in wide.items() if v not in (None, False)})
+    spec.update({k: v for k, v in wide.items()
+                 if v is not None and v is not False})
     if SLIDING in spec["layer_types"] and not (window
                                               and window >= chunks[-1]):
         raise ValueError("sliding_attention layers need a window of a "
                          "prefill chunk at least")
+    if norm_place not in (None, "pre"):
+        raise ValueError(f"norm_place {norm_place!r}: only 'pre'")
+    for kind, r in (rope or {}).items():
+        if r.get("rope_type", "default") not in ("default", "yarn"):
+            raise ValueError(f"{kind}: rope_type {r['rope_type']!r} is not "
+                             f"'default' nor 'yarn'")
     return spec
 
 
@@ -182,6 +204,75 @@ def _kv_heads(spec) -> int:
 
 def _is_expert_layer(spec, i: int) -> bool:
     return "moe" in spec and i >= spec["dense_layers"]
+
+
+def _softmax_routed(spec) -> bool:
+    return spec["moe"].get("scoring") == "softmax"
+
+
+def _norms(spec) -> Tuple[bool, bool]:
+    """(a norm before each branch, a norm after it)."""
+    pre_only = spec.get("norm_place") == "pre"
+    return bool(spec.get("pre_norms")) or pre_only, not pre_only
+
+
+def expert_kernel_layers(spec, program: str = "step") -> int:
+    """The expert layers whose grouped product is the kernel
+    (``experts.grouped_ffn_fits``) in the step program or in a prefill
+    program, which leaves out the last layer's feed-forward (it feeds
+    only the head); 0 where the shape takes the XLA form."""
+    if "moe" not in spec or not experts.grouped_ffn_fits(
+            spec["hidden"], spec["moe"]["intermediate"], spec["dtype"]):
+        return 0
+    n = len(spec["layer_types"]) - spec["dense_layers"]
+    return n - 1 if program == "prefill" and n else n
+
+
+def rope_table(spec, kind: str):
+    """(inverse frequencies (head_dim / 2,) float32, the factor on cos and
+    sin or None) of a layer type's rotary positions, or None for a type
+    without. ``rope`` names the types; else ``rope_theta`` is the
+    sliding layers'. YaRN (HF's ``_compute_yarn_parameters``): pair
+    ``i``'s frequency ``theta^(-i / half)`` is kept below the ramp (the
+    fast pairs), divided by ``factor`` above it, and mixed linearly on
+    it; the ramp runs between the pairs that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original_max_position_embeddings``
+    positions."""
+    import math
+
+    if "rope" in spec:
+        r = spec["rope"].get(kind)
+    elif kind == SLIDING and "rope_theta" in spec:
+        r = {"rope_theta": spec["rope_theta"]}
+    else:
+        r = None
+    if r is None:
+        return None
+    dim = spec["head_dim"]
+    half = dim // 2
+    theta = float(r["rope_theta"])
+    inv = theta ** (-np.arange(half) / half)
+    if r.get("rope_type", "default") != "yarn":
+        return inv.astype(np.float32), None
+    factor = float(r["factor"])
+    original = float(r["original_max_position_embeddings"])
+
+    def turns_at(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(float(r.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turns_at(float(r.get("beta_slow", 1)))), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    inv = inv / factor * ramp + inv * (1 - ramp)
+    scale = r.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv.astype(np.float32), float(scale)
+
+
+def rope_scaled(spec, kind: str) -> bool:
+    """Whether a layer type's rotary table is YaRN-scaled."""
+    table = rope_table(spec, kind)
+    return table is not None and table[1] is not None
 
 
 def cache_rows(spec, kind: str = FULL) -> int:
@@ -292,11 +383,13 @@ def weight_shapes(spec) -> Dict[str, Tuple[Tuple[int, int], bool]]:
     lh, dk, dv = spec["lin_heads"], spec["lin_dk"], spec["lin_dv"]
     out = {"embed": ((v, d), True), "lm_head": ((v, d), True),
            "final_norm": ((1, d), False)}
+    before, after = _norms(spec)
     for i, kind in enumerate(spec["layer_types"]):
         p = f"l{i:02d}."
-        out.update({p + "norm_mix": ((1, d), False),
-                    p + "norm_ffn": ((1, d), False)})
-        if spec.get("pre_norms"):
+        if after:
+            out.update({p + "norm_mix": ((1, d), False),
+                        p + "norm_ffn": ((1, d), False)})
+        if before:
             out.update({p + "norm_pre_mix": ((1, d), False),
                         p + "norm_pre_ffn": ((1, d), False)})
         if _is_expert_layer(spec, i):
@@ -304,11 +397,13 @@ def weight_shapes(spec) -> Dict[str, Tuple[Tuple[int, int], bool]]:
             # expert; its down projection likewise
             m = spec["moe"]
             fe = m["intermediate"]
-            out.update({p + "w_router": ((m["experts"], d), True),
-                        p + "route_bias": ((1, m["experts"]), False),
-                        p + "w_shared_gate_up": ((2 * fe, d), True),
-                        p + "w_shared_down": ((d, fe), True),
-                        p + "w_experts_gate_up": ((m["held"] * 2 * fe, d),
+            out[p + "w_router"] = ((m["experts"], d), True)
+            if not _softmax_routed(spec):
+                out[p + "route_bias"] = ((1, m["experts"]), False)
+            if m.get("shared", 1):
+                out.update({p + "w_shared_gate_up": ((2 * fe, d), True),
+                            p + "w_shared_down": ((d, fe), True)})
+            out.update({p + "w_experts_gate_up": ((m["held"] * 2 * fe, d),
                                                   True),
                         p + "w_experts_down": ((m["held"] * d, fe), True)})
         else:
@@ -424,29 +519,36 @@ def _gated(u, w_gate_up, w_down):
 
 def _feed_forward(spec, p, i, h, valid, tile):
     """A layer's second branch, ``h + N(F(h))`` (with ``pre_norms``
-    ``h + N4(F(N3 h))``), ``F`` dense or, on an expert layer, the shared
-    expert plus the held experts' weighted part. Returns it with the
+    ``h + N4(F(N3 h))``, with ``norm_place`` "pre" ``h + F(N2 h)``),
+    ``F`` dense or, on an expert layer, the held experts' weighted part
+    and the shared expert where there is one. Returns it with the
     layer's ``STEP_COUNTS`` (None on a dense layer); ``valid`` (T,)
     says which rows are tokens that count."""
     pre = f"l{i:02d}."
     eps = spec["eps"]
-    u = _rms(h, p[pre + "norm_pre_ffn"], eps) if spec.get("pre_norms") else h
+    before, after = _norms(spec)
+    u = _rms(h, p[pre + "norm_pre_ffn"], eps) if before else h
     if not _is_expert_layer(spec, i):
         f, counts = _gated(u, p[pre + "w_gate_up"], p[pre + "w_down"]), None
     else:
         m = spec["moe"]
         d, fe = spec["hidden"], m["intermediate"]
-        idx, weights = experts.route(u, p[pre + "w_router"],
-                                     p[pre + "route_bias"], m["top_k"],
-                                     m["route_scale"])
-        routed, counts = experts.held_experts_ffn(
+        if _softmax_routed(spec):
+            idx, weights = experts.route_softmax(u, p[pre + "w_router"],
+                                                 m["top_k"])
+        else:
+            idx, weights = experts.route(u, p[pre + "w_router"],
+                                         p[pre + "route_bias"], m["top_k"],
+                                         m["route_scale"])
+        f, counts = experts.held_experts_ffn(
             u, idx, weights, valid,
             p[pre + "w_experts_gate_up"].reshape(m["held"], 2, fe, d),
             p[pre + "w_experts_down"].reshape(m["held"], d, fe),
             m["first"], tile)
-        f = routed + _gated(u, p[pre + "w_shared_gate_up"],
-                            p[pre + "w_shared_down"])
-    return h + _rms(f, p[pre + "norm_ffn"], eps), counts
+        if m.get("shared", 1):
+            f = f + _gated(u, p[pre + "w_shared_gate_up"],
+                           p[pre + "w_shared_down"])
+    return h + (_rms(f, p[pre + "norm_ffn"], eps) if after else f), counts
 
 
 def _add_counts(total, counts):
@@ -503,16 +605,18 @@ def _lin_out(spec, p, pre, o, gate):
     return _dense(o, p[pre + "wo"])
 
 
-def _rope(x, pos, theta):
+def _rope(x, pos, inv, scale=None):
     """Rotary positions on ``x`` (T, heads, head_dim) at ``pos`` (T,):
-    the halves of a head rotated against each other by ``pos *
-    theta^(-2i / head_dim)``."""
+    the halves of a head rotated against each other by ``pos * inv``
+    (``rope_table``), cos and sin times ``scale`` where there is one."""
     import jax.numpy as jnp
 
     half = x.shape[-1] // 2
-    inv = (float(theta) ** (-np.arange(half) / half)).astype(np.float32)
     ang = pos.astype(jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    cos, sin = cos[:, None, :], sin[:, None, :]
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
@@ -531,9 +635,9 @@ def _attn_in(spec, p, pre, kind, u, pos):
         q, k = q.reshape(-1, heads, hd), k.reshape(-1, hkv, hd)
     q = _rms(q, p[pre + "q_norm"], spec["eps"]).reshape(-1, heads, hd)
     k = _rms(k, p[pre + "k_norm"], spec["eps"]).reshape(-1, hkv, hd)
-    if kind == SLIDING and "rope_theta" in spec:
-        q, k = (_rope(q, pos, spec["rope_theta"]),
-                _rope(k, pos, spec["rope_theta"]))
+    table = rope_table(spec, kind)
+    if table is not None:
+        q, k = _rope(q, pos, *table), _rope(k, pos, *table)
     return q, k, v, gate
 
 
@@ -614,6 +718,7 @@ def build_step(spec):
     types = spec["layer_types"]
     heads, hd = spec["heads"], spec["head_dim"]
     keep = spec["conv_k"] - 1
+    before, after = _norms(spec)
     fits = step_kernel_fits(spec["lin_dk"],
                             spec["lin_heads"] * spec["lin_dv"])
     attn = [t for t in types if t != LINEAR]
@@ -624,6 +729,10 @@ def build_step(spec):
             sum(t == LINEAR for t in types) if fits else 0)
         obs.REGISTRY.gauge("decode.attn.ragged_layers").set(
             sum(_ragged(spec, t) for t in attn))
+        obs.REGISTRY.gauge("decode.moe.kernel_layers").set(
+            expert_kernel_layers(spec))
+        obs.REGISTRY.gauge("decode.attn.rope_scaled_layers").set(
+            sum(rope_scaled(spec, t) for t in attn))
         slab = dict(slab)
         conv = slab.get("conv")          # None: no linear layer
         pos, tok = slab["pos"], slab["tok"]
@@ -635,7 +744,7 @@ def build_step(spec):
         li = fi = 0
         for i, kind in enumerate(types):
             pre = f"l{i:02d}."
-            if spec.get("pre_norms"):
+            if before:
                 x_in, x = x, _rms(x, p[pre + "norm_pre_mix"], eps)
             else:
                 x_in = x
@@ -680,7 +789,8 @@ def build_step(spec):
                         k_pos=_ring_pos(pos, rows) if window else None)
                 mix = _attn_out(p, pre, o.reshape(-1, heads * hd), gate)
                 fi += 1
-            h = x_in + _rms(mix, p[pre + "norm_mix"], eps)
+            h = x_in + (_rms(mix, p[pre + "norm_mix"], eps) if after
+                        else mix)
             x, c = _feed_forward(spec, p, i, h, active, STEP_TILE)
             counts = _add_counts(counts, c)
         logits = _dense(_rms(x, p["final_norm"], eps), p["lm_head"])
@@ -709,6 +819,7 @@ def build_prefill(spec, chunk: int):
     types = spec["layer_types"]
     heads, hd = spec["heads"], spec["head_dim"]
     keep = spec["conv_k"] - 1
+    before, after = _norms(spec)
     fits = chunk_kernel_fits(spec["delta_chunk"], spec["lin_dk"],
                              spec["lin_dv"])
 
@@ -718,6 +829,8 @@ def build_prefill(spec, chunk: int):
             sum(t == LINEAR for t in types) if fits else 0)
         obs.REGISTRY.gauge("prefill.attn.fused_layers").set(
             sum(t != LINEAR and _fused(spec, chunk, t) for t in types))
+        obs.REGISTRY.gauge("prefill.moe.kernel_layers").set(
+            expert_kernel_layers(spec, "prefill"))
         slab = dict(slab)
         conv = slab.get("conv")          # None: no linear layer
         pos, tok = slab["pos"], slab["tok"]
@@ -732,7 +845,7 @@ def build_prefill(spec, chunk: int):
         last = len(types) - 1
         for i, kind in enumerate(types):
             pre = f"l{i:02d}."
-            if spec.get("pre_norms"):
+            if before:
                 x_in, x = x, _rms(x, p[pre + "norm_pre_mix"], eps)
             else:
                 x_in = x
@@ -796,7 +909,8 @@ def build_prefill(spec, chunk: int):
                                else None))
                 mix = _attn_out(p, pre, o.reshape(chunk, heads * hd), gate)
                 fi += 1
-            h = x_in + _rms(mix, p[pre + "norm_mix"], eps)
+            h = x_in + (_rms(mix, p[pre + "norm_mix"], eps) if after
+                        else mix)
             if i == last:
                 break     # the last layer's FFN feeds only the head
             x, _ = _feed_forward(spec, p, i, h, valid, PREFILL_TILE)

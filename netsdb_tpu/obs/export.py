@@ -440,6 +440,23 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                       "cache up to its own length (0: "
                                       "the cache's shape took the "
                                       "whole pass)"),
+        ("decode.attn.rope_scaled_layers", "attention layers of the "
+                                           "last traced hybrid_lm step "
+                                           "program whose rotary "
+                                           "table is YaRN-scaled"),
+        ("decode.moe.kernel_layers", "expert layers of the last traced "
+                                     "hybrid_lm step program whose "
+                                     "grouped product is the Pallas "
+                                     "kernel (0: the experts' shape "
+                                     "took the XLA form)"),
+        ("prefill.moe.kernel_layers", "expert layers of the last "
+                                      "traced hybrid_lm prefill "
+                                      "program whose grouped product "
+                                      "is the Pallas kernel (0: the "
+                                      "experts' shape took the XLA "
+                                      "form); the last layer's "
+                                      "feed-forward, which feeds only "
+                                      "the head, is not counted"),
         ("dedup.page_bytes", "unique model weight-page bytes resident "
                              "after cross-model deduplication "
                              "(compare against the per-model "

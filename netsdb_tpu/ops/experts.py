@@ -16,7 +16,9 @@ expert that no token chose has no tile, and its matrices are not read.
 Experts are gated SiLU feed-forward blocks stored ``[out, in]`` like
 every other matrix of the model: ``w_gate_up`` (held, 2, F, D) holds an
 expert's gate over its up projection, ``w_down`` (held, D, F).
-Operands take the matrices' dtype, sums are float32.
+Operands take the matrices' dtype, sums are float32. A token's experts
+are chosen by sigmoid scores with a selection bias (:func:`route`) or by
+a softmax over the router's logits (:func:`route_softmax`).
 """
 
 from __future__ import annotations
@@ -32,6 +34,10 @@ LANES = 128
 #: works on: gate, up and down blocks of (512, 3072) bfloat16 are 3.1 MB
 #: each, 18.9 MB double-buffered
 TILE_F = 512
+#: a width of whole lanes that is not whole ``TILE_F`` blocks is one
+#: block, while its three matrices double-buffered take at most this many
+#: bytes of VMEM: (896, 2304) bfloat16 blocks are 24.8 MB so
+WHOLE_BLOCK_VMEM = 32 << 20
 
 
 def route(u: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
@@ -53,6 +59,22 @@ def route(u: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
     picked = jnp.take_along_axis(s, idx, axis=-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), weights
+
+
+def route_softmax(u: jax.Array, w_router: jax.Array,
+                  top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """Softmax routing, the chosen weights renormalised. ``u`` (T, D)
+    float32, ``w_router`` (E, D): ``p = softmax(W_r u)`` in float32 (the
+    router's product at the highest precision, as in :func:`route`);
+    the ``top_k`` experts with the largest ``p``; weights ``p_e / (sum
+    of the chosen p)``. No bias, no scale. Returns (experts (T, k) int32,
+    weights (T, k) float32)."""
+    logits = lax.dot_general(
+        u.astype(jnp.float32), w_router.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    picked, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return idx.astype(jnp.int32), picked / picked.sum(-1, keepdims=True)
 
 
 def n_tiles(pairs: int, held: int, tile: int) -> int:
@@ -107,12 +129,26 @@ def plan_tiles(idx: jax.Array, valid: jax.Array, first: int, held: int,
             "sizes": sizes}
 
 
+def expert_block(d: int, f: int, dtype):
+    """Columns of an expert's hidden width ``f`` (over a model width
+    ``d``) that one grid step of :func:`grouped_ffn` takes, or None where
+    the kernel does not take the shape: ``TILE_F`` where ``f`` is whole
+    ``TILE_F`` blocks, else the whole width while it is whole lanes and
+    its three blocks double-buffered fit ``WHOLE_BLOCK_VMEM``. With one
+    block an expert, consecutive tiles of one expert ask for the same
+    blocks, and the pipeline fetches an expert's matrices once."""
+    if d % LANES or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return None
+    if f % TILE_F == 0:
+        return TILE_F
+    fits = 6 * f * d * jnp.dtype(dtype).itemsize <= WHOLE_BLOCK_VMEM
+    return f if f % LANES == 0 and (f < TILE_F or fits) else None
+
+
 def grouped_ffn_fits(d: int, f: int, dtype) -> bool:
     """Whether :func:`grouped_ffn`'s kernel takes experts of hidden width
-    ``f`` over a model width ``d``: whole lanes, whole ``TILE_F`` blocks."""
-    return (d % LANES == 0 and f % min(TILE_F, f) == 0
-            and min(TILE_F, f) % LANES == 0
-            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
+    ``f`` over a model width ``d`` (:func:`expert_block`)."""
+    return expert_block(d, f, dtype) is not None
 
 
 def grouped_ffn_xla(xs, tile_expert, used, w_gate_up, w_down, tile: int):
@@ -139,12 +175,12 @@ def grouped_ffn(xs: jax.Array, tile_expert: jax.Array, used: jax.Array,
     """Each tile of ``tile`` rows of ``xs`` (tiles x tile, D) through the
     gated SiLU block of its own expert, one Pallas kernel
     (``grouped_ffn`` in a device trace). ``tile_expert`` (tiles,) and
-    ``used`` are scalar-prefetched: the grid is (tiles, F / TILE_F), a
-    tile's step ``j`` fetches block ``j`` of its expert's gate, up and
-    down matrices and adds ``(silu(x W_g^T) * x W_u^T) W_d^T`` of that
-    block to the tile's float32 sum. Tiles from ``used`` on repeat the
-    last used tile's last blocks, so they fetch nothing, and write
-    zeros. Returns float32 (tiles x tile, D)."""
+    ``used`` are scalar-prefetched: the grid is (tiles, F / block), the
+    block :func:`expert_block`'s; a tile's step ``j`` fetches block ``j``
+    of its expert's gate, up and down matrices and adds ``(silu(x W_g^T)
+    * x W_u^T) W_d^T`` of that block to the tile's float32 sum. Tiles
+    from ``used`` on repeat the last used tile's last blocks, so they
+    fetch nothing, and write zeros. Returns float32 (tiles x tile, D)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -153,10 +189,11 @@ def grouped_ffn(xs: jax.Array, tile_expert: jax.Array, used: jax.Array,
     rows, d = xs.shape
     held, _, f, _ = w_gate_up.shape
     dt = w_gate_up.dtype
-    if not grouped_ffn_fits(d, f, dt):
+    tf = expert_block(d, f, dt)
+    if tf is None:
         raise ValueError(f"experts of width {f} over {d} in {dt} are not "
-                         f"whole blocks of ({TILE_F}, {LANES})")
-    tf = min(TILE_F, f)
+                         f"whole blocks of ({TILE_F}, {LANES}) nor one "
+                         f"block of whole lanes that fits VMEM")
     nj = f // tf
     nt = rows // tile
 

@@ -569,6 +569,85 @@ def test_the_sparse_models_prefill_program_compiles_for_the_v5e(
     assert memory.temp_size_in_bytes < 1 << 30
 
 
+def _softmax_routed_model(one_chip):
+    return _benchmarks_model(one_chip, "lm_sessions_mellum2",
+                             "mellum2-12b-a2.5b-8l")
+
+
+def test_the_softmax_routed_models_step_compiles_for_the_v5e(one_chip,
+                                                            monkeypatch):
+    """The decode step of ``benchmark/configs/mellum2-12b-a2.5b-8l.json``:
+    one ``decode_attention`` call an attention layer (six rings, two full
+    caches), one ``grouped_ffn`` an expert layer with experts 896 wide in
+    one block (the benchmark's predicate finds those and no other call),
+    the slab written in place; 7.59 GB of weights, 6.17 GB of slab."""
+    import sys
+
+    sys.path.insert(0, BENCH)     # the work module imports moe_work
+    try:
+        mellum2_work = _load("bench_mellum2_work",
+                             os.path.join(BENCH, "mellum2_work.py"))
+    finally:
+        sys.path.remove(BENCH)
+    cfg, spec, params, slab, slab_bytes, arg = _softmax_routed_model(one_chip)
+    assert (hybrid_lm.cache_rows(spec), hybrid_lm.cache_rows(
+        spec, hybrid_lm.SLIDING), spec["slots"]) == (17408, 2048, 64)
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert round(weights / 1e9, 2) == 7.59
+    assert slab_bytes == mellum2_work.slab_bytes(cfg) + 2 * 4 * 64
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_step(spec),
+        (params, slab, arg((64,), "bool")), spec.get("xla_options"))
+    assert obs.REGISTRY.gauge("decode.attn.ragged_layers").value == 8
+    assert obs.REGISTRY.gauge("decode.moe.kernel_layers").value == 8
+    assert obs.REGISTRY.gauge("decode.attn.rope_scaled_layers").value == 2
+    text = compiled.as_text()
+    lines = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = _custom_calls(text)
+    assert len([c for c in names if "decode_attention" in c]) == 8
+    assert len([c for c in names if "cache_write_rows" in c]) == 16
+    assert len([c for c in names if "grouped_ffn" in c]) == 8
+    assert len(names) == 32
+    for line in lines:
+        assert mellum2_work.touches_experts(line, cfg) == (
+            "grouped_ffn" in line)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
+    assert memory.temp_size_in_bytes < slab_bytes // 100
+
+
+@pytest.mark.parametrize("chunk", [256, 1024])
+def test_the_softmax_routed_models_prefill_compiles_for_the_v5e(
+        one_chip, monkeypatch, chunk):
+    """Its two prefill programs: ``grouped_ffn`` on the seven expert
+    layers whose feed-forward a prompt needs and ``prefill_attention`` on
+    the seven attention layers whose output does (the last layer's feed
+    only the head), no loop and no array of scores left, the slab
+    written in place."""
+    cfg, spec, params, slab, slab_bytes, arg = _softmax_routed_model(one_chip)
+    scalar = arg((), "int32")
+    gauge = obs.REGISTRY.gauge("prefill.moe.kernel_layers")
+    gauge.set(-1)
+    compiled = _compile_for_the_chip(
+        monkeypatch, hybrid_lm.build_prefill(spec, chunk),
+        (params, slab, scalar, arg((chunk,), "int32"), scalar, scalar),
+        spec.get("xla_options"))
+    assert gauge.value == 7
+    names = _custom_calls(compiled.as_text())
+    assert len([c for c in names if "grouped_ffn" in c]) == 7
+    assert len([c for c in names if "prefill_attention" in c]) == 7
+    assert len(names) == 14
+    _no_scores_reach_hbm(compiled.as_text(),
+                         spec["heads"] // spec["kv_heads"], chunk,
+                         [hybrid_lm.cache_rows(spec),
+                          hybrid_lm.cache_rows(spec, hybrid_lm.SLIDING)])
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slab_bytes
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
 def test_the_experts_xla_form_is_found_by_the_benchmarks_predicate(
         one_chip, monkeypatch):
     """The other side of the fallback: the grouped product in plain XLA
