@@ -16,6 +16,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from netsdb_tpu import obs
 from netsdb_tpu.catalog.catalog import Catalog
 from netsdb_tpu.config import Configuration, DEFAULT_CONFIG
 from netsdb_tpu.core.blocked import BlockedTensor
@@ -323,7 +324,11 @@ class Client:
                 block_shape = tuple(placed)
         block_shape = block_shape or self.config.default_block_shape
         t = BlockedTensor.from_dense(dense, block_shape, dtype=dtype)
-        ident = _ident(db, set_name)
+        # the rows placed in HBM, and the zero rows their blocks add
+        rows = t.shape[0]
+        obs.REGISTRY.counter("store.ingest.rows").inc(rows)
+        obs.REGISTRY.counter("store.ingest.pad_rows").inc(
+            t.meta.padded_shape[0] - rows)
         self.store.put_tensor(ident, t)
         cat = self.catalog.get_set(db, set_name)
         if cat is not None:
@@ -501,7 +506,6 @@ class Client:
         arm that was merely chosen, so per-arm means measure real
         physical configurations (the scheduler-side self-learning hook,
         ``QuerySchedulerServer.cc:246-330``)."""
-        from netsdb_tpu import obs
         from netsdb_tpu.plan.executor import execute_computations
 
         def run():

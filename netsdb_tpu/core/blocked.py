@@ -32,6 +32,24 @@ import numpy as np
 
 Shape = Tuple[int, ...]
 
+#: the TPU's lane width: the last axis of an array is tiled in whole lanes
+LANES = 128
+
+
+def batch_block(rows: int, block_shape: Sequence[int], axis: int = 0) -> Shape:
+    """The block of a batch of ``rows`` rows scored against a model
+    blocked ``block_shape``, with the batch along ``axis``.
+
+    The batch's block follows its own rows, rounded up to whole lanes
+    (the batch is the last axis of the ``(hidden x batch)`` product)
+    and never past the model's; every other axis keeps the model's
+    block, so the contraction joins the stored weights' blocks
+    unchanged. A batch of at least the model's block rows, and a model
+    whose block is under a lane, take the model's block as it is."""
+    out = [int(b) for b in block_shape]
+    out[axis] = min(out[axis], max(1, -(-int(rows) // LANES)) * LANES)
+    return tuple(out)
+
 
 @dataclasses.dataclass(frozen=True)
 class BlockMeta:

@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from netsdb_tpu import obs
+from netsdb_tpu.core.blocked import batch_block
 
 
 class ModelServing:
@@ -112,16 +113,20 @@ class ModelServing:
         return addrs
 
     # --- the layer-chain plan ----------------------------------------
-    def _sink(self):
+    def _sink(self, rows: int):
         if self.sink_builder is not None:
             sink = self.sink_builder()
         else:
             sink = self.model.build_inference_dag(
                 input_set=self.input_set, output_set=self.output_set)
         # the tensor_chain opt-in: declares the chain batch-
-        # decomposable along `axis` (plan/scatter.py module docstring)
+        # decomposable along `axis` (plan/scatter.py module docstring);
+        # the assembled output's batch axis is blocked by the batch's
+        # own rows, as the shards' programs block it
+        block = batch_block(rows, self.block, self.batch_axis) \
+            if self.block else self.block
         sink.scatter_gather = {"axis": self.batch_axis,
-                               "block": self.block,
+                               "block": block,
                                "mode": self.gather_mode}
         return sink
 
@@ -137,20 +142,24 @@ class ModelServing:
         c = self._client()
         db = self.model.db
         batch = np.asarray(batch, np.float32)
+        rows = int(batch.shape[0])
+        # a batch under the model's block rows is blocked by its own
+        # rows (core/blocked.py::batch_block): the device then pads and
+        # computes no rows that nobody asked for
+        block = batch_block(rows, self.block) if self.block else None
         # ONE trace for the whole request: its three frames (ship the
         # batch, execute, read the scores back) carry one query id, so
         # the daemon's profile of each joins it; the span's self time
         # is this side's work between and after the frames
         with c.request_trace("models.score"):
-            c.send_matrix(db, self.input_set, batch, self.block)
+            c.send_matrix(db, self.input_set, batch, block)
             reply = c._request(
                 MsgType.EXECUTE_COMPUTATIONS,
-                {"sinks": [self._sink()], "job_name": f"{db}-serve",
+                {"sinks": [self._sink(rows)], "job_name": f"{db}-serve",
                  "materialize": True, "explain": bool(explain)},
                 codec=CODEC_PICKLE)
             results = c._collect_results(reply["results"], True)
         value = next(iter(results.values()))
-        rows = int(batch.shape[0])
         obs.REGISTRY.counter("models.batches_scored").inc()
         obs.REGISTRY.counter("models.rows_scored").inc(rows)
         if explain:

@@ -154,6 +154,10 @@ def _catalog() -> Dict[str, Tuple[str, str]]:
                                   "serving pool"),
         ("models.rows_scored", "batch rows scored over the serving "
                                "pool (the rows/s headline numerator)"),
+        ("store.ingest.rows", "rows of the matrices blocked and placed "
+                              "in HBM (client.py::send_matrix)"),
+        ("store.ingest.pad_rows", "zero rows the blocks of those "
+                                  "matrices add past their rows"),
         ("sched.feedback_reseeds", "lane weight/quota reseeds applied "
                                    "from the attribution + operator "
                                    "ledgers (sched_feedback)"),

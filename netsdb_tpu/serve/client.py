@@ -1559,7 +1559,9 @@ class RemoteClient:
         ingest bandwidth scales with the pool, like routed tables); a
         degraded slot's typed refusal surfaces to the caller — scoring
         batches are transient, so there is no handoff buffering to
-        fall back on."""
+        fall back on. Each slice is blocked by its own rows
+        (``core/blocked.py::batch_block``)."""
+        from netsdb_tpu.core.blocked import batch_block
         from netsdb_tpu.serve import placement as _pl
 
         if entry.get("mode") != "range":
@@ -1587,7 +1589,8 @@ class RemoteClient:
                         "db": db, "set": set_name,
                         "tensor": tensor_to_wire(
                             np.ascontiguousarray(dense[lo:hi]),
-                            block_shape),
+                            batch_block(hi - lo, block_shape)
+                            if block_shape else block_shape),
                         PLACEMENT_EPOCH_KEY: int(entry["epoch"]),
                         SHARD_SLOT_KEY: i})
             except Exception as e:  # noqa: BLE001 — surfaced below

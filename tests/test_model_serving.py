@@ -15,6 +15,7 @@ import pytest
 
 from netsdb_tpu import obs
 from netsdb_tpu.config import Configuration
+from netsdb_tpu.core.blocked import batch_block
 from netsdb_tpu.models.conv2d import Conv2DModel
 from netsdb_tpu.models.ff import FFModel
 from netsdb_tpu.models.serving import ModelServing, ff_serving
@@ -203,6 +204,107 @@ def test_ff_serving_staged_rows_bounded_per_shard(tmp_path):
                 total += rows
         assert total == B
         srv.close()
+
+
+# --- a shipped batch is blocked by its own rows ------------------------
+
+@pytest.mark.parametrize("rows, block, want", [
+    (1, (512, 512), (128, 512)), (128, (512, 512), (128, 512)),
+    (200, (512, 512), (256, 512)), (512, (512, 512), (512, 512)),
+    (600, (512, 512), (512, 512)), (3, (4, 4), (4, 4)),
+    (200, (4, 4), (4, 4)), (128, (512, 512, 2), (128, 512, 2))])
+def test_batch_block_follows_the_batch_rows_in_whole_lanes(rows, block,
+                                                          want):
+    assert batch_block(rows, block) == want
+    # along the output's batch axis, the other axes keep the model's
+    assert batch_block(rows, block[::-1], axis=len(block) - 1) \
+        == want[::-1]
+
+
+def _ingested():
+    return {k: _counter("store.ingest." + k) for k in ("rows", "pad_rows")}
+
+
+def _ff_served_block(tmp_path, weights, batch, n_workers, block):
+    """Score ``batch`` through ``ff_serving`` over a pool of ``n_workers``
+    workers (0: a solo daemon, the input set range-placed over its one
+    slot) with the model blocked ``block``; returns the output, each
+    slot's stored input block shape and padded rows, and what the
+    ingest counters counted."""
+    db = "ffbatch"
+    model = FFModel(db=db, block=block)
+
+    def load(c):
+        model.setup(c)
+        model.load_weights(c, *weights)
+
+    with pool(tmp_path, n_workers=n_workers) as (leader, workers, addr):
+        srv = ff_serving(model, addr, block=model.block)
+        srv.deploy(load)
+        before = _ingested()
+        out = srv.score(batch)
+        counted = {k: v - before[k] for k, v in _ingested().items()}
+        stored = [ctl.library.get_tensor(db, "inputs")
+                  for ctl in [leader] + workers]
+        scores = leader.library.get_tensor(db, "output")
+        srv.close()
+    return (out, [(t.meta.block_shape, t.data.shape[0]) for t in stored],
+            counted, (scores.meta.block_shape, scores.data.shape))
+
+
+@pytest.mark.parametrize("rows, row_block", [
+    (128, 128), (200, 256), (512, 512), (600, 512)])
+def test_a_shipped_batch_is_blocked_by_its_own_rows(tmp_path, rows,
+                                                    row_block):
+    """A batch of fewer rows than the model's block rows is stored with
+    a row block of its rows rounded up to a lane (128), its scores are
+    byte-equal to the same batch padded to the model's 512 rows, and
+    the output's batch axis takes the same block; a batch of 512 rows
+    or more keeps (512, 512). The ingest counters count the rows stored
+    and the zero rows their blocks add. 600 features are not a whole
+    number of 512-column blocks, so the contraction's margin is still
+    padded."""
+    rng = np.random.default_rng(29)
+    F, H, L = 600, 8, 5
+    weights = _ff_weights(rng, F, H, L)
+    batch = _int_f32(rng, (rows, F))
+    oracle = _ff_oracle(tmp_path, weights, batch, block=(512, 512))
+    out, stored, counted, scores = _ff_served_block(
+        tmp_path, weights, batch, 0, (512, 512))
+    padded = -(-rows // row_block) * row_block
+    assert stored == [((row_block, 512), padded)]
+    assert counted == {"rows": rows, "pad_rows": padded - rows}
+    assert scores == ((512, row_block), (512, padded))
+    assert np.array_equal(np.asarray(out.to_dense()), oracle)
+
+
+def test_a_routed_slice_is_blocked_by_its_own_rows(tmp_path):
+    """Over a pool, each slot's slice of the batch takes the rule for
+    its own rows: 200 rows over three slots are slices of 67, 67 and 66
+    rows, each stored in one 128-row block; the assembled scores are
+    blocked by the whole batch's 200 rows, 256, and byte-equal to the
+    solo engine's at 512."""
+    rng = np.random.default_rng(31)
+    weights = _ff_weights(rng, 600, 8, 5)
+    batch = _int_f32(rng, (200, 600))
+    oracle = _ff_oracle(tmp_path, weights, batch, block=(512, 512))
+    out, stored, counted, scores = _ff_served_block(
+        tmp_path, weights, batch, 2, (512, 512))
+    assert stored == [((128, 512), 128)] * 3
+    assert counted == {"rows": 200, "pad_rows": 3 * 128 - 200}
+    assert scores == ((512, 256), (512, 256))
+    assert np.array_equal(np.asarray(out.to_dense()), oracle)
+
+
+def test_the_ingest_counters_are_catalogued_and_documented():
+    import os
+
+    from netsdb_tpu.obs.export import CATALOG
+    doc = open(os.path.join(os.path.dirname(__file__), "..", "docs",
+                            "METRICS.md")).read()
+    for name in ("store.ingest.rows", "store.ingest.pad_rows"):
+        assert CATALOG[name][0] == "counter"
+        assert f"| `{name}` | counter |" in doc
 
 
 # --- conv2d: items-mode tensor_chain without ModelServing -------------
